@@ -640,8 +640,8 @@ def bench_agg_parse(n_iters: int = 300, k: int = 64,
 def bench_device_agg(n_rounds: int = 3, agg_k: int = 64,
                      n_slots: int = 2) -> list[dict]:
     """'device_agg': the batched aggregate-container sweep vs the shipping
-    per-slot singleton ring at the same K-sub-record workload (interpret
-    mode, 1-device mesh).
+    per-slot singleton ring at the same K-sub-record workload (1-device
+    mesh; Pallas mode follows the backend).
 
     * ``agg_sweep`` — all K sub-records arrive in ONE container slot; a
       single ring visit (one ``agg_ring_poll`` pass + ONE ``ifunc_vm``
@@ -688,14 +688,14 @@ def bench_device_agg(n_rounds: int = 3, agg_k: int = 64,
                                      body_words, slot_words_a)
     mb_a = jnp.asarray(mb_a)
     sweep_a = make_agg_sweep(mesh, "mb", prog, agg_k, n_tiles, T,
-                             bound_hash=bound, interpret=True)
+                             bound_hash=bound)
 
     slot_words_s = HDR_WORDS + body_words + 1
     mb_s = np.zeros((1, n_slots, slot_words_s), np.uint32)
     for j in range(n_slots):
         mb_s[0, j] = pack_word_frame(pays[j], slot_words_s)
     mb_s = jnp.asarray(mb_s)
-    sweep_s = make_sweep(mesh, "mb", prog, n_tiles, T, interpret=True)
+    sweep_s = make_sweep(mesh, "mb", prog, n_tiles, T)
 
     jax.block_until_ready(sweep_a(mb_a, ext))    # compile + warm both arms
     jax.block_until_ready(sweep_s(mb_s, ext))
@@ -731,7 +731,7 @@ def bench_device_agg(n_rounds: int = 3, agg_k: int = 64,
 
 
 def bench_uvm(n_tiles: int = 8, iters: int = 5) -> list[dict]:
-    """Device-tier μVM execution cost per injected program (interpret mode)."""
+    """Device-tier μVM execution cost per injected program."""
     import numpy as np
 
     from repro.core.codegen import assemble

@@ -73,6 +73,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from benchmarks import bench_ifunc as B  # noqa: E402
+from repro.backend import use_compile_cache  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 OUT = ROOT / "experiments" / "bench_results.json"
@@ -235,6 +236,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="cached-fast-path suite only, reduced iterations")
     args = ap.parse_args()
+    use_compile_cache()
     if args.quick:
         suites = [lambda: fig5_cached(quick=True),
                   lambda: fig_graph(quick=True),

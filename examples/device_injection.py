@@ -1,7 +1,7 @@
 """Tier-B demo: inject a μVM program into on-device mailboxes over the ICI,
 through the unified transport layer.
 
-Eight (emulated) TPU shards form a ``DeviceMeshFabric``; a host-side
+Every device JAX sees is one shard of a ``DeviceMeshFabric``; a host-side
 dispatcher sends ordinary ifunc frames (``uvm_affine``: y = relu(x @ W),
 W bound from the target's external table — the device GOT).  The fabric
 transcodes each wire frame into the device word-frame layout, one-sided-
@@ -10,12 +10,14 @@ deposits it into the *right neighbor's* ring buffer via collective_permute
 (ring_poll kernel) and runs the injected program on every shard.
 
     PYTHONPATH=src python examples/device_injection.py
+
+On a CPU host, ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+emulates eight shards; with one device the frames land in its own ring.
 """
 
 import os
 import pathlib
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ.setdefault("REPRO_IFUNC_LIB_DIR",
                       str(pathlib.Path(__file__).resolve().parents[1] / "ifunc_libs"))
 
@@ -30,7 +32,7 @@ from repro.transport.device_fabric import DeviceMeshFabric
 
 from repro.parallel.sharding import make_mesh
 
-T, NT, SHARDS = 128, 2, 8
+T, NT, SHARDS = 128, 2, len(jax.devices())
 
 mesh = make_mesh((SHARDS,), ("model",))
 source = Context("host-source")

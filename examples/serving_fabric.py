@@ -30,7 +30,6 @@ import pathlib
 
 os.environ.setdefault("REPRO_IFUNC_LIB_DIR",
                       str(pathlib.Path(__file__).resolve().parents[1] / "ifunc_libs"))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np

@@ -1,17 +1,8 @@
-"""Cross-version jax API normalizers shared by src/ and benchmarks/.
-
-Mesh/shard_map shims live in ``repro.parallel.sharding`` (they need the
-sharding imports); the jax-API helpers with no other home live here.
-"""
+"""jax-API helpers shared by src/ and benchmarks/."""
 
 from __future__ import annotations
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: newer
-    releases return one dict, older ones a one-element list of dicts (one
-    per device program), and either may be empty/None."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``compiled.cost_analysis()`` as a dict, empty where XLA gives none."""
+    return dict(compiled.cost_analysis() or {})

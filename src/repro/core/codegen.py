@@ -289,4 +289,15 @@ def deserialize_uvm(code: bytes) -> UvmProgram:
     for dt in (np.int32, np.int32, np.int32, np.int32, np.float32):
         arrs.append(np.frombuffer(code, dt, P, off).copy())
         off += P * 4
+    op, dst, a, b, _ = arrs
+    # the compiled kernel indexes its register file and external table
+    # with these words unchecked: out of range there is out of bounds
+    loade = op == OPS["loade"]
+    if ((op < 0) | (op >= N_OPS)).any():
+        raise CodeVerifyError("uvm opcode out of range")
+    for regs in (dst, b, a[~loade]):
+        if ((regs < 0) | (regs >= UVM_REGS)).any():
+            raise CodeVerifyError("uvm register out of range")
+    if (a[loade] < 0).any():
+        raise CodeVerifyError("uvm external slot out of range")
     return UvmProgram(*arrs, n_ext=n_ext, symbols=symbols)

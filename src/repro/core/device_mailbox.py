@@ -20,13 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.codegen import UvmProgram
 from repro.kernels.ifunc_vm import ifunc_vm
 from repro.kernels.ring_poll import BAD, EMPTY, HDR_WORDS, INFLIGHT, MAGIC, READY, TRAILER
 from repro.kernels.ring_poll import ring_poll
-from repro.parallel.sharding import shard_map  # version-shimmed shard_map
+from repro.parallel.sharding import shard_map
 
 
 def pack_word_frame(payload_f32: np.ndarray, slot_words: int, kind: int = 3,
@@ -78,8 +78,15 @@ def pack_agg_word_frame(payloads, hashes, agg_k: int, body_words: int,
     return s
 
 
-def empty_mailbox(n_shards: int, n_slots: int, slot_words: int) -> jnp.ndarray:
-    return jnp.zeros((n_shards, n_slots, slot_words), jnp.uint32)
+def shard_rows(mesh, axis: str) -> NamedSharding:
+    """Leading dim split over ``axis``: shard i of a ring lives on device i."""
+    return NamedSharding(mesh, P(axis))
+
+
+def empty_mailbox(mesh, axis: str, n_slots: int, slot_words: int) -> jnp.ndarray:
+    """A zero ring per shard, made in place on the shard's own device."""
+    return jnp.zeros((mesh.shape[axis], n_slots, slot_words), jnp.uint32,
+                     device=shard_rows(mesh, axis))
 
 
 def make_deposit(mesh, axis: str):
@@ -93,6 +100,7 @@ def make_deposit(mesh, axis: str):
     left untouched."""
     n = mesh.shape[axis]
 
+    @functools.partial(jax.jit, static_argnames=("shift",))
     def deposit(mailbox, outgoing, shift: int):
         def f(mb, out):
             perm = [(i, (i + shift) % n) for i in range(n)]
@@ -105,8 +113,7 @@ def make_deposit(mesh, axis: str):
     return deposit
 
 
-def make_sweep(mesh, axis: str, prog: UvmProgram, n_tiles: int, tile: int = 128,
-               *, interpret: bool = True):
+def make_sweep(mesh, axis: str, prog: UvmProgram, n_tiles: int, tile: int = 128):
     """Build ``sweep(mailbox, externals)`` -> (status, results, cleared_mb).
 
     Validates every slot with the ring_poll kernel, bit-casts READY frame
@@ -115,14 +122,15 @@ def make_sweep(mesh, axis: str, prog: UvmProgram, n_tiles: int, tile: int = 128,
     """
     body_words = n_tiles * tile * tile
 
+    @jax.jit
     def sweep(mailbox, ext):
         def f(mb, ext_l):
             mb2 = mb[0]                      # [n_slots, slot_words]
-            status = ring_poll(mb2, interpret=interpret)
+            status = ring_poll(mb2)
             body = mb2[:, HDR_WORDS:HDR_WORDS + body_words]
             tiles = jax.lax.bitcast_convert_type(body, jnp.float32)
             tiles = tiles.reshape(mb2.shape[0] * n_tiles, tile, tile)
-            out = ifunc_vm(prog, tiles, ext_l[0], interpret=interpret)
+            out = ifunc_vm(prog, tiles, ext_l[0])
             out = out.reshape(mb2.shape[0], n_tiles, tile, tile)
             ready = (status == READY)
             out = out * ready[:, None, None, None].astype(out.dtype)
@@ -141,8 +149,7 @@ def make_sweep(mesh, axis: str, prog: UvmProgram, n_tiles: int, tile: int = 128,
 
 
 def make_agg_sweep(mesh, axis: str, prog: UvmProgram, agg_k: int,
-                   n_tiles: int, tile: int = 128, *, bound_hash: int = 0,
-                   interpret: bool = True):
+                   n_tiles: int, tile: int = 128, *, bound_hash: int = 0):
     """Build ``sweep(mailbox, externals)`` for *aggregate-container* slots
     -> (status, sub_status, results, cleared_mb).
 
@@ -158,18 +165,18 @@ def make_agg_sweep(mesh, axis: str, prog: UvmProgram, agg_k: int,
 
     body_words = n_tiles * tile * tile
     hdr_words = HDR_WORDS + 2 * agg_k
-    bound = jnp.asarray([bound_hash & 0xFFFFFFFF], jnp.uint32)
+    bound = np.asarray([bound_hash & 0xFFFFFFFF], np.uint32)
 
+    @jax.jit
     def sweep(mailbox, ext):
         def f(mb, ext_l):
             mb2 = mb[0]                      # [n_slots, slot_words]
             n_slots = mb2.shape[0]
-            status, sub_st = agg_ring_poll(
-                mb2[:, :hdr_words], mb2[:, -1:], bound, interpret=interpret)
+            status, sub_st = agg_ring_poll(mb2[:, :hdr_words], mb2[:, -1:], bound)
             body = mb2[:, hdr_words:hdr_words + agg_k * body_words]
             tiles = jax.lax.bitcast_convert_type(body, jnp.float32)
             tiles = tiles.reshape(n_slots * agg_k * n_tiles, tile, tile)
-            out = ifunc_vm(prog, tiles, ext_l[0], interpret=interpret)
+            out = ifunc_vm(prog, tiles, ext_l[0])
             out = out.reshape(n_slots, agg_k, n_tiles, tile, tile)
             ready = (sub_st == SUB_READY)
             out = out * ready[:, :, None, None, None].astype(out.dtype)

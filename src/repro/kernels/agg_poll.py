@@ -32,18 +32,20 @@ INFLIGHT / BAD — same lattice as ring_poll) plus K per-sub statuses:
 A corrupt container header (or missing trailer) rejects the whole
 container: per-sub fields cannot be trusted, exactly the host-side
 ``parse_agg`` signal-mismatch behaviour.
+
+The interleaved descriptor pairs are split into a hash and a check table
+by XLA before the kernel, which sees whole [n_slots, K] tables.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ring_poll import BAD, EMPTY, HDR_WORDS, INFLIGHT, READY, TRAILER
+from repro import backend
+from repro.kernels.ring_poll import BAD, EMPTY, HDR_WORDS, INFLIGHT, READY, TRAILER, as_i32
 
 AGG_MAGIC = 0x1F5C0DE6
 SUB_SALT = 0x5A17A9E5
@@ -51,35 +53,30 @@ SUB_SALT = 0x5A17A9E5
 SUB_EMPTY, SUB_READY, SUB_BAD, SUB_NACK = 0, 1, 3, 4
 
 
-def _agg_poll_kernel(bound_ref, hdr_ref, tr_ref, status_ref, sub_ref):
-    hdr = hdr_ref[0].astype(jnp.uint32)       # [HDR_WORDS + 2K]
-    k = sub_ref.shape[1]
-    magic, n_subs, kind, rsvd, chk = hdr[0], hdr[1], hdr[2], hdr[3], hdr[4]
-    hdr_ok = ((magic == jnp.uint32(AGG_MAGIC))
-              & (chk == (magic ^ n_subs ^ kind ^ rsvd)))
-    bounds_ok = n_subs <= jnp.uint32(k)
-    trailer_ok = tr_ref[0, 0].astype(jnp.uint32) == jnp.uint32(TRAILER)
+def _agg_poll_kernel(bound_ref, hdr_ref, hash_ref, chk_ref, tr_ref,
+                     status_ref, sub_ref):
+    n, k = sub_ref.shape
+    magic, n_subs, kind, rsvd, chk = (hdr_ref[:, i:i + 1]
+                                      for i in range(HDR_WORDS))
+    hdr_ok = (magic == AGG_MAGIC) & (chk == (magic ^ n_subs ^ kind ^ rsvd))
+    bounds_ok = (n_subs >= 0) & (n_subs <= k)
     st = jnp.where(
-        magic == jnp.uint32(0), EMPTY,
+        magic == 0, EMPTY,
         jnp.where(~(hdr_ok & bounds_ok), BAD,
-                  jnp.where(trailer_ok, READY, INFLIGHT)))
-    status_ref[0] = st.astype(jnp.int32)
+                  jnp.where(tr_ref[...] == as_i32(TRAILER), READY, INFLIGHT)))
+    status_ref[...] = st.astype(jnp.int32)
 
-    desc = hdr[HDR_WORDS:HDR_WORDS + 2 * k].reshape(k, 2)
-    hashes, checks = desc[:, 0], desc[:, 1]
-    bound = bound_ref[0].astype(jnp.uint32)
-    occupied = (jax.lax.broadcasted_iota(jnp.int32, (k,), 0)
-                < n_subs.astype(jnp.int32))
-    ok = checks == (hashes ^ jnp.uint32(SUB_SALT))
-    match = (bound == jnp.uint32(0)) | (hashes == bound)
-    sub = jnp.where(ok & match, SUB_READY,
-                    jnp.where(ok, SUB_NACK, SUB_BAD))
+    hashes, checks = hash_ref[...], chk_ref[...]
+    bound = bound_ref[0]
+    occupied = jax.lax.broadcasted_iota(jnp.int32, (n, k), 1) < n_subs
+    ok = checks == (hashes ^ SUB_SALT)
+    match = (bound == 0) | (hashes == bound)
+    sub = jnp.where(ok & match, SUB_READY, jnp.where(ok, SUB_NACK, SUB_BAD))
     sub = jnp.where(occupied & (st == READY), sub, SUB_EMPTY)
-    sub_ref[0] = sub.astype(jnp.int32)
+    sub_ref[...] = sub.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def agg_ring_poll(hdr_tbl, trailers, bound, *, interpret=True):
+def agg_ring_poll(hdr_tbl, trailers, bound):
     """Validate every aggregate slot's header block in one batched pass.
 
     hdr_tbl:  [n_slots, HDR_WORDS + 2K] uint32 (container hdr + descriptors)
@@ -89,17 +86,23 @@ def agg_ring_poll(hdr_tbl, trailers, bound, *, interpret=True):
     """
     n, hw = hdr_tbl.shape
     k = (hw - HDR_WORDS) // 2
-    return pl.pallas_call(
+    words = jax.lax.bitcast_convert_type(hdr_tbl, jnp.int32)
+    desc = words[:, HDR_WORDS:HDR_WORDS + 2 * k]
+    tables = (words[:, :HDR_WORDS], desc[:, 0::2], desc[:, 1::2],
+              jax.lax.bitcast_convert_type(trailers, jnp.int32))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda i: (0, 0))
+
+    status, sub = pl.pallas_call(
         _agg_poll_kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, hw), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1,), lambda i: (i,)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((n,), jnp.int32),
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+                 + [whole(t) for t in tables],
+        out_specs=(pl.BlockSpec((n, 1), lambda i: (0, 0)),
+                   pl.BlockSpec((n, k), lambda i: (0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((n, 1), jnp.int32),
                    jax.ShapeDtypeStruct((n, k), jnp.int32)),
-        interpret=interpret,
-    )(bound, hdr_tbl, trailers)
+        interpret=backend.pallas_interpret(),
+    )(jax.lax.bitcast_convert_type(bound, jnp.int32), *tables)
+    return status[:, 0], sub

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import backend
+
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
 NEG = -1e30
@@ -72,9 +74,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
         lse_ref[0] = (m_s[...] + jnp.log(l)).astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk",
-                                             "interpret"))
-def _flash_fwd(q, k, v, *, scale, window, bq, bk, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk"))
+def _flash_fwd(q, k, v, *, scale, window, bq, bk):
     BH, S, hd = q.shape
     nq, nk = S // bq, S // bk
     grid = (BH, nq, nk)
@@ -100,7 +101,7 @@ def _flash_fwd(q, k, v, *, scale, window, bq, bk, interpret):
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=backend.pallas_interpret(),
     )(q, k, v)
     return o, lse
 
@@ -174,9 +175,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk",
-                                             "interpret"))
-def _flash_bwd(q, k, v, o, lse, do, *, scale, window, bq, bk, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk"))
+def _flash_bwd(q, k, v, o, lse, do, *, scale, window, bq, bk):
     BH, S, hd = q.shape
     nq, nk = S // bq, S // bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -195,7 +195,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, window, bq, bk, interpret):
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.pallas_interpret(),
     )(q, k, v, do, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, window=window,
@@ -219,7 +219,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, window, bq, bk, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.pallas_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -227,26 +227,23 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, window, bq, bk, interpret):
 # ----------------------------------------------------------------- wrapper
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, scale: float, window: int = 0,
-                    bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True):
+                    bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK):
     """q,k,v: [BH, S, hd] (KV pre-repeated to full heads).  Causal always."""
-    o, _ = _flash_fwd(q, k, v, scale=scale, window=window, bq=bq, bk=bk,
-                      interpret=interpret)
+    o, _ = _flash_fwd(q, k, v, scale=scale, window=window, bq=bq, bk=bk)
     return o
 
 
-def _fa_fwd(q, k, v, scale, window, bq, bk, interpret):
-    o, lse = _flash_fwd(q, k, v, scale=scale, window=window, bq=bq, bk=bk,
-                        interpret=interpret)
+def _fa_fwd(q, k, v, scale, window, bq, bk):
+    o, lse = _flash_fwd(q, k, v, scale=scale, window=window, bq=bq, bk=bk)
     return o, (q, k, v, o, lse)
 
 
-def _fa_bwd(scale, window, bq, bk, interpret, res, do):
+def _fa_bwd(scale, window, bq, bk, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, scale=scale, window=window,
-                            bq=bq, bk=bk, interpret=interpret)
+                            bq=bq, bk=bk)
     return dq, dk, dv
 
 

@@ -8,49 +8,23 @@ device GOT — operands name model-resident tensors by slot, bound at launch.
 
 Grid: one step per payload tile; the whole program runs per tile
 (data-parallel μcode).  Instruction streams live in SMEM; register file is
-VMEM scratch.
+VMEM scratch.  Dispatch is a flat run of one ``pl.when`` per opcode: a
+20-way ``lax.switch`` nests 20 deep in Mosaic and overflows the stack of
+its layout inference.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.codegen import N_OPS, OPS, UVM_REGS, UVM_TILE
+from repro import backend
+from repro.core.codegen import OPS, UVM_REGS, UVM_TILE
 
 T = UVM_TILE
 R = UVM_REGS
-
-
-def _branches(va, vb, vd, pt, ev, imm):
-    """Tile-valued result per opcode (indexed by core.codegen.OPS)."""
-    z = jnp.zeros_like(va)
-    return [
-        lambda: vd,                                   # halt  (nop)
-        lambda: pt,                                   # loadp
-        lambda: ev,                                   # loade
-        lambda: vd,                                   # store (side effect below)
-        lambda: va + vb,                              # add
-        lambda: va - vb,                              # sub
-        lambda: va * vb,                              # mul
-        lambda: vd + va * vb,                         # fma
-        lambda: jnp.maximum(va, 0.0),                 # relu
-        lambda: jax.nn.gelu(va),                      # gelu
-        lambda: jnp.exp(va),                          # exp
-        lambda: va * imm,                             # scale
-        lambda: jnp.dot(va, vb, preferred_element_type=jnp.float32),  # matmul
-        lambda: jnp.maximum(va, vb),                  # max
-        lambda: va,                                   # copy
-        lambda: z,                                    # zero
-        lambda: jnp.tanh(va),                         # tanh
-        lambda: jax.lax.rsqrt(jnp.abs(va) + 1e-12),   # rsqrt
-        lambda: va + imm,                             # addi
-        lambda: va * imm,                             # muli
-    ]
 
 
 def _vm_kernel(op_ref, dst_ref, a_ref, b_ref, imm_ref,  # SMEM instr stream
@@ -60,52 +34,61 @@ def _vm_kernel(op_ref, dst_ref, a_ref, b_ref, imm_ref,  # SMEM instr stream
     n_instr = op_ref.shape[0]
     n_ext = ext_ref.shape[0]
 
-    # zero the register file at tile start
+    # a tile starts from a zero register file and a zero output: an output
+    # block no ``store`` reaches is garbage on the chip, not zero
     regs_ref[...] = jnp.zeros((R, T, T), jnp.float32)
+    out_ref[...] = jnp.zeros((1, T, T), jnp.float32)
 
-    def step(pc, _):
+    def step(pc, carry):
         op = op_ref[pc]
         d = dst_ref[pc]
         a = a_ref[pc]
         b = b_ref[pc]
         imm = imm_ref[pc]
-        va = pl.load(regs_ref, (pl.ds(a, 1), slice(None), slice(None)))[0]
-        vb = pl.load(regs_ref, (pl.ds(b, 1), slice(None), slice(None)))[0]
-        vd = pl.load(regs_ref, (pl.ds(d, 1), slice(None), slice(None)))[0]
-        pt = payload_ref[0]
-        ea = jnp.minimum(a, n_ext - 1)
-        ev = pl.load(ext_ref, (pl.ds(ea, 1), slice(None), slice(None)))[0]
-        res = jax.lax.switch(op, _branches(va, vb, vd, pt, ev, imm))
-        pl.store(regs_ref, (pl.ds(d, 1), slice(None), slice(None)), res[None])
+
+        def ra():
+            return regs_ref[a]
+
+        def rb():
+            return regs_ref[b]
+
+        # register-writing opcodes (halt is a nop, store is below)
+        results = {
+            "loadp": lambda: payload_ref[0],
+            "loade": lambda: ext_ref[jnp.minimum(a, n_ext - 1)],
+            "add": lambda: ra() + rb(),
+            "sub": lambda: ra() - rb(),
+            "mul": lambda: ra() * rb(),
+            "fma": lambda: regs_ref[d] + ra() * rb(),
+            "relu": lambda: jnp.maximum(ra(), 0.0),
+            "gelu": lambda: jax.nn.gelu(ra()),
+            "exp": lambda: jnp.exp(ra()),
+            "scale": lambda: ra() * imm,
+            "matmul": lambda: jnp.dot(ra(), rb(),
+                                      precision=jax.lax.Precision.HIGHEST,
+                                      preferred_element_type=jnp.float32),
+            "max": lambda: jnp.maximum(ra(), rb()),
+            "copy": ra,
+            "zero": lambda: jnp.zeros((T, T), jnp.float32),
+            "tanh": lambda: jnp.tanh(ra()),
+            "rsqrt": lambda: jax.lax.rsqrt(jnp.abs(ra()) + 1e-12),
+            "addi": lambda: ra() + imm,
+            "muli": lambda: ra() * imm,
+        }
+        for name, result in results.items():
+            @pl.when(op == OPS[name])
+            def _(result=result):
+                regs_ref[d] = result()
 
         @pl.when(op == OPS["store"])
         def _():
-            out_ref[0] = va
-        return 0
+            out_ref[0] = ra()
+        return carry
 
     jax.lax.fori_loop(0, n_instr, step, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("n_instr", "n_tiles", "n_ext", "interpret"))
-def _vm_call(op, dst, a, b, imm, payload, ext, *, n_instr, n_tiles, n_ext,
-             interpret=True):
-    grid = (n_tiles,)
-    instr_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.pallas_call(
-        _vm_kernel,
-        grid=grid,
-        in_specs=[instr_spec] * 5 + [
-            pl.BlockSpec((1, T, T), lambda i: (i, 0, 0)),          # payload tile
-            pl.BlockSpec((n_ext, T, T), lambda i: (0, 0, 0)),      # ext table
-        ],
-        out_specs=pl.BlockSpec((1, T, T), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, T, T), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((R, T, T), jnp.float32)],
-        interpret=interpret,
-    )(op, dst, a, b, imm, payload, ext)
-
-
-def ifunc_vm(prog, payload_tiles, externals, *, interpret=True):
+def ifunc_vm(prog, payload_tiles, externals):
     """Execute μcode over payload tiles.  externals: [n_ext, T, T] f32."""
     payload = jnp.asarray(payload_tiles, jnp.float32)
     ext = jnp.asarray(externals, jnp.float32)
@@ -114,8 +97,19 @@ def ifunc_vm(prog, payload_tiles, externals, *, interpret=True):
     if ext.shape[0] == 0:
         ext = jnp.zeros((1, T, T), jnp.float32)
     assert payload.ndim == 3 and payload.shape[1:] == (T, T), payload.shape
-    return _vm_call(jnp.asarray(prog.opcode), jnp.asarray(prog.dst),
-                    jnp.asarray(prog.a), jnp.asarray(prog.b),
-                    jnp.asarray(prog.imm), payload, ext,
-                    n_instr=len(prog.opcode), n_tiles=payload.shape[0],
-                    n_ext=ext.shape[0], interpret=interpret)
+    n_tiles, n_ext = payload.shape[0], ext.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        _vm_kernel,
+        grid=(n_tiles,),
+        in_specs=[smem] * 5 + [
+            pl.BlockSpec((1, T, T), lambda i: (i, 0, 0)),          # payload tile
+            pl.BlockSpec((n_ext, T, T), lambda i: (0, 0, 0)),      # ext table
+        ],
+        out_specs=pl.BlockSpec((1, T, T), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, T, T), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((R, T, T), jnp.float32)],
+        interpret=backend.pallas_interpret(),
+    )(jnp.asarray(prog.opcode, jnp.int32), jnp.asarray(prog.dst, jnp.int32),
+      jnp.asarray(prog.a, jnp.int32), jnp.asarray(prog.b, jnp.int32),
+      jnp.asarray(prog.imm, jnp.float32), payload, ext)

@@ -1,16 +1,13 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run with ``interpret=True``; on a real TPU
-set ``REPRO_PALLAS_COMPILE=1`` to lower them natively.  ``ssd_scan_op``
-matches the models/ssm.py chunk layout so the model stack can swap its XLA
-path for the kernel on TPU.
+Kernels run compiled on an accelerator and in interpret mode on the CPU
+(``repro.backend.pallas_interpret``).  ``ssd_scan_op`` matches the
+models/ssm.py chunk layout so the model stack can swap its XLA path for
+the kernel on TPU.
 """
 
 from __future__ import annotations
 
-import os
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -20,10 +17,6 @@ from repro.kernels.ring_poll import ring_poll
 from repro.kernels.ssd_scan import ssd_scan
 
 
-def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
-
 def uvm_execute(prog: UvmProgram, payload_tiles, externals) -> np.ndarray:
     """Device-tier ifunc execution (called by core.api poll for UVM frames)."""
     if len(externals) != len(prog.symbols):
@@ -31,16 +24,15 @@ def uvm_execute(prog: UvmProgram, payload_tiles, externals) -> np.ndarray:
                          f"({prog.symbols}), got {len(externals)}")
     ext = (jnp.stack([jnp.asarray(e, jnp.float32) for e in externals])
            if len(externals) else jnp.zeros((0, UVM_TILE, UVM_TILE)))
-    out = ifunc_vm(prog, payload_tiles, ext, interpret=_interpret())
+    out = ifunc_vm(prog, payload_tiles, ext)
     return np.asarray(out)
 
 
 def mailbox_poll(slots) -> np.ndarray:
     """Validate device mailbox slots -> status per slot."""
-    return np.asarray(ring_poll(jnp.asarray(slots, jnp.uint32),
-                                interpret=_interpret()))
+    return np.asarray(ring_poll(jnp.asarray(slots, jnp.uint32)))
 
 
 def ssd_scan_op(x, la, Bm, Cm):
     """[BH,nc,Q,hd] chunked SSD (kernel path)."""
-    return ssd_scan(x, la, Bm, Cm, interpret=_interpret())
+    return ssd_scan(x, la, Bm, Cm)

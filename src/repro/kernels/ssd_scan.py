@@ -17,12 +17,12 @@ Output:
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import backend
 
 
 def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, state_ref):
@@ -73,8 +73,8 @@ def ssd_hbm_bytes(B, nh, S, hd, ds, *, train: bool, dtype_bytes=2) -> float:
     return fwd * (3.0 if train else 1.0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_scan(x, la, Bm, Cm, *, interpret=True):
+@jax.jit
+def ssd_scan(x, la, Bm, Cm):
     """x [BH,nc,Q,hd], la [BH,nc,Q], Bm/Cm [BH,nc,Q,ds] -> y [BH,nc,Q,hd]."""
     BH, nc, Q, hd = x.shape
     ds = Bm.shape[-1]
@@ -90,5 +90,5 @@ def ssd_scan(x, la, Bm, Cm, *, interpret=True):
         out_specs=pl.BlockSpec((1, 1, Q, hd), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, nc, Q, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.pallas_interpret(),
     )(x, la, Bm, Cm)

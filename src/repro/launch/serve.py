@@ -27,27 +27,36 @@ import time
 import jax
 import numpy as np
 
+from repro.backend import use_compile_cache
 from repro.models import transformer as T
+from repro.models.config import ModelConfig
 from repro.serving import (TINY, IfuncFrontend, Request, Server,
                            ServingFabric)
 
 
-def make_requests(n: int, max_new: int, *, seed: int = 0) -> list[Request]:
+def make_requests(n: int, max_new: int, *, vocab: int,
+                  prompt_lens: tuple[int, ...] = (8,),
+                  seed: int = 0) -> list[Request]:
+    """``n`` random prompts, request i of length ``prompt_lens[i % len]``."""
     rng = np.random.default_rng(seed)
-    return [Request(i, rng.integers(0, TINY.vocab_size, size=8,
+    return [Request(i, rng.integers(0, vocab,
+                                    size=prompt_lens[i % len(prompt_lens)],
                                     dtype=np.int32), max_new=max_new)
             for i in range(n)]
 
 
-def run_host(args, params) -> None:
+def run_host(cfg: ModelConfig, params, reqs: list[Request], *, slots: int,
+             cache_len: int) -> dict[int, Request]:
+    """Serve ``reqs`` on one host: ``Server`` fed by an ``IfuncFrontend``.
+    Returns the finished requests by rid, as the decode path reported
+    them."""
     from repro.core import Context
 
     server_ctx = Context("server")
     fe = IfuncFrontend(server_ctx)
     # ONE bundle across frontend transport + batcher: the final snapshot
     # shows ingest (peer/dispatcher counters) and serving side by side
-    srv = Server(TINY, params, args.slots, args.cache, obs=fe.rt.obs)
-    reqs = make_requests(args.requests, args.steps)
+    srv = Server(cfg, params, slots, cache_len, obs=fe.rt.obs)
     unsubmitted = list(reqs)
     acks = []
     done: dict[int, Request] = {}
@@ -86,7 +95,7 @@ def run_host(args, params) -> None:
     assert stats["timed_out"] == 0, stats
     print(f"served {len(reqs)} requests ({len(acked)} acked, max queue depth "
           f"{max(a['depth'] for a in acked)}), {total} decode tokens in "
-          f"{dt:.2f}s ({total / max(dt, 1e-9):.0f} tok/s, batch={args.slots}); "
+          f"{dt:.2f}s ({total / max(dt, 1e-9):.0f} tok/s, batch={slots}); "
           f"ingest: sent={stats['sent']} slim={stats['slim_sent']} "
           f"delivered={stats['delivered']} backpressure={stats['backpressure']} "
           f"replies={stats['replies']} via {stats['bytes']}B of ifunc frames "
@@ -98,14 +107,16 @@ def run_host(args, params) -> None:
           f"{len(snap['histograms'])} histograms in the registry)")
     for rid in sorted(done)[:2]:
         r = done[rid]
-        print(f"  req {r.rid}: prompt={r.prompt.tolist()} -> {r.out}")
+        print(f"  req {r.rid}: prompt[:8]={r.prompt[:8].tolist()} "
+              f"({len(r.prompt)} tokens) -> {r.out}")
+    return done
 
 
 def run_disagg(args, params) -> None:
     fab = ServingFabric(TINY, params, n_prefill=args.prefill,
                         n_decode=args.decode, batch_slots=args.slots,
                         cache_len=args.cache)
-    reqs = make_requests(args.requests, args.steps)
+    reqs = make_requests(args.requests, args.steps, vocab=TINY.vocab_size)
     t0 = time.time()
     done = fab.run(reqs)
     dt = time.time() - t0
@@ -140,9 +151,13 @@ def main():
     os.environ.setdefault(
         "REPRO_IFUNC_LIB_DIR",
         str(pathlib.Path(__file__).resolve().parents[3] / "ifunc_libs"))
+    use_compile_cache()
     params = T.init_params(TINY, jax.random.PRNGKey(0))
     if args.mode == "host":
-        run_host(args, params)
+        run_host(TINY, params,
+                 make_requests(args.requests, args.steps,
+                               vocab=TINY.vocab_size),
+                 slots=args.slots, cache_len=args.cache)
     else:
         run_disagg(args, params)
 

@@ -172,16 +172,13 @@ def attention_seq_kv(p, x, cfg, *, window: int = 0):
     if cfg.attn_impl == "flash":
         # Pallas flash-attention kernel: scores stay in VMEM (TPU target;
         # interpret-mode on CPU).  [B,S,H,hd] -> [B*H, S, hd].
-        import os
-
         from repro.kernels.flash_attn import flash_attention
 
-        interp = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
         bq = bk = min(max(128, cfg.q_chunk // 8), 512, S)
         qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
         kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
         vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-        of = flash_attention(qf, kf, vf, float(scale), window, bq, bk, interp)
+        of = flash_attention(qf, kf, vf, float(scale), window, bq, bk)
         o = of.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
         o = shard_act(o, "batch", "seq", "act_heads", None)
         out = jnp.einsum("bshk,hkd->bsd", o, p["wo"], preferred_element_type=x.dtype)

@@ -114,18 +114,15 @@ def ssd_seq_cached(p, x, cfg, *, want_cache: bool = False):
         # Pallas ssd_scan kernel: [Q,Q] decay/score tensors stay in VMEM
         # (TPU target; interpret-mode on CPU).  x pre-weighted by Δt; B/C are
         # group-shared, broadcast per head for the [BH,...] kernel layout.
-        import os
-
         from repro.kernels.ssd_scan import ssd_scan as _ssd_kernel
 
-        interp = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
         xk = (xc * dtc[..., None].astype(xc.dtype)) \
             .transpose(0, 3, 1, 2, 4).reshape(B * nh, nc, Q, hd)
         lak = lac.transpose(0, 3, 1, 2).reshape(B * nh, nc, Q)
         bk = jnp.broadcast_to(bc[:, None], (B, nh, nc, Q, ds)).reshape(B * nh, nc, Q, ds)
         ck = jnp.broadcast_to(cc[:, None], (B, nh, nc, Q, ds)).reshape(B * nh, nc, Q, ds)
         yk = _ssd_kernel(xk.astype(jnp.float32), lak, bk.astype(jnp.float32),
-                         ck.astype(jnp.float32), interpret=interp)
+                         ck.astype(jnp.float32))
         y = yk.reshape(B, nh, nc, Q, hd).transpose(0, 2, 3, 1, 4).astype(x.dtype)
         y = y.reshape(B, S, nh, hd)
         y = y + xh * p["D_skip"].astype(x.dtype)[None, None, :, None]

@@ -13,57 +13,29 @@ table, not the model code.
 
 from __future__ import annotations
 
-
 import threading
 from dataclasses import dataclass, field, replace
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# ---------------------------------------------------------------------------
-# Version shims: jax.make_mesh grew an ``axis_types`` kwarg (and
-# jax.sharding.AxisType) in later releases, and shard_map moved from
-# jax.experimental to jax.shard_map; older installs know neither.
-
-import inspect
-
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
+from jax import shard_map as _shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None) -> Mesh:
-    """``jax.make_mesh`` across jax versions.  When the installed jax knows
-    about axis types, every axis defaults to Auto (the behaviour this repo
-    assumes); otherwise the kwarg is dropped."""
-    if _AXIS_TYPE is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (_AXIS_TYPE.Auto,) * len(tuple(axis_names))
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis Auto unless ``axis_types`` says
+    otherwise (the behaviour this repo assumes)."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=axis_types)
 
 
-# shard_map: ``axis_names`` (manual-over-a-subset, new API) maps to the old
-# experimental API's ``auto`` complement.
-try:  # pragma: no cover - version shim
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False, **kw)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-        kw = {}
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - set(axis_names)
-            if auto:
-                kw["auto"] = auto
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=False, **kw)
+def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
+    """``jax.shard_map`` without replication checks; ``axis_names`` makes it
+    manual over that subset of the mesh axes only."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False, **kw)
 
 
 # ---------------------------------------------------------------------------

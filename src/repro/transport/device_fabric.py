@@ -32,11 +32,14 @@ class DeviceMeshMailbox(Mailbox):
 
     def __init__(self, fabric: "DeviceMeshFabric", mesh, axis: str, prog,
                  externals, n_slots: int, n_tiles: int, tile: int = 128,
-                 *, interpret: bool = True, shift: int = 0,
-                 agg_k: int = 0, prog_name: str | None = None):
+                 *, shift: int = 0, agg_k: int = 0,
+                 prog_name: str | None = None):
         super().__init__()
+        import jax
+
         from repro.core.device_mailbox import (empty_mailbox, make_agg_sweep,
-                                               make_deposit, make_sweep)
+                                               make_deposit, make_sweep,
+                                               shard_rows)
         from repro.kernels.ring_poll import HDR_WORDS
 
         self.fabric = fabric
@@ -65,24 +68,27 @@ class DeviceMeshMailbox(Mailbox):
             self.slot_words = HDR_WORDS + self.body_words + 1
             self.slot_size = self.slot_words * 4     # byte-equivalent capacity
         self.prog = prog
-        self.externals = externals                   # [n_shards, n_ext, T, T]
-        self._mb = empty_mailbox(self.n_shards, n_slots, self.slot_words)
+        # ring, staged generations and externals ([n_shards, n_ext, T, T])
+        # all live shard i on device i of the mesh
+        self._sharding = shard_rows(mesh, axis)
+        self.externals = jax.device_put(externals, self._sharding)
+        self._mb = empty_mailbox(mesh, axis, n_slots, self.slot_words)
         self._deposit = make_deposit(mesh, axis)
         if agg_k:
             self._sweep = make_agg_sweep(mesh, axis, prog, agg_k, n_tiles,
-                                         tile, bound_hash=self.bound_hash,
-                                         interpret=interpret)
+                                         tile, bound_hash=self.bound_hash)
         else:
-            self._sweep = make_sweep(mesh, axis, prog, n_tiles, tile,
-                                     interpret=interpret)
+            self._sweep = make_sweep(mesh, axis, prog, n_tiles, tile)
         self._staged: np.ndarray | None = None
         self._staged_count = 0
         self._deposited = 0                          # frames awaiting sweep
         self.results: list = []                      # READY outputs, one entry
         #                                 per consumed container/singleton
         self.last_coords: list[tuple[int, int]] = []  # (shard, slot) per
-        #                                 status of the most recent sweep —
-        #                                 the reply demux correlates device
+        #                                 status of the most recent sweep,
+        #                                 where the frame was *staged* (a
+        #                                 deposit lands ``shift`` shards on)
+        #                                 — the reply demux correlates device
         #                                 results to task corr-ids with this
 
     @property
@@ -110,15 +116,20 @@ class DeviceMeshMailbox(Mailbox):
         """Deposit the staged generation over the ICI (collective_permute)."""
         if self._staged is None:
             return
-        import jax.numpy as jnp
+        import jax
 
-        self._mb = self._deposit(self._mb, jnp.asarray(self._staged),
+        self._mb = self._deposit(self._mb,
+                                 jax.device_put(self._staged, self._sharding),
                                  shift=self.shift)
         self._deposited += self._staged_count
         self._staged = None
         self._staged_count = 0
 
     # target side
+
+    def _staged_at(self, shard: int, slot: int) -> tuple[int, int]:
+        """Coordinates a frame found at (shard, slot) was staged at."""
+        return (shard - self.shift) % self.n_shards, slot
 
     def slot_view(self, i: int):
         raise TransportError("device mailbox slots live in device memory; "
@@ -148,19 +159,20 @@ class DeviceMeshMailbox(Mailbox):
         for shard in range(status.shape[0]):
             for slot in range(status.shape[1]):
                 st = int(status[shard, slot])
+                coord = self._staged_at(shard, slot)
                 if st == READY:
                     self.results.append(out[shard, slot])
                     if isinstance(target_args, dict):
                         target_args.setdefault("results", []).append(
                             out[shard, slot])
                     statuses.append(Status.OK)
-                    self.last_coords.append((shard, slot))
+                    self.last_coords.append(coord)
                 elif st == BAD:
                     statuses.append(Status.REJECTED)
-                    self.last_coords.append((shard, slot))
+                    self.last_coords.append(coord)
                 elif st == INFLIGHT:
                     statuses.append(Status.IN_PROGRESS)
-                    self.last_coords.append((shard, slot))
+                    self.last_coords.append(coord)
         consumed = sum(1 for s in statuses
                        if s in (Status.OK, Status.REJECTED))
         self.head += consumed
@@ -189,6 +201,7 @@ class DeviceMeshMailbox(Mailbox):
         for shard in range(status.shape[0]):
             for slot in range(status.shape[1]):
                 st = int(status[shard, slot])
+                coord = self._staged_at(shard, slot)
                 if st == READY:
                     subs: list[AggSubResult] = []
                     vals: list = []
@@ -210,7 +223,7 @@ class DeviceMeshMailbox(Mailbox):
                         else:                        # SUB_NACK
                             subs.append(AggSubResult(
                                 Status.NACK_UNCACHED, "", b"", 0))
-                    self.last_agg[(shard, slot)] = subs
+                    self.last_agg[coord] = subs
                     while len(self.last_agg) > 2 * self.n_slots:
                         self.last_agg.pop(next(iter(self.last_agg)))
                     # ONE results entry per consumed container keeps the
@@ -222,13 +235,13 @@ class DeviceMeshMailbox(Mailbox):
                     if isinstance(target_args, dict):
                         target_args.setdefault("results", []).extend(vals)
                     statuses.append(Status.OK)
-                    self.last_coords.append((shard, slot))
+                    self.last_coords.append(coord)
                 elif st == BAD:
                     statuses.append(Status.REJECTED)
-                    self.last_coords.append((shard, slot))
+                    self.last_coords.append(coord)
                 elif st == INFLIGHT:
                     statuses.append(Status.IN_PROGRESS)
-                    self.last_coords.append((shard, slot))
+                    self.last_coords.append(coord)
         consumed = sum(1 for s in statuses
                        if s in (Status.OK, Status.REJECTED))
         self.head += consumed
@@ -346,10 +359,8 @@ class DeviceMeshFabric(Fabric):
 
     kind = "device"
 
-    def __init__(self, mesh, axis: str = "model", *, interpret: bool = True,
-                 shift: int = 0):
-        self.mesh, self.axis = mesh, axis
-        self.interpret, self.shift = interpret, shift
+    def __init__(self, mesh, axis: str = "model", *, shift: int = 0):
+        self.mesh, self.axis, self.shift = mesh, axis, shift
 
     def open_mailbox(self, target_ctx, n_slots: int, slot_size: int,
                      *, prog=None, externals=None, n_tiles: int = 1,
@@ -364,15 +375,12 @@ class DeviceMeshFabric(Fabric):
         (mismatches NACK per sub, None = wildcard)."""
         if prog is None:
             raise TransportError("DeviceMeshFabric.open_mailbox needs prog=")
-        import jax.numpy as jnp
-
         n_shards = self.mesh.shape[self.axis]
         if externals is None:
-            externals = jnp.zeros((n_shards, max(prog.n_ext, 1), tile, tile),
-                                  jnp.float32)
+            externals = np.zeros((n_shards, max(prog.n_ext, 1), tile, tile),
+                                 np.float32)
         mb = DeviceMeshMailbox(self, self.mesh, self.axis, prog, externals,
-                               n_slots, n_tiles, tile,
-                               interpret=self.interpret, shift=self.shift,
+                               n_slots, n_tiles, tile, shift=self.shift,
                                agg_k=agg_k, prog_name=prog_name)
         if slot_size < mb.slot_size:
             raise TransportError(

@@ -97,6 +97,17 @@ def test_uvm_bad_magic():
         CG.deserialize_uvm(b"\0" * 64)
 
 
+@pytest.mark.parametrize("field,value", [("opcode", CG.N_OPS), ("dst", -1),
+                                         ("a", CG.UVM_REGS), ("b", 99)])
+def test_uvm_out_of_range_rejected(field, value):
+    """Words the device kernel would index with unchecked are refused when
+    the program arrives."""
+    prog = CG.assemble([("loadp", 0), ("add", 1, 0, 0), ("store", 0, 1)])
+    getattr(prog, field)[1] = value
+    with pytest.raises(CG.CodeVerifyError, match="out of range"):
+        CG.deserialize_uvm(CG.serialize_uvm(prog))
+
+
 # --- HLO -------------------------------------------------------------------
 
 def test_hlo_export_roundtrip():

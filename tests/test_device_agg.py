@@ -129,7 +129,7 @@ def test_agg_poll_kernel_vs_oracle(lib_dir):
 
     status, sub_st = agg_ring_poll(
         jnp.asarray(slots[:, :HDR_WORDS + 2 * K]), jnp.asarray(slots[:, -1:]),
-        jnp.asarray([bound], jnp.uint32), interpret=True)
+        jnp.asarray([bound], jnp.uint32))
     for i in range(6):
         want_st, want_sub = oracle(slots[i])
         assert int(status[i]) == want_st, f"slot {i} container status"

@@ -138,10 +138,10 @@ def test_flash_attention_fwd_bwd(shape):
     q, k, v = (jnp.asarray(RNG.standard_normal((BH, S, hd)), jnp.float32)
                for _ in range(3))
     scale = 1.0 / np.sqrt(hd)
-    o = flash_attention(q, k, v, scale, window, bq, bk, True)
+    o = flash_attention(q, k, v, scale, window, bq, bk)
     np.testing.assert_allclose(np.asarray(o), np.asarray(_ref_attn(q, k, v, scale, window)),
                                rtol=3e-5, atol=3e-5)
-    g = jax.grad(lambda *a: flash_attention(*a, scale, window, bq, bk, True).sum(),
+    g = jax.grad(lambda *a: flash_attention(*a, scale, window, bq, bk).sum(),
                  argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lambda *a: _ref_attn(*a, scale, window).sum(),
                   argnums=(0, 1, 2))(q, k, v)

@@ -123,7 +123,7 @@ for d in range(8):
     frames[d, 0] = pack_word_frame(pay[d], slot_words)
     frames[d, 1] = pack_word_frame(pay[d], slot_words, no_trailer=True)
 
-mb = empty_mailbox(8, NS, slot_words)
+mb = empty_mailbox(mesh, "model", NS, slot_words)
 deposit = make_deposit(mesh, "model")
 mb = deposit(mb, jnp.asarray(frames), shift=1)   # RDMA-put to right neighbor
 ext = jnp.broadcast_to(jnp.ones((1, 1, T, T), jnp.float32) * 2.0, (8, 1, T, T))
@@ -148,6 +148,47 @@ def test_device_mailbox_multidevice():
     r = subprocess.run([sys.executable, "-c", _MAILBOX_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "MAILBOX_OK" in r.stdout, r.stdout + r.stderr
+
+
+_SHIFT_FUTURES_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+from repro.core import Context, register_ifunc
+from repro.core.codegen import deserialize_uvm
+from repro.parallel.sharding import make_mesh
+from repro.tasks import TaskRuntime
+from repro.transport import Dispatcher, ProgressEngine
+from repro.transport.device_fabric import DeviceMeshFabric
+
+T = 128
+mesh = make_mesh((4,), ("model",))
+src = Context("src")
+rt = TaskRuntime(src, Dispatcher(src, ProgressEngine(inflight_window="trailer")))
+h = register_ifunc(src, "uvm_affine")
+rng = np.random.default_rng(0)
+W = (rng.standard_normal((T, T)) / np.sqrt(T)).astype(np.float32)
+rt.add_peer("tpu", DeviceMeshFabric(mesh, "model", shift=1), None, n_slots=2,
+            slot_size=128 << 10, prog=deserialize_uvm(h.lib.code),
+            externals=np.broadcast_to(W, (4, 1, T, T)))
+xs = rng.standard_normal((24, 1, T, T)).astype(np.float32)
+futs = [rt.submit("tpu", h, x) for x in xs]
+for x, f in zip(xs, futs):
+    np.testing.assert_allclose(np.asarray(f.result()), np.maximum(x @ W, 0),
+                               rtol=1e-4, atol=1e-5)
+print("SHIFT_FUTURES_OK")
+"""
+
+
+def test_device_futures_shift_multidevice(lib_dir):
+    """Deposits one shard to the right (shift=1) on a 4-device mesh: each
+    frame executes on its neighbour, and the reply demux still hands every
+    future the result of its own payload across three ring wraps."""
+    env = dict(os.environ, PYTHONPATH=f"{REPO}/src",
+               REPRO_IFUNC_LIB_DIR=str(lib_dir))
+    r = subprocess.run([sys.executable, "-c", _SHIFT_FUTURES_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert "SHIFT_FUTURES_OK" in r.stdout, r.stdout + r.stderr[-3000:]
 
 
 _DRYRUN_SCRIPT = r"""

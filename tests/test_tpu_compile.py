@@ -1,0 +1,138 @@
+"""Compile the device tier's kernels for a described TPU v5e, at real sizes.
+
+Nothing runs: the TPU compiler, installed without a chip, compiles for a
+topology that is described and not attached.  That catches what interpret
+mode cannot (block tiling rules, VMEM/SMEM limits, Mosaic crashes) before
+any chip time is spent.  The topology is described inside a fixture, never
+at import: only one process at a time may load the TPU library.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from repro import backend  # noqa: E402
+from repro.core.codegen import OPS, assemble  # noqa: E402
+from repro.kernels.ring_poll import HDR_WORDS  # noqa: E402
+
+T = 128
+N_TILES = 8                                  # 512 KiB frames
+SLOTS = 64                                   # a 32 MiB singleton ring
+AGG_K, AGG_SLOTS = 64, 16                    # a 64 MiB aggregate ring
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compiled_for_tpu(monkeypatch):
+    """Kernels lower for Mosaic, not the interpreter, and nothing lands in
+    a persistent cache that could never be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(backend, "pallas_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _all_ops_program(n_ext: int):
+    """Every opcode once, plus a load of the last external slot."""
+    body = [(op, 1, 2, 3, 0.5) for op in OPS]
+    return assemble([("loadp", 0), ("loade", 1, n_ext - 1), *body,
+                     ("store", 0, 2)],
+                    symbols=tuple(f"e{i}" for i in range(n_ext)))
+
+
+def _compiled(fn, *args):
+    # a fresh wrapper: jit's trace cache is keyed by function, and a trace
+    # made here (Mosaic) must never serve a CPU caller of ``fn``
+    c = jax.jit(lambda *a: fn(*a)).lower(*args).compile()
+    assert "tpu_custom_call" in c.as_text(), "no Mosaic kernel in the program"
+    return c
+
+
+def test_ring_poll_compiles(one_chip):
+    from repro.kernels.ring_poll import ring_poll
+
+    slot_words = HDR_WORDS + N_TILES * T * T + 1
+    _compiled(ring_poll, jax.ShapeDtypeStruct((SLOTS, slot_words), jnp.uint32,
+                                              sharding=one_chip))
+
+
+def test_agg_ring_poll_compiles(one_chip):
+    from repro.kernels.agg_poll import agg_ring_poll
+
+    u32 = jnp.uint32
+    _compiled(agg_ring_poll,
+              jax.ShapeDtypeStruct((AGG_SLOTS, HDR_WORDS + 2 * AGG_K), u32,
+                                   sharding=one_chip),
+              jax.ShapeDtypeStruct((AGG_SLOTS, 1), u32, sharding=one_chip),
+              jax.ShapeDtypeStruct((1,), u32, sharding=one_chip))
+
+
+def test_ifunc_vm_compiles(one_chip):
+    """512 tiles, 8 externals, all 20 opcodes: the flat per-opcode dispatch
+    compiles where a 20-way lax.switch crashed Mosaic's layout pass."""
+    from repro.kernels.ifunc_vm import ifunc_vm
+
+    prog = _all_ops_program(8)
+    c = _compiled(lambda p, e: ifunc_vm(prog, p, e),
+                  jax.ShapeDtypeStruct((512, T, T), jnp.float32,
+                                       sharding=one_chip),
+                  jax.ShapeDtypeStruct((8, T, T), jnp.float32,
+                                       sharding=one_chip))
+    assert c.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("n_chips,agg_k", [(1, 0), (1, AGG_K), (4, 0),
+                                           (4, AGG_K)])
+def test_sweep_compiles(topo, n_chips, agg_k):
+    """The jitted deposit + sweep of a device lane, singleton or aggregate,
+    on one chip and across the 2x2 mesh (where the deposit is a
+    collective-permute to the right neighbour)."""
+    from repro.core.device_mailbox import (make_agg_sweep, make_deposit,
+                                           make_sweep)
+
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("model",))
+    rows = NamedSharding(mesh, P("model"))
+    prog = assemble([("loadp", 0), ("loade", 1, 0), ("matmul", 2, 0, 1),
+                     ("relu", 2, 2), ("store", 0, 2)], symbols=("W",))
+    if agg_k:
+        slots, n_tiles = AGG_SLOTS, 1
+        slot_words = HDR_WORDS + 2 * agg_k + agg_k * T * T + 1
+        sweep = make_agg_sweep(mesh, "model", prog, agg_k, n_tiles,
+                               bound_hash=0xBEEF)
+    else:
+        slots, n_tiles = SLOTS, N_TILES
+        slot_words = HDR_WORDS + n_tiles * T * T + 1
+        sweep = make_sweep(mesh, "model", prog, n_tiles)
+    ring = jax.ShapeDtypeStruct((n_chips, slots, slot_words), jnp.uint32,
+                                sharding=rows)
+    ext = jax.ShapeDtypeStruct((n_chips, 1, T, T), jnp.float32, sharding=rows)
+    c = sweep.lower(ring, ext).compile()
+    assert "tpu_custom_call" in c.as_text()
+    dep = make_deposit(mesh, "model").lower(ring, ring, shift=1).compile()
+    if n_chips > 1:
+        assert "collective-permute" in dep.as_text()
