@@ -1,0 +1,6 @@
+"""``inv_per_flush.lane`` read in an aggregate-lane cell, where it moves
+``invocations_per_s.agg``."""
+
+from bench.harness import load_module
+
+read = load_module("metrics", "inv_per_flush.lane").read

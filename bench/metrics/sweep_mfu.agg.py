@@ -1,0 +1,6 @@
+"""``sweep_mfu.lane`` read in an aggregate-lane cell, where it moves
+``invocations_per_s.agg``."""
+
+from bench.harness import load_module
+
+read = load_module("metrics", "sweep_mfu.lane").read
