@@ -126,18 +126,23 @@ def make_sweep(mesh, axis: str, prog: UvmProgram, n_tiles: int, tile: int = 128)
     def sweep(mailbox, ext):
         def f(mb, ext_l):
             mb2 = mb[0]                      # [n_slots, slot_words]
-            status = ring_poll(mb2)
-            body = mb2[:, HDR_WORDS:HDR_WORDS + body_words]
-            tiles = jax.lax.bitcast_convert_type(body, jnp.float32)
-            tiles = tiles.reshape(mb2.shape[0] * n_tiles, tile, tile)
-            out = ifunc_vm(prog, tiles, ext_l[0])
-            out = out.reshape(mb2.shape[0], n_tiles, tile, tile)
-            ready = (status == READY)
-            out = out * ready[:, None, None, None].astype(out.dtype)
-            # READY slots are consumed; BAD (rejected) slots are cleared too
-            # so a corrupt frame is reported once, not on every later sweep.
-            done = ready | (status == BAD)
-            cleared = jnp.where(done[:, None], jnp.zeros_like(mb2), mb2)
+            with jax.named_scope("poll"):
+                status = ring_poll(mb2)
+            with jax.named_scope("body"):
+                body = mb2[:, HDR_WORDS:HDR_WORDS + body_words]
+                tiles = jax.lax.bitcast_convert_type(body, jnp.float32)
+                tiles = tiles.reshape(mb2.shape[0] * n_tiles, tile, tile)
+            with jax.named_scope("uvm"):
+                out = ifunc_vm(prog, tiles, ext_l[0])
+                out = out.reshape(mb2.shape[0], n_tiles, tile, tile)
+                ready = (status == READY)
+                out = out * ready[:, None, None, None].astype(out.dtype)
+            with jax.named_scope("clear"):
+                # READY slots are consumed; BAD (rejected) slots are cleared
+                # too so a corrupt frame is reported once, not on every
+                # later sweep.
+                done = ready | (status == BAD)
+                cleared = jnp.where(done[:, None], jnp.zeros_like(mb2), mb2)
             return status[None], out[None], cleared[None]
         return shard_map(
             f, mesh,
@@ -172,16 +177,21 @@ def make_agg_sweep(mesh, axis: str, prog: UvmProgram, agg_k: int,
         def f(mb, ext_l):
             mb2 = mb[0]                      # [n_slots, slot_words]
             n_slots = mb2.shape[0]
-            status, sub_st = agg_ring_poll(mb2[:, :hdr_words], mb2[:, -1:], bound)
-            body = mb2[:, hdr_words:hdr_words + agg_k * body_words]
-            tiles = jax.lax.bitcast_convert_type(body, jnp.float32)
-            tiles = tiles.reshape(n_slots * agg_k * n_tiles, tile, tile)
-            out = ifunc_vm(prog, tiles, ext_l[0])
-            out = out.reshape(n_slots, agg_k, n_tiles, tile, tile)
-            ready = (sub_st == SUB_READY)
-            out = out * ready[:, :, None, None, None].astype(out.dtype)
-            done = (status == READY) | (status == BAD)
-            cleared = jnp.where(done[:, None], jnp.zeros_like(mb2), mb2)
+            with jax.named_scope("poll"):
+                status, sub_st = agg_ring_poll(mb2[:, :hdr_words],
+                                               mb2[:, -1:], bound)
+            with jax.named_scope("body"):
+                body = mb2[:, hdr_words:hdr_words + agg_k * body_words]
+                tiles = jax.lax.bitcast_convert_type(body, jnp.float32)
+                tiles = tiles.reshape(n_slots * agg_k * n_tiles, tile, tile)
+            with jax.named_scope("uvm"):
+                out = ifunc_vm(prog, tiles, ext_l[0])
+                out = out.reshape(n_slots, agg_k, n_tiles, tile, tile)
+                ready = (sub_st == SUB_READY)
+                out = out * ready[:, :, None, None, None].astype(out.dtype)
+            with jax.named_scope("clear"):
+                done = (status == READY) | (status == BAD)
+                cleared = jnp.where(done[:, None], jnp.zeros_like(mb2), mb2)
             return status[None], sub_st[None], out[None], cleared[None]
         return shard_map(
             f, mesh,

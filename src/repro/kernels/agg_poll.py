@@ -104,5 +104,6 @@ def agg_ring_poll(hdr_tbl, trailers, bound):
         out_shape=(jax.ShapeDtypeStruct((n, 1), jnp.int32),
                    jax.ShapeDtypeStruct((n, k), jnp.int32)),
         interpret=backend.pallas_interpret(),
+        name="agg_ring_poll",
     )(jax.lax.bitcast_convert_type(bound, jnp.int32), *tables)
     return status[:, 0], sub

@@ -110,6 +110,7 @@ def ifunc_vm(prog, payload_tiles, externals):
         out_shape=jax.ShapeDtypeStruct((n_tiles, T, T), jnp.float32),
         scratch_shapes=[pltpu.VMEM((R, T, T), jnp.float32)],
         interpret=backend.pallas_interpret(),
+        name="ifunc_vm",
     )(jnp.asarray(prog.opcode, jnp.int32), jnp.asarray(prog.dst, jnp.int32),
       jnp.asarray(prog.a, jnp.int32), jnp.asarray(prog.b, jnp.int32),
       jnp.asarray(prog.imm, jnp.float32), payload, ext)

@@ -71,5 +71,6 @@ def ring_poll(slots):
         out_specs=pl.BlockSpec((n, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=backend.pallas_interpret(),
+        name="ring_poll",
     )(words, trailer)
     return status[:, 0]

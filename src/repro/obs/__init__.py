@@ -8,7 +8,9 @@ Three pillars, one bundle:
   them; the hot paths keep their plain ``+= 1``).
 * :class:`~repro.obs.trace.Tracer` — cross-peer span tracing keyed on
   the transport's ``corr_id``, exportable as Chrome ``trace_event`` JSON
-  (Perfetto-renderable).  Off by default.
+  (Perfetto-renderable).  Off by default.  Its synchronous layer scopes
+  (``Tracer.scope``) also write into a JAX profiler trace while one is
+  collecting.
 * :class:`~repro.obs.recorder.FlightRecorder` — a bounded ring of recent
   transport events, dumped automatically when ``fail_inflight`` /
   ``drain(deadline=)`` declare a peer dead.
@@ -26,7 +28,7 @@ from __future__ import annotations
 from repro.obs.metrics import (Counter, Gauge, Histogram, Registry,
                                delta, merge_snapshots)
 from repro.obs.recorder import FlightRecorder
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import NULL_SCOPE, Span, Tracer, install_gc_scope
 
 
 class Obs:
@@ -51,7 +53,7 @@ class Obs:
         self.rtt_hist = self.metrics.histogram("transport.deliver_us")
         self.sweep_hist = self.metrics.histogram("target.sweep_us")
         self.exec_hist = self.metrics.histogram("target.exec_us")
-        self.reply_hist = self.metrics.histogram("task.reply_us")
+        install_gc_scope()
 
     @property
     def tracing(self) -> bool:
@@ -75,5 +77,5 @@ class Obs:
         return self.metrics.to_text()
 
 
-__all__ = ["Counter", "FlightRecorder", "Gauge", "Histogram", "Obs",
-           "Registry", "Span", "Tracer", "delta", "merge_snapshots"]
+__all__ = ["Counter", "FlightRecorder", "Gauge", "Histogram", "NULL_SCOPE",
+           "Obs", "Registry", "Span", "Tracer", "delta", "merge_snapshots"]

@@ -16,11 +16,22 @@ Export is the ``trace_event`` JSON array format: ``ph:"X"`` complete
 events (ts/dur in microseconds), ``ph:"i"`` instants, and ``ph:"M"``
 thread-name metadata mapping the integer tids back to actor names.
 chrome://tracing and https://ui.perfetto.dev both open the file as-is.
+
+Synchronous layer spans (:meth:`Tracer.scope`, named
+``repro.<layer>.<step>``) have a second sink: while a JAX profiler session
+is collecting they are written into the profiler's own trace as
+``TraceAnnotation`` events, on the same clock as the device's operations,
+so an idle gap of the device is named by the program's work over it.  The
+asynchronous corr-keyed spans (``task:``, ``put:``, ``agg:``) stay in
+memory only: they cover whole round trips, and in the profiler they would
+lie over every gap.
 """
 
 from __future__ import annotations
 
+import gc
 import json
+import sys
 import time
 
 
@@ -42,6 +53,75 @@ class Span:
         self.args = args
 
 
+#: ``jax.profiler.TraceAnnotation`` (jax's ``TraceMe``), once jax has
+#: imported it; its ``is_enabled()`` says whether a profiler collects
+_ANNOTATION = None
+
+
+def _find_annotation():
+    """Look the profiler's annotation up in a jax that is already imported,
+    never importing anything: ``repro.transport`` runs without jax, and a
+    ``gc`` callback may fire in the middle of ``import jax``, before
+    ``jax.profiler`` exists (it is bound only once fully imported)."""
+    global _ANNOTATION
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    if prof is not None:
+        _ANNOTATION = prof.TraceAnnotation
+    return _ANNOTATION
+
+
+class _NullScope:
+    """What :meth:`Tracer.scope` returns while neither sink is on: one
+    shared object, so the off path allocates nothing and reads no clock."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+NULL_SCOPE = _NullScope()
+
+
+class _Scope:
+    """One synchronous span, written to whichever sinks were on at entry."""
+
+    __slots__ = ("tracer", "name", "args", "ann", "span")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict, ann):
+        self.tracer, self.name, self.args = tracer, name, args
+        self.ann = None if ann is None else ann(name, **args)
+        self.span = None
+
+    def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
+        if self.tracer.enabled:
+            self.span = self.tracer.begin(self.name, cat="scope",
+                                          **self.args)
+        return self
+
+    def set_metadata(self, **args) -> None:
+        """Args known only once the work is done (``n`` consumed ...)."""
+        if self.ann is not None:
+            self.ann.set_metadata(**args)
+        if self.span is not None:
+            self.span.args = {**(self.span.args or {}), **args}
+
+    def __exit__(self, *exc):
+        if self.span is not None:
+            self.tracer.end(self.span)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
 class Tracer:
     def __init__(self, enabled: bool = False, max_events: int = 100_000):
         self.enabled = enabled
@@ -58,6 +138,23 @@ class Tracer:
         return (time.perf_counter() - self._epoch) * 1e6
 
     # -- recording ----------------------------------------------------------
+
+    def scope(self, name: str, **args):
+        """A synchronous layer span, ``with tracer.scope("repro.x.y", n=k)
+        as sc: ...``.  Written into the profiler's trace (``args`` become
+        the event's stats) while a profiler session collects, and kept in
+        memory while the tracer is enabled; ``sc.set_metadata(**args)``
+        adds args known only at the end.  With the profiler's sink alone
+        it is the profiler's own annotation; with neither sink on, the
+        shared :data:`NULL_SCOPE`."""
+        ann = _ANNOTATION
+        if ann is None and "jax" in sys.modules:
+            ann = _find_annotation()
+        if ann is not None and not ann.is_enabled():
+            ann = None
+        if not self.enabled:
+            return NULL_SCOPE if ann is None else ann(name, **args)
+        return _Scope(self, name, args, ann)
 
     def begin(self, name: str, cat: str = "", actor: str = "",
               corr=None, **args):
@@ -143,4 +240,31 @@ class Tracer:
         return doc
 
 
-__all__ = ["Span", "Tracer"]
+class _GcScope:
+    """``gc.callbacks`` hook: each collection of the host's garbage is a
+    ``repro.host.gc`` scope, so a gap the host spent collecting is named
+    as such in the profiler's trace."""
+
+    def __init__(self):
+        self.tracer = Tracer()
+        self.open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            sc = self.tracer.scope("repro.host.gc",
+                                   generation=info["generation"])
+            if sc is not NULL_SCOPE:
+                sc.__enter__()
+                self.open = sc
+        elif self.open is not None:
+            sc, self.open = self.open, None
+            sc.__exit__(None, None, None)
+
+
+def install_gc_scope() -> None:
+    """Register the ``repro.host.gc`` hook, once per process."""
+    if not any(isinstance(cb, _GcScope) for cb in gc.callbacks):
+        gc.callbacks.append(_GcScope())
+
+
+__all__ = ["NULL_SCOPE", "Span", "Tracer", "install_gc_scope"]

@@ -13,7 +13,6 @@ never a barrier.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 import jax.numpy as jnp
@@ -64,7 +63,6 @@ class ContinuousBatcher:
         self._installed = m.counter(f"serve.{name}.installed")
         self._decoded = m.counter(f"serve.{name}.decoded")
         self._finished = m.counter(f"serve.{name}.finished")
-        self.install_hist = m.histogram(f"serve.{name}.install_us")
 
     def free_slots(self) -> list[int]:
         return [s for s in range(self.B) if s not in self.active]
@@ -79,33 +77,29 @@ class ContinuousBatcher:
             raise ValueError(f"slot {slot} already active")
         if not (0 < pos0 <= self.W):
             raise ValueError(f"pos0 {pos0} outside cache width {self.W}")
-        t0 = time.perf_counter()
-        src = dict(cache1)
-        for k, tgt in self._one.items():
-            if k not in src and k.endswith("slot_pos"):
-                base = synth_slot_pos(pos0, tgt.shape[-1])
-                src[k] = jnp.asarray(np.broadcast_to(base, tgt.shape))
-        src = SRV.pad_cache_to(src, self._one)
-        tr = self.obs.tracer
-        sp = tr.begin(f"kv_install:{self.name}", cat="serve",
-                      actor=self.name) if tr.enabled else None
-        for k in self.cache:
-            bdim = next((i for i, (a, b) in enumerate(
-                zip(self._full[k].shape, self._one[k].shape)) if a != b), None)
-            row = src[k].astype(self.cache[k].dtype)
-            if bdim is None:            # batch-free entry: shared write
-                self.cache[k] = row
-            else:
-                idx = tuple([slice(None)] * bdim + [slice(slot, slot + 1)])
-                self.cache[k] = self.cache[k].at[idx].set(row)
-        if sp is not None:
-            tr.end(sp)
+        with self.obs.tracer.scope("repro.serve.install"):
+            src = dict(cache1)
+            for k, tgt in self._one.items():
+                if k not in src and k.endswith("slot_pos"):
+                    base = synth_slot_pos(pos0, tgt.shape[-1])
+                    src[k] = jnp.asarray(np.broadcast_to(base, tgt.shape))
+            src = SRV.pad_cache_to(src, self._one)
+            for k in self.cache:
+                bdim = next((i for i, (a, b) in enumerate(
+                    zip(self._full[k].shape, self._one[k].shape)) if a != b),
+                    None)
+                row = src[k].astype(self.cache[k].dtype)
+                if bdim is None:            # batch-free entry: shared write
+                    self.cache[k] = row
+                else:
+                    idx = tuple([slice(None)] * bdim
+                                + [slice(slot, slot + 1)])
+                    self.cache[k] = self.cache[k].at[idx].set(row)
         self.tokens[slot, 0] = int(first_token)
         self.pos[slot] = pos0
         self.active[slot] = req
         req.out.append(int(first_token))
         self._installed.inc()
-        self.install_hist.observe((time.perf_counter() - t0) * 1e6)
 
     def tick(self) -> tuple[int, list[Request]]:
         """One decode step for all active slots.  Returns (#tokens
@@ -113,10 +107,13 @@ class ContinuousBatcher:
         decode path, never at admission time."""
         if not self.active:
             return 0, []
-        self.cache, logits = self._decode(self.params, self.cache,
-                                          jnp.asarray(self.tokens),
-                                          jnp.asarray(self.pos))
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+        tr = self.obs.tracer
+        with tr.scope("repro.serve.decode_dispatch", n=len(self.active)):
+            self.cache, logits = self._decode(self.params, self.cache,
+                                              jnp.asarray(self.tokens),
+                                              jnp.asarray(self.pos))
+        with tr.scope("repro.serve.token_wait"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
         emitted, finished = 0, []
         for slot, req in list(self.active.items()):
             tok = int(nxt[slot])

@@ -66,8 +66,10 @@ class Server:
             self._admit_full.inc()
             return False
         t0 = time.perf_counter()
-        cache1, last = self._prefill(self.params, {"tokens": req.prompt[None]})
-        first = int(jnp.argmax(last[0, -1]))
+        with self.obs.tracer.scope("repro.serve.prefill", n=len(req.prompt)):
+            cache1, last = self._prefill(self.params,
+                                         {"tokens": req.prompt[None]})
+            first = int(jnp.argmax(last[0, -1]))
         self.batcher.install(free[0], cache1, len(req.prompt), first, req)
         self._admitted.inc()
         self.admit_hist.observe((time.perf_counter() - t0) * 1e6)

@@ -26,8 +26,6 @@ the graph workload (``examples/graph_analysis.py``) sit on.
 
 from __future__ import annotations
 
-import time
-
 from repro.core import frame as F
 from repro.tasks import wire
 from repro.tasks.future import Future, TaskState, TaskTimeout, wait_all
@@ -253,10 +251,6 @@ class TaskRuntime:
         if fut is None:                      # duplicate / expired corr-id
             self.stats["orphan_replies"] += 1
             return
-        o = self.obs
-        if o.enabled:
-            o.reply_hist.observe(
-                (time.monotonic() - fut.submitted_at) * 1e6)
         if not decoded and not isinstance(value, wire.RemoteExecutionError):
             try:
                 value = wire.decode(value)

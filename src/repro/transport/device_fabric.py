@@ -21,14 +21,30 @@ Kept in its own module so ``repro.transport`` imports without jax.
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 from repro.core import frame as F
+from repro.obs.trace import Tracer
 from repro.transport.fabric import Channel, Fabric, Mailbox, TransportError
+
+
+#: the scopes of a lane no dispatcher has handed its bundle to
+_NO_TRACER = Tracer()
 
 
 class DeviceMeshMailbox(Mailbox):
     """Ring of word-frame slots on every shard of a 1-D device mesh."""
+
+    #: the ``repro.obs.Obs`` bundle of the dispatcher that opened this lane
+    #: (the lane has no target context): its scopes, its channel's, and
+    #: ``target.sweep_us`` go there
+    obs = None
+
+    @property
+    def tracer(self) -> Tracer:
+        return _NO_TRACER if self.obs is None else self.obs.tracer
 
     def __init__(self, fabric: "DeviceMeshFabric", mesh, axis: str, prog,
                  externals, n_slots: int, n_tiles: int, tile: int = 128,
@@ -113,7 +129,9 @@ class DeviceMeshMailbox(Mailbox):
         self._staged_count += 1
 
     def _publish(self) -> None:
-        """Deposit the staged generation over the ICI (collective_permute)."""
+        """Deposit the staged generation over the ICI (collective_permute):
+        the whole generation is copied to the device, however few slots it
+        holds."""
         if self._staged is None:
             return
         import jax
@@ -142,43 +160,66 @@ class DeviceMeshMailbox(Mailbox):
         round (its yield still counts against the caller's total budget).
         READY results land in ``self.results`` and
         ``target_args['results']``."""
-        from repro.core.api import Status
-        from repro.kernels.ring_poll import BAD, INFLIGHT, READY
-
         if self._deposited == 0:
             self.last_coords = []
             return []
-        if self.agg_k:
-            return self._sweep_agg(target_args)
-        status, out, cleared = self._sweep(self._mb, self.externals)
-        status = np.asarray(status)
-        out = np.asarray(out)
-        self._mb = cleared
-        statuses: list = []
-        self.last_coords = []
-        for shard in range(status.shape[0]):
-            for slot in range(status.shape[1]):
-                st = int(status[shard, slot])
-                coord = self._staged_at(shard, slot)
-                if st == READY:
-                    self.results.append(out[shard, slot])
-                    if isinstance(target_args, dict):
-                        target_args.setdefault("results", []).append(
-                            out[shard, slot])
-                    statuses.append(Status.OK)
-                    self.last_coords.append(coord)
-                elif st == BAD:
-                    statuses.append(Status.REJECTED)
-                    self.last_coords.append(coord)
-                elif st == INFLIGHT:
-                    statuses.append(Status.IN_PROGRESS)
-                    self.last_coords.append(coord)
+        o = self.obs
+        t0 = time.perf_counter() if o is not None and o.enabled else None
+        consumed0 = self.consumed
+        statuses = (self._sweep_agg if self.agg_k
+                    else self._sweep_one)(target_args)
+        if t0 is not None and self.consumed != consumed0:
+            # as on a host lane: only sweeps that consumed something observe
+            o.sweep_hist.observe((time.perf_counter() - t0) * 1e6)
+        return statuses
+
+    def _run_sweep(self) -> list:
+        """The jitted sweep over the ring, its outputs read back to the
+        host (blocking); the cleared ring stays on the device."""
+        with self.tracer.scope("repro.device.readback") as sc:
+            *outs, self._mb = self._sweep(self._mb, self.externals)
+            host = [np.asarray(a) for a in outs]
+            sc.set_metadata(bytes=sum(h.nbytes for h in host))
+        return host
+
+    def _sweep_one(self, target_args) -> list:
+        from repro.core.api import Status
+        from repro.kernels.ring_poll import BAD, INFLIGHT, READY
+
+        status, out = self._run_sweep()
+        with self.tracer.scope("repro.device.demux") as sc:
+            statuses: list = []
+            self.last_coords = []
+            for shard in range(status.shape[0]):
+                for slot in range(status.shape[1]):
+                    st = int(status[shard, slot])
+                    coord = self._staged_at(shard, slot)
+                    if st == READY:
+                        self.results.append(out[shard, slot])
+                        if isinstance(target_args, dict):
+                            target_args.setdefault("results", []).append(
+                                out[shard, slot])
+                        statuses.append(Status.OK)
+                        self.last_coords.append(coord)
+                    elif st == BAD:
+                        statuses.append(Status.REJECTED)
+                        self.last_coords.append(coord)
+                    elif st == INFLIGHT:
+                        statuses.append(Status.IN_PROGRESS)
+                        self.last_coords.append(coord)
+            sc.set_metadata(n=self._consume(statuses))
+        return statuses
+
+    def _consume(self, statuses) -> int:
+        """Advance the consume counters past a sweep's OK/REJECTED slots."""
+        from repro.core.api import Status
+
         consumed = sum(1 for s in statuses
                        if s in (Status.OK, Status.REJECTED))
         self.head += consumed
         self.consumed += consumed
         self._deposited = max(self._deposited - consumed, 0)
-        return statuses
+        return consumed
 
     def _sweep_agg(self, target_args) -> list:
         """Aggregate-container sweep: one batched kernel pass validates all
@@ -191,62 +232,56 @@ class DeviceMeshMailbox(Mailbox):
         from repro.kernels.agg_poll import SUB_BAD, SUB_EMPTY, SUB_READY
         from repro.kernels.ring_poll import BAD, INFLIGHT, READY
 
-        status, sub_st, out, cleared = self._sweep(self._mb, self.externals)
-        status = np.asarray(status)
-        sub_st = np.asarray(sub_st)
-        out = np.asarray(out)
-        self._mb = cleared
-        statuses: list = []
-        self.last_coords = []
-        for shard in range(status.shape[0]):
-            for slot in range(status.shape[1]):
-                st = int(status[shard, slot])
-                coord = self._staged_at(shard, slot)
-                if st == READY:
-                    subs: list[AggSubResult] = []
-                    vals: list = []
-                    for i in range(self.agg_k):
-                        s_i = int(sub_st[shard, slot, i])
-                        if s_i == SUB_EMPTY:
-                            break
-                        if s_i == SUB_READY:
-                            subs.append(AggSubResult(
-                                Status.OK, "", b"", 0,
-                                value=out[shard, slot, i]))
-                            vals.append(out[shard, slot, i])
-                        elif s_i == SUB_BAD:
-                            subs.append(AggSubResult(
-                                Status.REJECTED, "", b"", 0,
-                                error=TransportError(
-                                    "poisoned sub-record (descriptor "
-                                    "check mismatch)")))
-                        else:                        # SUB_NACK
-                            subs.append(AggSubResult(
-                                Status.NACK_UNCACHED, "", b"", 0))
-                    self.last_agg[coord] = subs
-                    while len(self.last_agg) > 2 * self.n_slots:
-                        self.last_agg.pop(next(iter(self.last_agg)))
-                    # ONE results entry per consumed container keeps the
-                    # dispatcher's per-status result cursor aligned: a
-                    # 1-sub container (transcoded singleton) yields its
-                    # bare output, a K-sub one the per-sub list
-                    entry = vals[0] if len(subs) == 1 and vals else vals
-                    self.results.append(entry)
-                    if isinstance(target_args, dict):
-                        target_args.setdefault("results", []).extend(vals)
-                    statuses.append(Status.OK)
-                    self.last_coords.append(coord)
-                elif st == BAD:
-                    statuses.append(Status.REJECTED)
-                    self.last_coords.append(coord)
-                elif st == INFLIGHT:
-                    statuses.append(Status.IN_PROGRESS)
-                    self.last_coords.append(coord)
-        consumed = sum(1 for s in statuses
-                       if s in (Status.OK, Status.REJECTED))
-        self.head += consumed
-        self.consumed += consumed
-        self._deposited = max(self._deposited - consumed, 0)
+        status, sub_st, out = self._run_sweep()
+        with self.tracer.scope("repro.device.demux") as sc:
+            statuses: list = []
+            self.last_coords = []
+            for shard in range(status.shape[0]):
+                for slot in range(status.shape[1]):
+                    st = int(status[shard, slot])
+                    coord = self._staged_at(shard, slot)
+                    if st == READY:
+                        subs: list[AggSubResult] = []
+                        vals: list = []
+                        for i in range(self.agg_k):
+                            s_i = int(sub_st[shard, slot, i])
+                            if s_i == SUB_EMPTY:
+                                break
+                            if s_i == SUB_READY:
+                                subs.append(AggSubResult(
+                                    Status.OK, "", b"", 0,
+                                    value=out[shard, slot, i]))
+                                vals.append(out[shard, slot, i])
+                            elif s_i == SUB_BAD:
+                                subs.append(AggSubResult(
+                                    Status.REJECTED, "", b"", 0,
+                                    error=TransportError(
+                                        "poisoned sub-record (descriptor "
+                                        "check mismatch)")))
+                            else:                    # SUB_NACK
+                                subs.append(AggSubResult(
+                                    Status.NACK_UNCACHED, "", b"", 0))
+                        self.last_agg[coord] = subs
+                        while len(self.last_agg) > 2 * self.n_slots:
+                            self.last_agg.pop(next(iter(self.last_agg)))
+                        # ONE results entry per consumed container keeps the
+                        # dispatcher's per-status result cursor aligned: a
+                        # 1-sub container (transcoded singleton) yields its
+                        # bare output, a K-sub one the per-sub list
+                        entry = vals[0] if len(subs) == 1 and vals else vals
+                        self.results.append(entry)
+                        if isinstance(target_args, dict):
+                            target_args.setdefault("results",
+                                                   []).extend(vals)
+                        statuses.append(Status.OK)
+                        self.last_coords.append(coord)
+                    elif st == BAD:
+                        statuses.append(Status.REJECTED)
+                        self.last_coords.append(coord)
+                    elif st == INFLIGHT:
+                        statuses.append(Status.IN_PROGRESS)
+                        self.last_coords.append(coord)
+            sc.set_metadata(n=self._consume(statuses))
         return statuses
 
 
@@ -266,6 +301,15 @@ class DeviceMeshChannel(Channel):
         the ICI — a SLIM frame (code elided at the source) transcodes
         identically to a FULL one, and the payload is read through a
         zero-copy section view straight out of the sender's slab."""
+        with self.mailbox.tracer.scope("repro.device.transcode",
+                                       bytes=len(data)) as sc:
+            sc.set_metadata(n=self._transcode(data, slot, deliver_bytes))
+        self.stats["puts"] += 1
+        self.stats["bytes"] += len(data)
+
+    def _transcode(self, data, slot: int, deliver_bytes: int | None) -> int:
+        """Parse, pack into a word-frame and stage; returns the number of
+        invocations the frame carries."""
         from repro.core.device_mailbox import pack_agg_word_frame, pack_word_frame
 
         mb = self.mailbox
@@ -339,17 +383,21 @@ class DeviceMeshChannel(Channel):
             self._pending_trailers = getattr(self, "_pending_trailers", [])
             self._pending_trailers.append((slot, word_idx, TRAILER))
             self.stats["partial"] += 1
-        self.stats["puts"] += 1
-        self.stats["bytes"] += len(data)
+        return batch.n if hdr.is_agg else 1
 
     def flush(self) -> None:
         mb = self.mailbox
-        for slot, word_idx, trailer in getattr(self, "_pending_trailers", []):
-            shard, idx = mb.slot_coords(slot)
-            if mb._staged is not None:
-                mb._staged[shard, idx, word_idx] = trailer
-        self._pending_trailers = []
-        mb._publish()
+        staged = mb._staged
+        with mb.tracer.scope(
+                "repro.device.publish", n=mb._staged_count,
+                bytes=0 if staged is None else staged.nbytes):
+            for slot, word_idx, trailer in getattr(self, "_pending_trailers",
+                                                   []):
+                shard, idx = mb.slot_coords(slot)
+                if staged is not None:
+                    staged[shard, idx, word_idx] = trailer
+            self._pending_trailers = []
+            mb._publish()
         self.stats["flushes"] += 1
 
 

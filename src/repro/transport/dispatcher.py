@@ -116,7 +116,7 @@ from collections import deque
 from dataclasses import dataclass, field, replace
 
 from repro.core import frame as F
-from repro.obs import Obs
+from repro.obs import NULL_SCOPE, Obs
 from repro.transport import codec as WC
 from repro.transport.fabric import Fabric, TransportError
 from repro.transport.progress import ProgressEngine
@@ -486,6 +486,10 @@ class Dispatcher:
             mb = fabric.open_mailbox(target_ctx, n_slots, slot_size,
                                      **mailbox_kw)
             ch = fabric.connect(self.src_ctx, mb)
+            if hasattr(mb, "obs"):
+                # a lane with no target context (the device lane) traces
+                # its own layers into this bundle
+                mb.obs = self.obs
             peer.rings.append(RingState(mb, ch))
         peer.stripe = stripe and rings > 1
         self.peers[name] = peer
@@ -1153,12 +1157,14 @@ class Dispatcher:
                     # records the policy declines to aggregate pay
                     # singleton cost, not singleton + coalescing-
                     # machinery cost)
-                    used = init(view[:mx], mx, args, sz)
-                    used = mx if used in (None, 0) else int(used)
                     cid = corr_ids[i] if corr_ids else 0
-                    fl = F.seal_frame(slab, name, b"", kind, used,
-                                      digest=digest, slim=True,
-                                      corr_id=cid)
+                    with (self.obs.tracer.scope("repro.dispatch.pack", n=1)
+                          if is_device else NULL_SCOPE):
+                        used = init(view[:mx], mx, args, sz)
+                        used = mx if used in (None, 0) else int(used)
+                        fl = F.seal_frame(slab, name, b"", kind, used,
+                                          digest=digest, slim=True,
+                                          corr_id=cid)
                     self._post_view(peer, lane, slab[:fl],
                                     _TxRec(name, digest, handle,
                                            slim=True, corr_id=cid),
@@ -1167,51 +1173,55 @@ class Dispatcher:
                     n += 1
                     i += 1
                     continue             # slot consumed: repick a lane
-                off = F.begin_agg(view, [name])
-                prologue_end = off
-                hdrs: list[tuple] = []
-                subs: list[_PendingSub] = []
-                hdr_add, sub_add = hdrs.append, subs.append
-                budget = len(view) - 4
-                n_subs = 0
-                stop = False
-                # the inner loop IS the per-message cost of a coalesced
-                # burst: the sub-header row is built inline (plain
-                # records: name_idx 0, no flags, no cont) and the payload
-                # view is sliced once when the codec fills its estimate
-                while i < N and n_subs < max_subs:
-                    args = payloads[i]
-                    try:
-                        sz = len(args)
-                    except TypeError:
-                        sz = 0
-                    mx = int(gms(args, sz))
-                    if not is_device and full_base + mx > cap:
-                        stop = True      # FULL fallback cannot fit a ring
-                        break            # slot: the generic loop errors
-                    if mx > max_sub_bytes:
-                        break            # seal the container first; the
-                        #                  outer peek re-sees this record
-                    n_subs += 1
-                    if off + mx + n_subs * sub_fixed > budget:
-                        n_subs -= 1
-                        break            # container full: seal + continue
-                    pv = view[off:off + mx]
-                    used = init(pv, mx, args, sz)
-                    used = mx if used in (None, 0) else int(used)
-                    cid = corr_ids[i] if corr_ids else 0
-                    hdr_add((0, kind_int, 0, digest, cid, used, 0))
-                    sub_add(_PendingSub(
-                        handle, name, kind, digest,
-                        pv if used == mx else view[off:off + used],
-                        cid, None, futures[i] if futures else None, now))
-                    off += used
-                    i += 1
+                with self.obs.tracer.scope("repro.dispatch.pack") as sc:
+                    off = F.begin_agg(view, [name])
+                    prologue_end = off
+                    hdrs: list[tuple] = []
+                    subs: list[_PendingSub] = []
+                    hdr_add, sub_add = hdrs.append, subs.append
+                    budget = len(view) - 4
+                    n_subs = 0
+                    stop = False
+                    # the inner loop IS the per-message cost of a coalesced
+                    # burst: the sub-header row is built inline (plain
+                    # records: name_idx 0, no flags, no cont) and the payload
+                    # view is sliced once when the codec fills its estimate
+                    while i < N and n_subs < max_subs:
+                        args = payloads[i]
+                        try:
+                            sz = len(args)
+                        except TypeError:
+                            sz = 0
+                        mx = int(gms(args, sz))
+                        if not is_device and full_base + mx > cap:
+                            stop = True      # FULL fallback cannot fit a ring
+                            break            # slot: the generic loop errors
+                        if mx > max_sub_bytes:
+                            break            # seal the container first; the
+                            #                  outer peek re-sees this record
+                        n_subs += 1
+                        if off + mx + n_subs * sub_fixed > budget:
+                            n_subs -= 1
+                            break            # container full: seal + continue
+                        pv = view[off:off + mx]
+                        used = init(pv, mx, args, sz)
+                        used = mx if used in (None, 0) else int(used)
+                        cid = corr_ids[i] if corr_ids else 0
+                        hdr_add((0, kind_int, 0, digest, cid, used, 0))
+                        sub_add(_PendingSub(
+                            handle, name, kind, digest,
+                            pv if used == mx else view[off:off + used],
+                            cid, None, futures[i] if futures else None, now))
+                        off += used
+                        i += 1
+                    if subs:
+                        plen = F.finish_agg(view, prologue_end, off, hdrs)
+                        fl = F.seal_frame(slab, F.AGG_NAME, b"", kind, plen,
+                                          digest=F.NO_DIGEST,
+                                          flags=F.FLAG_AGG)
+                        sc.set_metadata(n=len(subs))
                 if not subs:
                     break
-                plen = F.finish_agg(view, prologue_end, off, hdrs)
-                fl = F.seal_frame(slab, F.AGG_NAME, b"", kind,
-                                  plen, digest=F.NO_DIGEST, flags=F.FLAG_AGG)
                 futs = [s.future for s in subs if s.future is not None]
                 self._post_view(peer, lane, slab[:fl],
                                 _TxRec(F.AGG_NAME, F.NO_DIGEST, None,
@@ -1250,13 +1260,17 @@ class Dispatcher:
         """Pack queued sub-records into the lane's slab cell and post: one
         container, one credit.  A single queued record ships as a plain
         SLIM singleton — the aggregate wrapper is never latency overhead."""
+        pack = (self.obs.tracer.scope("repro.dispatch.pack", n=len(subs))
+                if peer.fabric.kind == "device" else NULL_SCOPE)
         if len(subs) == 1:
             sub = subs[0]
             lib = sub.handle.lib
             slab = self.engine.slab_slot(lane.channel, lane.tail)
-            n = F.pack_frame_into(slab, lib.name, b"", sub.payload, lib.kind,
-                                  digest=lib.code_digest, slim=True,
-                                  corr_id=sub.corr_id, cont=sub.cont)
+            with pack:
+                n = F.pack_frame_into(slab, lib.name, b"", sub.payload,
+                                      lib.kind, digest=lib.code_digest,
+                                      slim=True, corr_id=sub.corr_id,
+                                      cont=sub.cont)
             self._post_view(peer, lane, slab[:n],
                             _TxRec(lib.name, lib.code_digest, sub.handle,
                                    slim=True, corr_id=sub.corr_id),
@@ -1267,7 +1281,8 @@ class Dispatcher:
         # carries the records' code kind: the device put rejects non-UVM
         # frames at the header, before parsing the payload.
         slab = self.engine.slab_slot(lane.channel, lane.tail)
-        n = F.seal_agg_frame(slab, subs, kind=subs[0].kind)
+        with pack:
+            n = F.seal_agg_frame(slab, subs, kind=subs[0].kind)
         futs = [s.future for s in subs if s.future is not None]
         rec = _TxRec(F.AGG_NAME, F.NO_DIGEST, None, slim=True,
                      subs=list(subs))
@@ -1493,7 +1508,8 @@ class Dispatcher:
         max_size = int(lib.payload_get_max_size(source_args, source_args_size))
         cont_len = 0 if cont is None else len(cont)
         slim = self._slim_ok(peer, lib)
-        if slim and peer.fabric.kind != "device":
+        is_device = peer.fabric.kind == "device"
+        if slim and not is_device:
             self._check_full_fits(lane, lib, max_size, cont_len)
         code = b"" if slim else lib.code
         slab = self.engine.slab_slot(lane.channel, lane.tail)
@@ -1501,12 +1517,15 @@ class Dispatcher:
                 + F.TRAILER_LEN) > len(slab):
             raise TransportError(
                 f"frame would exceed slot {lane.mailbox.slot_size}B")
-        pv = F.frame_payload_view(slab, len(code), max_size)
-        used = lib.payload_init(pv, max_size, source_args, source_args_size)
-        used = max_size if used in (None, 0) else int(used)
-        n = F.seal_frame(slab, lib.name, code, lib.kind, used,
-                         digest=lib.code_digest, slim=slim, corr_id=corr_id,
-                         cont=cont)
+        with (self.obs.tracer.scope("repro.dispatch.pack", n=1)
+              if is_device else NULL_SCOPE):
+            pv = F.frame_payload_view(slab, len(code), max_size)
+            used = lib.payload_init(pv, max_size, source_args,
+                                    source_args_size)
+            used = max_size if used in (None, 0) else int(used)
+            n = F.seal_frame(slab, lib.name, code, lib.kind, used,
+                             digest=lib.code_digest, slim=slim,
+                             corr_id=corr_id, cont=cont)
         self._post_view(peer, lane, slab[:n],
                         _TxRec(lib.name, lib.code_digest, handle, slim,
                                corr_id=corr_id),
@@ -1940,123 +1959,139 @@ class Dispatcher:
                     coords = getattr(lane.mailbox, "last_coords", None)
                     res_new = list(getattr(lane.mailbox, "results",
                                            ())[res_before:])
-                ri = 0                       # cursor over res_new
-                for i, st in enumerate(sts):
-                    rec = None
-                    coord = (coords[i] if coords is not None
-                             and i < len(coords) else None)
-                    if st in (Status.OK, Status.REJECTED,
-                              Status.NACK_UNCACHED):
-                        if track:
-                            rec = lane.inflight.pop(slot, None)
-                        elif coord is not None:
-                            rec = lane.agg_by_coords.pop(coord, None)
-                        slot += 1
-                    if st == Status.OK:
-                        progressed = True
-                        if not track:
-                            # one results entry lands per device container
-                            # (aggregate or singleton): consume the cursor
-                            # BEFORE branching so later statuses in this
-                            # sweep stay aligned
-                            val = res_new[ri] if ri < len(res_new) else None
-                            ri += 1
-                        if rec is not None and rec.subs is not None:
-                            # aggregate container: per-sub-record
-                            # completion (cache confirms, individual NACK
-                            # rebuilds, one coalesced reply)
-                            done += self._complete_agg(
-                                peer, lane, rec,
-                                coord if not track
-                                else lane.mailbox.slot_coords(slot - 1))
-                            continue
-                        peer.stats["delivered"] += 1
-                        done += 1
-                        if rec is not None:
-                            peer.cached.add(rec.digest)
-                            if rec.stream is not None:
-                                rec.stream.dead = True   # complete: pump off
-                            o = self.obs
-                            if o.enabled:
-                                o.rtt_hist.observe(
-                                    (time.monotonic() - rec.sent_at) * 1e6)
-                                if rec.span is not None:
-                                    o.tracer.end(rec.span, status="ok")
-                                    rec.span = None
-                        if not track:
-                            ent = (lane.corr_by_coords.pop(coord, None)
-                                   if coord is not None else None)
-                            if ent:          # device reply: the result IS it
-                                self._route_reply(ent[0], peer.name, val,
-                                                  False, decoded=True)
-                    elif st == Status.REJECTED:
-                        peer.stats["rejected"] += 1
-                        done += 1
-                        progressed = True
-                        if rec is not None:
-                            o = self.obs
-                            if o.enabled:
-                                o.recorder.add(
-                                    "reject", peer.name,
-                                    f"{rec.name} corr={rec.corr_id}")
-                                if rec.span is not None:
-                                    o.tracer.end(rec.span,
-                                                 status="rejected")
-                                    rec.span = None
-                        if rec is not None and rec.stream is not None:
-                            # corrupt stream: ONLY this stream dies — stop
-                            # its pump; the scrubbed slot flows on
-                            rec.stream.dead = True
-                        if rec is not None and rec.subs is not None:
-                            # whole container rejected (corrupt aggregate
-                            # signal): every corr-carrying record resolves
-                            # with the transport error — none executed
-                            for sub in rec.subs:
-                                if sub.corr_id:
+                # the device lane's completions are a traced layer; a host
+                # lane's per-message poll stays free of the scope's cost
+                sc = (self.obs.tracer.scope("repro.dispatch.complete")
+                      if sts and not track else None)
+                if sc is not None:
+                    sc.__enter__()
+                done0 = done
+                try:
+                    ri = 0                       # cursor over res_new
+                    for i, st in enumerate(sts):
+                        rec = None
+                        coord = (coords[i] if coords is not None
+                                 and i < len(coords) else None)
+                        if st in (Status.OK, Status.REJECTED,
+                                  Status.NACK_UNCACHED):
+                            if track:
+                                rec = lane.inflight.pop(slot, None)
+                            elif coord is not None:
+                                rec = lane.agg_by_coords.pop(coord, None)
+                            slot += 1
+                        if st == Status.OK:
+                            progressed = True
+                            if not track:
+                                # one results entry lands per device container
+                                # (aggregate or singleton): consume the cursor
+                                # BEFORE branching so later statuses in this
+                                # sweep stay aligned
+                                val = (res_new[ri] if ri < len(res_new)
+                                       else None)
+                                ri += 1
+                            if rec is not None and rec.subs is not None:
+                                # aggregate container: per-sub-record
+                                # completion (cache confirms, individual NACK
+                                # rebuilds, one coalesced reply)
+                                done += self._complete_agg(
+                                    peer, lane, rec,
+                                    coord if not track
+                                    else lane.mailbox.slot_coords(slot - 1))
+                                continue
+                            peer.stats["delivered"] += 1
+                            done += 1
+                            if rec is not None:
+                                peer.cached.add(rec.digest)
+                                if rec.stream is not None:
+                                    # complete: pump off
+                                    rec.stream.dead = True
+                                o = self.obs
+                                if o.enabled:
+                                    o.rtt_hist.observe(
+                                        (time.monotonic() - rec.sent_at) * 1e6)
+                                    if rec.span is not None:
+                                        o.tracer.end(rec.span, status="ok")
+                                        rec.span = None
+                            if not track:
+                                ent = (lane.corr_by_coords.pop(coord, None)
+                                       if coord is not None else None)
+                                if ent:  # device reply: the result IS it
+                                    self._route_reply(ent[0], peer.name, val,
+                                                      False, decoded=True)
+                        elif st == Status.REJECTED:
+                            peer.stats["rejected"] += 1
+                            done += 1
+                            progressed = True
+                            if rec is not None:
+                                o = self.obs
+                                if o.enabled:
+                                    o.recorder.add(
+                                        "reject", peer.name,
+                                        f"{rec.name} corr={rec.corr_id}")
+                                    if rec.span is not None:
+                                        o.tracer.end(rec.span,
+                                                     status="rejected")
+                                        rec.span = None
+                            if rec is not None and rec.stream is not None:
+                                # corrupt stream: ONLY this stream dies —
+                                # stop its pump; the scrubbed slot flows on
+                                rec.stream.dead = True
+                            if rec is not None and rec.subs is not None:
+                                # whole container rejected (corrupt aggregate
+                                # signal): every corr-carrying record resolves
+                                # with the transport error — none executed
+                                for sub in rec.subs:
+                                    if sub.corr_id:
+                                        self._route_reply(
+                                            sub.corr_id, peer.name,
+                                            TransportError(
+                                                "aggregate container "
+                                                "rejected"),
+                                            True, decoded=True)
+                            if not track and coord is not None:
+                                ent = lane.corr_by_coords.pop(coord, None)
+                                corr = ent[0] if ent else 0
+                                if corr:
                                     self._route_reply(
-                                        sub.corr_id, peer.name,
-                                        TransportError(
-                                            "aggregate container rejected"),
+                                        corr, peer.name,
+                                        "frame rejected on device sweep",
                                         True, decoded=True)
-                        if not track and coord is not None:
-                            ent = lane.corr_by_coords.pop(coord, None)
-                            corr = ent[0] if ent else 0
-                            if corr:
-                                self._route_reply(
-                                    corr, peer.name,
-                                    "frame rejected on device sweep",
-                                    True, decoded=True)
-                    elif st == Status.NACK_UNCACHED:
-                        peer.stats["nacks"] += 1
-                        self.stats["nacks"] += 1
-                        progressed = True
-                        if rec is not None:
-                            o = self.obs
-                            if o.enabled:
-                                o.recorder.add(
-                                    "nack", peer.name,
-                                    f"{rec.name} corr={rec.corr_id} "
-                                    f"slim miss")
-                                if rec.span is not None:
-                                    o.tracer.end(rec.span, status="nack")
-                                    rec.span = None
-                        if rec is not None and rec.stream is not None:
-                            # SLIM stream missed the cache at its
-                            # descriptor: park the pump and queue a FULL
-                            # re-open from chunk 0 (nothing executed)
-                            rec.stream.dead = True
-                            peer.cached.discard(rec.digest)
-                            peer.resend.append(_StreamResend(rec.stream))
-                        elif rec is not None and rec.handle is not None:
-                            peer.cached.discard(rec.digest)
-                            peer.resend.append(
-                                self._rebuild_full(lane, slot - 1, rec))
-                        else:
-                            # a SLIM frame we have no record/handle for (raw
-                            # send): nothing to rebuild — surface the loss
-                            peer.stats["nack_lost"] += 1
-                    elif st == Status.IN_PROGRESS:
-                        peer.stats["inflight_polls"] += 1
+                        elif st == Status.NACK_UNCACHED:
+                            peer.stats["nacks"] += 1
+                            self.stats["nacks"] += 1
+                            progressed = True
+                            if rec is not None:
+                                o = self.obs
+                                if o.enabled:
+                                    o.recorder.add(
+                                        "nack", peer.name,
+                                        f"{rec.name} corr={rec.corr_id} "
+                                        f"slim miss")
+                                    if rec.span is not None:
+                                        o.tracer.end(rec.span, status="nack")
+                                        rec.span = None
+                            if rec is not None and rec.stream is not None:
+                                # SLIM stream missed the cache at its
+                                # descriptor: park the pump and queue a FULL
+                                # re-open from chunk 0 (nothing executed)
+                                rec.stream.dead = True
+                                peer.cached.discard(rec.digest)
+                                peer.resend.append(_StreamResend(rec.stream))
+                            elif rec is not None and rec.handle is not None:
+                                peer.cached.discard(rec.digest)
+                                peer.resend.append(
+                                    self._rebuild_full(lane, slot - 1, rec))
+                            else:
+                                # a SLIM frame we have no record/handle
+                                # for (raw send): nothing to rebuild —
+                                # surface the loss
+                                peer.stats["nack_lost"] += 1
+                        elif st == Status.IN_PROGRESS:
+                            peer.stats["inflight_polls"] += 1
+                finally:
+                    if sc is not None:
+                        sc.set_metadata(n=done - done0)
+                        sc.__exit__(None, None, None)
                 if peer.stripe:
                     # rotation advances one step per consumed slot, so the
                     # next visit reads the ring the next post landed in

@@ -30,6 +30,7 @@ from collections import deque
 from dataclasses import dataclass, field
 
 from repro.core import frame as F
+from repro.obs.trace import NULL_SCOPE
 from repro.transport.fabric import Channel
 
 _TRAILER_BYTES = F.TRAILER.to_bytes(F.TRAILER_LEN, "little")
@@ -85,7 +86,7 @@ class ProgressEngine:
                       "auto_flushes": 0, "callbacks": 0, "slab_bytes": 0,
                       "futures_sent": 0}
         #: repro.obs.Obs bundle — installed by the owning Dispatcher so
-        #: flush spans land in the same trace as its put/poll spans
+        #: flush scopes land in the same trace as its put/poll spans
         self.obs = None
 
     # -- send slabs ---------------------------------------------------------
@@ -229,39 +230,35 @@ class ProgressEngine:
         keys = [id(channel)] if channel is not None else list(self._outstanding)
         n = 0
         o = self.obs
-        sp = None
-        if (o is not None and o.enabled and o.tracer.enabled
-                and any(self._outstanding.get(k) for k in keys)):
-            sp = o.tracer.begin("flush", cat="engine",
-                                actor="engine",
-                                channels=sum(1 for k in keys
-                                             if self._outstanding.get(k)))
-        for key in keys:
-            handles = self._outstanding.pop(key, [])
-            if not handles:
-                continue
-            # drop the channel ref once drained (re-registered on next post)
-            # so removed peers' rings don't stay reachable from the engine
-            ch = self._channels.pop(key)
-            ch.flush()
-            for h in handles:
-                h.done = True
-                self.completion_queue.append(
-                    Completion(h.seq, h.peer, h.nbytes, h.slot))
-                if h.future is not None:
-                    futs = (h.future if isinstance(h.future, (list, tuple))
-                            else (h.future,))
-                    for f in futs:
-                        f._mark_sent(h.seq)
-                    self.stats["futures_sent"] += len(futs)
-                if h.on_complete is not None:
-                    h.on_complete(h)
-                    self.stats["callbacks"] += 1
-                n += 1
+        with (o.tracer.scope("repro.engine.flush")
+              if o is not None and self._outstanding else NULL_SCOPE) as sc:
+            for key in keys:
+                handles = self._outstanding.pop(key, [])
+                if not handles:
+                    continue
+                # drop the channel ref once drained (re-registered on next
+                # post) so removed peers' rings don't stay reachable from
+                # the engine
+                ch = self._channels.pop(key)
+                ch.flush()
+                for h in handles:
+                    h.done = True
+                    self.completion_queue.append(
+                        Completion(h.seq, h.peer, h.nbytes, h.slot))
+                    if h.future is not None:
+                        futs = (h.future
+                                if isinstance(h.future, (list, tuple))
+                                else (h.future,))
+                        for f in futs:
+                            f._mark_sent(h.seq)
+                        self.stats["futures_sent"] += len(futs)
+                    if h.on_complete is not None:
+                        h.on_complete(h)
+                        self.stats["callbacks"] += 1
+                    n += 1
+            sc.set_metadata(n=n)
         self.stats["completed"] += n
         self.stats["flushes"] += 1
-        if sp is not None:
-            o.tracer.end(sp, completions=n)
         return n
 
     def progress(self) -> int:

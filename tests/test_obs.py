@@ -288,3 +288,240 @@ def test_set_tracing_toggles_midrun(lib_dir):
     d.drain()
     assert obs.tracer.spans(cat="wire")
     assert obs.tracer.open_count() == 0
+
+
+# ---------------------------------------------------------------------------
+# layer scopes: the in-memory sink and the profiler's own trace
+
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` inside a profiler session and a ``bench.window``
+    annotation; the trace's ``bench.*`` and ``repro.*`` host events, as
+    the benchmark's ``Events`` (no device planes on the CPU)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from bench import trace_reduce as TR
+
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation(TR.WINDOW):
+            body()
+    pd = ProfileData.from_file(TR.find_xplane(str(tmp_path)))
+    host = [(e.name, float(e.start_ns), float(e.duration_ns), dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(("bench.", "repro."))]
+    return TR.Events({}, {}, host)
+
+
+def test_scope_records_in_memory_when_enabled():
+    tr = Tracer(enabled=True)
+    with tr.scope("repro.device.demux", n=3) as sc:
+        sc.set_metadata(bytes=7)
+    (sp,) = tr.spans(cat="scope")
+    assert sp.name == "repro.device.demux" and sp.dur >= 0
+    assert sp.args == {"n": 3, "bytes": 7}
+    assert tr.open_count() == 0
+
+
+def test_scope_writes_profiler_event_with_stats(tmp_path):
+    tr = Tracer()                        # in-memory sink off: profiler only
+
+    def body():
+        with tr.scope("repro.device.publish", n=2, bytes=4096):
+            pass
+        with tr.scope("repro.device.demux") as sc:
+            sc.set_metadata(n=5)
+
+    ev = _profiled(tmp_path, body)
+    got = {name: stats for name, _, _, stats in ev.host
+           if name.startswith("repro.device.")}
+    assert got["repro.device.publish"]["n"] == 2
+    assert got["repro.device.publish"]["bytes"] == 4096
+    assert got["repro.device.demux"]["n"] == 5
+    assert tr.events == []
+
+
+def test_scope_off_is_one_null_object(monkeypatch):
+    import time
+    import tracemalloc
+
+    from repro.obs import NULL_SCOPE
+
+    tr = Tracer()
+    assert tr.scope("repro.a") is tr.scope("repro.b", n=1) is NULL_SCOPE
+
+    def no_clock():
+        raise AssertionError("the off path read the clock")
+    monkeypatch.setattr(time, "perf_counter", no_clock)
+    tracemalloc.start()
+    try:
+        base, _ = tracemalloc.get_traced_memory()
+        for _ in range(10_000):
+            with tr.scope("repro.device.transcode", n=1, bytes=4096) as sc:
+                assert sc is NULL_SCOPE
+                sc.set_metadata(n=2)
+        now, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    # nothing is kept: the few hundred bytes are the loop's own
+    assert now - base < 1024 and peak - base < 1024
+    assert tr.events == []
+
+
+def test_scope_nested_under_bench_names_the_gap(tmp_path):
+    """The reduction's rule over the program's scopes: a device gap that
+    lies under both ``bench.submit`` and a program scope inside it is
+    named after the inner scope (the benchmark's loader keeps ``bench.*``
+    events only; a later benchmark change adds ``repro.*``)."""
+    import time
+
+    import jax
+
+    from bench import trace_reduce as TR
+
+    tr = Tracer()
+
+    def body():
+        with jax.profiler.TraceAnnotation("bench.submit"):
+            time.sleep(0.002)
+            with tr.scope("repro.device.publish"):
+                time.sleep(0.01)
+            time.sleep(0.002)
+
+    ev = _profiled(tmp_path, body)
+    (s, d) = next((s, d) for n, s, d, _ in ev.host
+                  if n == "repro.device.publish")
+    lo, hi = TR.window_bounds(ev)
+    # the device busy up to the scope's start and from its end on: the
+    # gap between is covered alike by the benchmark's span and the scope
+    ev.device_ops["/device:TPU:0"] = [("%a = f32[1]{0} fusion()", lo, s - lo),
+                                      ("%b = f32[1]{0} fusion()", s + d,
+                                       hi - s - d)]
+    red = TR.reduce(ev)
+    assert red.idle_gaps[0][0] == "repro.device.publish"
+    assert red.idle_gaps[0][1] == pytest.approx(d * 1e-9)
+
+
+def test_host_lane_opens_no_dispatch_scopes(lib_dir):
+    """The dispatcher's pack and complete scopes belong to device lanes: a
+    host lane's per-message path opens none, even with tracing on (its
+    engine flush still does)."""
+    obs = Obs("t", trace=True)
+    d, _ = _mk(lib_dir, obs)
+    h = register_ifunc(d.src_ctx, "rle_insert")
+    for payload in (b"a", b"b", b"c"):
+        assert d.send_ifunc("p", h, payload)
+        d.drain()
+    names = {sp.name for sp in obs.tracer.spans(cat="scope")}
+    assert "repro.engine.flush" in names
+    assert not {n for n in names if n.startswith("repro.dispatch.")}
+    assert d.peers["p"].stats["delivered"] == 3
+
+
+def test_gc_collection_is_a_scope(tmp_path):
+    import gc
+
+    Obs("t")                             # installs the process's one hook
+    Obs("u")
+    assert sum(type(cb).__name__ == "_GcScope" for cb in gc.callbacks) == 1
+    ev = _profiled(tmp_path, lambda: gc.collect(1))
+    gcs = [stats for name, _, _, stats in ev.host if name == "repro.host.gc"]
+    assert gcs and any(st.get("generation") == 1 for st in gcs)
+
+
+def test_gc_scope_quiet_while_jax_imports():
+    """A collection in the middle of ``import jax`` (a dispatcher made
+    first, in a host-only process): the hook sees a ``jax`` module with no
+    ``profiler`` yet, imports nothing and raises nothing."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys, types\n"
+            "from repro.obs import Obs\n"
+            "from repro.obs.trace import NULL_SCOPE, Tracer, _GcScope\n"
+            "Obs('t')\n"
+            "sys.modules['jax'] = types.ModuleType('jax')  # half imported\n"
+            "before = set(sys.modules)\n"
+            "hook = _GcScope()\n"
+            "hook('start', {'generation': 0})\n"
+            "hook('stop', {'generation': 0})\n"
+            "assert Tracer().scope('repro.x') is NULL_SCOPE\n"
+            "assert set(sys.modules) == before, set(sys.modules) - before\n"
+            "print('ok')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr
+
+
+def _device_lane(lib_dir, obs, n_slots=4):
+    import jax
+    import numpy as np
+
+    from repro.core.codegen import deserialize_uvm
+    from repro.parallel.sharding import make_mesh
+    from repro.tasks import TaskRuntime
+    from repro.transport.device_fabric import DeviceMeshFabric
+
+    T = 128
+    mesh = make_mesh((len(jax.devices()),), ("model",))
+    src = Context("src", lib_dir=lib_dir)
+    h = register_ifunc(src, "uvm_affine")
+    d = Dispatcher(src, ProgressEngine(inflight_window="trailer",
+                                       flush_threshold=2), obs=obs)
+    rt = TaskRuntime(src, d)
+    w = np.eye(T, dtype=np.float32)[None, None]
+    rt.add_peer("tpu", DeviceMeshFabric(mesh, "model"), None,
+                n_slots=n_slots, slot_size=(T * T + 64) * 4 + (64 << 10),
+                prog=deserialize_uvm(h.lib.code),
+                externals=np.broadcast_to(w, (mesh.shape["model"], 1, T, T)),
+                n_tiles=1)
+    return rt, h
+
+
+def test_device_lane_scopes_bytes_and_sweep_hist(tmp_path, lib_dir):
+    """A traced device lane on the CPU: every lane scope is written, the
+    publish scopes' bytes are one staged generation per flush, and
+    ``target.sweep_us`` observes once per sweep that consumed frames."""
+    import numpy as np
+
+    obs = Obs("t")
+    rt, h = _device_lane(lib_dir, obs)
+    ring = rt.dispatcher.peers["tpu"].rings[0]
+    ch, mb = ring.channel, ring.mailbox
+    assert mb.obs is obs
+    x = np.ones((1, 128, 128), np.float32)
+    futs = []
+
+    def body():
+        for _ in range(3):
+            futs.extend(rt.submit("tpu", h, x) for _ in range(2))
+            while any(not f.done() for f in futs):
+                rt.progress()
+
+    ev = _profiled(tmp_path, body)
+    assert all(f.exception() is None for f in futs)
+    names = [n for n, _, _, _ in ev.host]
+    for scope in ("dispatch.pack", "engine.flush", "device.transcode",
+                  "device.publish", "device.readback", "device.demux",
+                  "dispatch.complete"):
+        assert f"repro.{scope}" in names, scope
+    stats = {}
+    for n, _, _, st in ev.host:
+        stats.setdefault(n, []).append(st)
+    generation = mb.n_shards * mb.n_slots_per_shard * mb.slot_words * 4
+    pub = stats["repro.device.publish"]
+    assert len(pub) == ch.stats["flushes"] == 3
+    assert sum(st["bytes"] for st in pub) == generation * 3
+    assert sum(st["n"] for st in pub) == 6
+    assert sum(st["n"] for st in stats["repro.device.transcode"]) == 6
+    # a sweep reads back every slot's int32 status and its f32 output tile
+    back = stats["repro.device.readback"]
+    per_sweep = mb.n_shards * mb.n_slots_per_shard * (4 + 128 * 128 * 4)
+    assert back and all(st["bytes"] == per_sweep for st in back)
+    consuming = sum(st["n"] > 0 for st in stats["repro.device.demux"])
+    assert sum(st["n"] for st in stats["repro.device.demux"]) == 6
+    assert obs.sweep_hist.count == consuming >= 3

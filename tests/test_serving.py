@@ -237,6 +237,32 @@ def test_host_server_completion_off_decode_path(params):
     assert "admitted=0" in line2 and "decoded=0" in line2
 
 
+def test_host_server_scopes_once_per_admit_and_tick(params):
+    """The serving step's layer scopes: prefill and install once per
+    admit, decode dispatch and token wait once per tick."""
+    from repro.obs import Obs
+
+    obs = Obs("t", trace=True)
+    srv = Server(TINY, params, batch_slots=4, cache_len=32, obs=obs)
+    reqs = _reqs(2, max_new=4)
+    for r in reqs:
+        assert srv.admit(r)
+    for _ in range(3):
+        srv.tick()
+    spans = obs.tracer.spans(cat="scope")
+    count = {n: sum(s.name == n for s in spans) for n in (
+        "repro.serve.prefill", "repro.serve.install",
+        "repro.serve.decode_dispatch", "repro.serve.token_wait")}
+    assert count == {"repro.serve.prefill": 2, "repro.serve.install": 2,
+                     "repro.serve.decode_dispatch": 3,
+                     "repro.serve.token_wait": 3}
+    prefill = [s for s in spans if s.name == "repro.serve.prefill"]
+    assert [s.args["n"] for s in prefill] == [len(r.prompt) for r in reqs]
+    assert all(s.args["n"] == 2 for s in spans
+               if s.name == "repro.serve.decode_dispatch")
+    assert obs.tracer.open_count() == 0
+
+
 # ---------------------------------------------------------------------------
 # the disaggregated fabric
 

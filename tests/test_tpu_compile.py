@@ -133,6 +133,28 @@ def test_sweep_compiles(topo, n_chips, agg_k):
     ext = jax.ShapeDtypeStruct((n_chips, 1, T, T), jnp.float32, sharding=rows)
     c = sweep.lower(ring, ext).compile()
     assert "tpu_custom_call" in c.as_text()
+    _kernels_keep_their_names(c.as_text(), agg_k)
     dep = make_deposit(mesh, "model").lower(ring, ring, shift=1).compile()
     if n_chips > 1:
         assert "collective-permute" in dep.as_text()
+
+
+def _kernels_keep_their_names(text: str, agg_k: int):
+    """The sweep's kernels carry their own names into the compiled module
+    (so a trace's breakdown names them), and the roofline readers, which
+    find them by HLO signature, still match each one and nothing else."""
+    from bench.harness import load_module
+
+    uvm = load_module("metrics", "uvm_roofline.lane").KERNEL
+    poll = load_module("metrics", "poll_roofline.lane").KERNEL
+    calls = {}
+    for line in text.splitlines():
+        op = line.strip().removeprefix("ROOT ")
+        if "tpu_custom_call" in op:
+            calls[op.split(" = ")[0].lstrip("%").split(".")[0]] = op
+    poll_name = "agg_ring_poll" if agg_k else "ring_poll"
+    assert set(calls) == {"ifunc_vm", poll_name}
+    assert uvm.match(calls["ifunc_vm"]) and not poll.match(calls["ifunc_vm"])
+    assert poll.match(calls[poll_name]) and not uvm.match(calls[poll_name])
+    assert "/uvm/ifunc_vm/" in calls["ifunc_vm"]
+    assert f"/poll/{poll_name}/" in calls[poll_name]
