@@ -12,8 +12,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard_act
+from repro.parallel.sharding import (current_mesh, logical_sharding, shard_act,
+                                     shard_map)
 
 # ---------------------------------------------------------------------------
 # generic param plumbing
@@ -221,80 +223,103 @@ def attention_seq(p, x, cfg, *, window: int = 0):
 
 def attn_cache_specs(cfg, batch: int, cache_len: int, *,
                      per_slot: bool = False) -> dict[str, Spec]:
-    """KV-cache layout.  ``per_slot=True`` gives every batch row its own
-    ``slot_pos`` vector ([batch, cache_len] instead of the shared
+    """KV-cache layout: keys and values ``[batch, Kv, hd, cache_len]``, the
+    ring's positions on lanes.  ``per_slot=True`` gives every batch row its
+    own ``slot_pos`` vector ([batch, cache_len] instead of the shared
     [cache_len]) — the layout continuous batching needs so sequences at
     different positions coexist in one cache."""
     Kv, hd = cfg.num_kv_heads, cfg.head_dim
     sp_shape = (batch, cache_len) if per_slot else (cache_len,)
     sp_axes = ("cache_batch", "cache_seq") if per_slot else ("cache_seq",)
+    kv_axes = ("cache_batch", "cache_kv_heads", None, "cache_seq")
     return {
-        "k": ((batch, cache_len, Kv, hd), ("cache_batch", "cache_seq", "cache_kv_heads", None)),
-        "v": ((batch, cache_len, Kv, hd), ("cache_batch", "cache_seq", "cache_kv_heads", None)),
+        "k": ((batch, Kv, hd, cache_len), kv_axes),
+        "v": ((batch, Kv, hd, cache_len), kv_axes),
         "slot_pos": (sp_shape, sp_axes),
     }
 
 
-def attention_decode(p, x, cfg, cache, pos, *, window: int = 0):
-    """Single-token decode against a (possibly ring) KV cache.
+def _write_columns(k_all, v_all, k_new, v_new, layer, slot):
+    """The kernel's column write; under a mesh, per shard of the cache
+    (a row whose slot another shard of the ring holds writes nothing)."""
+    # imported here: Pallas takes over a second to import, and only a
+    # process that decodes needs it
+    from repro.kernels.kv_write import kv_column_write
 
-    x: [B,1,D]; cache k/v: [B,W,Kv,hd].  Two cache layouts share this
-    implementation, distinguished by ``slot_pos``'s rank:
+    mesh = current_mesh()
+    if mesh is None:
+        return kv_column_write(k_all, v_all, k_new, v_new, layer, slot)
+    axes = ("stack", "cache_batch", "cache_kv_heads", None, "cache_seq")
+    cs = logical_sharding(axes, shape=k_all.shape).spec
+    cs = P(*cs, *([None] * (5 - len(cs))))
+    ns, ss = P(cs[1], cs[2], None), P(cs[1])
+    seq = cs[4] if isinstance(cs[4], tuple) else (cs[4],) if cs[4] else ()
 
-    * **wave batching** (``slot_pos: [W]``, shared): ``pos`` is a scalar
+    def local(k_all, v_all, k_new, v_new, layer, slot):
+        w = k_all.shape[-1]
+        shard = 0
+        for a in seq:
+            shard = shard * mesh.shape[a] + jax.lax.axis_index(a)
+        s = slot - shard * w
+        s = jnp.where((s >= 0) & (s < w), s, -1)
+        return kv_column_write(k_all, v_all, k_new, v_new, layer, s)
+
+    return shard_map(local, mesh, in_specs=(cs, cs, ns, ns, P(), ss),
+                     out_specs=(cs, cs))(k_all, v_all, k_new, v_new, layer, slot)
+
+
+def attention_decode(p, x, cfg, cache, pos, layer, *, window: int = 0):
+    """Single-token decode against layer ``layer`` of a stacked (possibly
+    ring) KV cache, updated in place.
+
+    x: [B,1,D]; cache k/v: [L,B,Kv,hd,W].  Each row's new key and value
+    go into column ``pos[b] % W`` of layer ``layer`` (the one write the
+    step makes: ``kernels/kv_write.py``), then the layer is read straight
+    from the stacked buffer.  Two ``slot_pos`` layouts share this
+    implementation, distinguished by its rank:
+
+    * **wave batching** (``slot_pos: [L,W]``, shared): ``pos`` is a scalar
       int32 — every row writes the same ring slot and advances in
       lockstep (the legacy single-wave layout).
-    * **continuous batching** (``slot_pos: [B,W]``, per row): ``pos`` may
-      be a ``[B]`` int32 vector — each row writes its own ring slot
-      ``pos[b] % W`` and masks against its own validity row, so
-      sequences admitted mid-wave decode at unequal positions.
+    * **continuous batching** (``slot_pos: [L,B,W]``, per row): ``pos``
+      may be a ``[B]`` int32 vector — each row writes its own ring slot
+      and masks against its own validity row, so sequences admitted
+      mid-wave decode at unequal positions.
 
     Returns ([B,1,D], new_cache).  Grouped-query attention; the cache
-    stays at Kv heads and its seq axis is sharded (sequence-parallel
-    decode).
+    stays at Kv heads, and under a mesh each shard writes its own columns.
     """
     B = x.shape[0]
     H, Kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = cfg.group_size
-    per_slot = cache["slot_pos"].ndim == 2
-    W = cache["k"].shape[1]
-    if per_slot:
-        pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-        positions = pos_v[:, None]
-        q, k_new, v_new = _qkv(p, x, cfg, positions)
-        slot = (pos_v % W).astype(jnp.int32)
-        b_idx = jnp.arange(B)
-        k = cache["k"].at[b_idx, slot].set(k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[b_idx, slot].set(v_new[:, 0].astype(cache["v"].dtype))
-        slot_pos = cache["slot_pos"].at[b_idx, slot].set(pos_v)
+    W = cache["k"].shape[-1]
+    pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q, k_new, v_new = _qkv(p, x, cfg, pos_v[:, None])
+    slot = (pos_v % W).astype(jnp.int32)
+    k_all, v_all = _write_columns(cache["k"], cache["v"], k_new[:, 0],
+                                  v_new[:, 0], layer, slot)
+    if cache["slot_pos"].ndim == 3:
+        slot_pos = cache["slot_pos"].at[layer, jnp.arange(B), slot].set(pos_v)
+        sp = slot_pos[layer]                                   # [B,W]
     else:
-        positions = jnp.full((B, 1), pos, dtype=jnp.int32)
-        q, k_new, v_new = _qkv(p, x, cfg, positions)
-        slot = (pos % W).astype(jnp.int32)
-        k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype), (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype), (0, slot, 0, 0))
-        slot_pos = jax.lax.dynamic_update_slice(cache["slot_pos"], pos[None].astype(jnp.int32), (slot,))
+        slot_pos = cache["slot_pos"].at[layer, slot[0]].set(pos_v[0])
+        sp = slot_pos[layer][None]                             # [1,W]
+    k, v = k_all[layer], v_all[layer]                          # [B,Kv,hd,W]
 
     qg = q.reshape(B, Kv, G, hd)
     qg = shard_act(qg, "cache_batch", "cache_kv_heads", None, None)
-    s_ = jnp.einsum("bkgd,btkd->bkgt", qg, k, preferred_element_type=jnp.float32)
+    s_ = jnp.einsum("bkgd,bkdt->bkgt", qg, k, preferred_element_type=jnp.float32)
     s_ = _softcap(s_ / np.sqrt(hd), cfg.attn_logit_softcap)
-    if per_slot:
-        valid = (slot_pos >= 0) & (slot_pos <= pos_v[:, None])
-        if window:
-            valid &= slot_pos > pos_v[:, None] - window
-        s_ = jnp.where(valid[:, None, None, :], s_, -1e30)
-    else:
-        valid = (slot_pos >= 0) & (slot_pos <= pos)
-        if window:
-            valid &= slot_pos > pos - window
-        s_ = jnp.where(valid[None, None, None, :], s_, -1e30)
+    valid = (sp >= 0) & (sp <= pos_v[:, None])
+    if window:
+        valid &= sp > pos_v[:, None] - window
+    s_ = jnp.where(valid[:, None, None, :], s_, -1e30)
     pr = jax.nn.softmax(s_, axis=-1).astype(x.dtype)
     pr = shard_act(pr, "cache_batch", "cache_kv_heads", None, "cache_seq")
-    o = jnp.einsum("bkgt,btkd->bkgd", pr, v, preferred_element_type=x.dtype)
+    o = jnp.einsum("bkgt,bkdt->bkgd", pr, v, preferred_element_type=x.dtype)
     o = o.reshape(B, 1, H, hd)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"], preferred_element_type=x.dtype)
-    return out, {"k": k, "v": v, "slot_pos": slot_pos}
+    return out, {"k": k_all, "v": v_all, "slot_pos": slot_pos}
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,8 @@ Three modes share the block implementations:
 
 * ``train``   — full sequence, no cache.
 * ``prefill`` — full sequence, emits a serving cache.
-* ``decode``  — one token against the cache (functional update).
+* ``decode``  — one token against the cache, carried through the layer
+  loop and written one column per layer in place.
 """
 
 from __future__ import annotations
@@ -159,25 +160,38 @@ def _attn_seq_with_cache(p, x, cfg, kind, want_cache: bool):
     y, kv = L.attention_seq_kv(p, x, cfg, window=window)
     if not want_cache:
         return y, None
-    k, v = kv
-    B, Sq = x.shape[0], x.shape[1]
-    W = min(Sq if not window else window, k.shape[1]) if window else Sq
+    k, v = (a.transpose(0, 2, 3, 1) for a in kv)      # [B,Kv,hd,S]: S on lanes
+    Sq = x.shape[1]
     if window and Sq > window:
-        k, v = k[:, -window:], v[:, -window:]
+        k, v = k[..., -window:], v[..., -window:]
         slot_pos = jnp.arange(Sq - window, Sq, dtype=jnp.int32)
     else:
-        slot_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
+        slot_pos = jnp.arange(Sq, dtype=jnp.int32)
     return y, {"k": k, "v": v, "slot_pos": slot_pos}
 
 
-def block_fwd(kind: str, cfg: ModelConfig, p: dict, x, *, mode: str, pos=None, cache=None):
-    """Returns (x, new_cache, aux_loss)."""
+def _decode_state(fn, p, h, cfg, cache, layer):
+    """A recurrent block's decode against layer ``layer`` of its stacked
+    state: read that layer, replace it with the block's new state."""
+    c = {k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+         for k, v in cache.items()}
+    y, nc = fn(p, h, cfg, c)
+    return y, {k: jax.lax.dynamic_update_index_in_dim(
+        v, nc[k].astype(v.dtype), layer, 0) for k, v in cache.items()}
+
+
+def block_fwd(kind: str, cfg: ModelConfig, p: dict, x, *, mode: str, pos=None,
+              cache=None, layer=None):
+    """Returns (x, new_cache, aux_loss).  In decode mode ``cache`` holds the
+    block's entries stacked over layers and ``layer`` is this block's index
+    in them; the stacked entries come back with that layer updated."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ATTN_KINDS:
         window = cfg.attn_window if kind == "attn_local" else 0
         h = L.rmsnorm(x, p["ln1_scale"], cfg.norm_eps)
         if mode == "decode":
-            a, new_cache = L.attention_decode(p, h, cfg, cache, pos, window=window)
+            a, new_cache = L.attention_decode(p, h, cfg, cache, pos, layer,
+                                              window=window)
         else:
             a, new_cache = _attn_seq_with_cache(p, h, cfg, kind, mode == "prefill")
         x = x + a
@@ -191,14 +205,14 @@ def block_fwd(kind: str, cfg: ModelConfig, p: dict, x, *, mode: str, pos=None, c
     if kind == "ssd":
         h = L.rmsnorm(x, p["ln1_scale"], cfg.norm_eps)
         if mode == "decode":
-            y, new_cache = S.ssd_decode(p, h, cfg, cache)
+            y, new_cache = _decode_state(S.ssd_decode, p, h, cfg, cache, layer)
         else:
             y, new_cache = S.ssd_seq_cached(p, h, cfg, want_cache=mode == "prefill")
         return x + y, new_cache, aux
     if kind == "rglru":
         h = L.rmsnorm(x, p["ln1_scale"], cfg.norm_eps)
         if mode == "decode":
-            y, new_cache = R.rglru_decode(p, h, cfg, cache)
+            y, new_cache = _decode_state(R.rglru_decode, p, h, cfg, cache, layer)
         else:
             y, new_cache = R.rglru_seq_cached(p, h, cfg, want_cache=mode == "prefill")
         x = x + y
@@ -228,6 +242,46 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn)  # "block": save block boundaries only
 
 
+def _decode_stack(params: dict, x, cfg: ModelConfig, cache: dict, pos):
+    """Decode through every block with the whole cache as the loop's carry:
+    each block updates its own layer of the stacked entries in place, so
+    the cache is neither sliced out per layer nor stacked back after."""
+    pattern = cfg.block_pattern
+
+    def blocks(carry, slot_params, i):
+        x, aux, cache = carry
+        cache = dict(cache)
+        for slot, kind in enumerate(pattern):
+            pre = f"s{slot}_"
+            x, nc, a = block_fwd(kind, cfg, slot_params[f"s{slot}"], x,
+                                 mode="decode", pos=pos, cache=_sub(cache, pre),
+                                 layer=i)
+            cache.update({pre + k: v for k, v in nc.items()})
+            aux = aux + a
+        return x, aux, cache
+
+    carry = (x, jnp.zeros((), jnp.float32), cache)
+    if cfg.n_super > 0:
+        stacked = {f"s{slot}": _sub(params, f"s{slot}_") for slot in range(len(pattern))}
+        if cfg.scan_layers and cfg.n_super > 1:
+            carry, _ = jax.lax.scan(
+                lambda c, xs: (blocks(c, *xs), None), carry,
+                (stacked, jnp.arange(cfg.n_super, dtype=jnp.int32)))
+        else:
+            for i in range(cfg.n_super):
+                carry = blocks(carry, jax.tree.map(lambda a: a[i], stacked), i)
+    x, aux, cache = carry
+    cache = dict(cache)
+    for ti, kind in enumerate(cfg.trailing):
+        pre = f"t{ti}_"
+        one = {k: v[None] for k, v in _sub(cache, pre).items()}  # a stack of one
+        x, nc, a = block_fwd(kind, cfg, _sub(params, pre), x, mode="decode",
+                             pos=pos, cache=one, layer=0)
+        cache.update({pre + k: v[0] for k, v in nc.items()})
+        aux = aux + a
+    return x, cache, aux
+
+
 def forward(params: dict, inputs: dict, cfg: ModelConfig, *, mode: str = "train",
             cache: dict | None = None, pos=None):
     """Run the stack.  Returns (logits, new_cache, aux_loss).
@@ -238,18 +292,19 @@ def forward(params: dict, inputs: dict, cfg: ModelConfig, *, mode: str = "train"
     batching) layout — see :func:`cache_specs`.
     """
     x = _embed_inputs(params, inputs, cfg)
+    if mode == "decode":
+        x, new_cache, aux_total = _decode_stack(params, x, cfg, cache, pos)
+        return _logits(params, x, cfg), new_cache, aux_total
     pattern = cfg.block_pattern
     n_super = cfg.n_super
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
 
-    def super_fwd(x, slot_params, slot_caches):
+    def super_fwd(x, slot_params):
         aux_sum = jnp.zeros((), jnp.float32)
         outs = {}
         for slot, kind in enumerate(pattern):
-            c = slot_caches.get(f"s{slot}") if slot_caches else None
-            x, nc, aux = block_fwd(kind, cfg, slot_params[f"s{slot}"], x,
-                                   mode=mode, pos=pos, cache=c)
+            x, nc, aux = block_fwd(kind, cfg, slot_params[f"s{slot}"], x, mode=mode)
             if nc is not None:
                 outs[f"s{slot}"] = nc
             aux_sum = aux_sum + aux
@@ -257,28 +312,19 @@ def forward(params: dict, inputs: dict, cfg: ModelConfig, *, mode: str = "train"
 
     if n_super > 0:
         stacked = {f"s{slot}": _sub(params, f"s{slot}_") for slot in range(len(pattern))}
-        cache_stacked = None
-        if mode == "decode":
-            cache_stacked = {f"s{slot}": _sub(cache, f"s{slot}_") for slot in range(len(pattern))}
-
         body_fn = _maybe_remat(super_fwd, cfg)
 
-        def scan_body(carry, xs):
+        def scan_body(carry, sp):
             x, aux = carry
-            sp = xs["params"]
-            sc = xs.get("cache")
-            x, outs, aux_d = body_fn(x, sp, sc)
+            x, outs, aux_d = body_fn(x, sp)
             return (x, aux + aux_d), outs
 
-        xs = {"params": stacked}
-        if cache_stacked is not None:
-            xs["cache"] = cache_stacked
         if cfg.scan_layers and n_super > 1:
-            (x, aux_total), cache_out = jax.lax.scan(scan_body, (x, aux_total), xs)
+            (x, aux_total), cache_out = jax.lax.scan(scan_body, (x, aux_total), stacked)
         else:
             cache_parts = []
             for i in range(n_super):
-                sl = jax.tree.map(lambda a: a[i], xs)
+                sl = jax.tree.map(lambda a: a[i], stacked)
                 (x, aux_total), co = scan_body((x, aux_total), sl)
                 cache_parts.append(co)
             cache_out = (jax.tree.map(lambda *a: jnp.stack(a), *cache_parts)
@@ -289,17 +335,18 @@ def forward(params: dict, inputs: dict, cfg: ModelConfig, *, mode: str = "train"
                     new_cache[f"{slot_name}_{k}"] = v
 
     for ti, kind in enumerate(cfg.trailing):
-        c = _sub(cache, f"t{ti}_") if (cache and mode == "decode") else None
-        x, nc, aux = block_fwd(kind, cfg, _sub(params, f"t{ti}_"), x,
-                               mode=mode, pos=pos, cache=c)
+        x, nc, aux = block_fwd(kind, cfg, _sub(params, f"t{ti}_"), x, mode=mode)
         aux_total = aux_total + aux
         if nc is not None:
             for k, v in nc.items():
                 new_cache[f"t{ti}_{k}"] = v
 
+    return _logits(params, x, cfg), (new_cache if new_cache else None), aux_total
+
+
+def _logits(params: dict, x, cfg: ModelConfig):
     x = L.rmsnorm(x, params["final_scale"], cfg.norm_eps)
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    logits = shard_act(logits, "batch", "seq", "act_vocab")
-    return logits, (new_cache if new_cache else None), aux_total
+    return shard_act(logits, "batch", "seq", "act_vocab")

@@ -100,14 +100,71 @@ def test_mid_wave_admission_unequal_pos(params):
     assert len(r0.out) == 6 and len(r1.out) == 6
 
 
+_LOCAL = TINY.with_(block_pattern=("attn_local", "attn"), num_layers=5,
+                    attn_window=8)          # two scanned pairs + a trailing ring
+
+
+@pytest.mark.parametrize("cfg,per_slot,pos", [
+    (TINY, True, [3, 7, 0, 12]),
+    (TINY, False, 5),
+    (_LOCAL, True, [9, 13, 8, 15]),         # the 8-wide rings have wrapped
+    (TINY.with_(scan_layers=False), True, [3, 7, 0, 12]),
+], ids=["per_slot", "shared_wave", "local_ring_wrapped", "unscanned"])
+def test_decode_writes_one_column(cfg, per_slot, pos):
+    """One decode step writes exactly one ``[Kv, hd]`` column per layer and
+    row, at the row's ring slot, and leaves every other cache element
+    bitwise equal."""
+    from repro.train import serve as SRV
+
+    B, W = 4, 16
+    params = T.init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(4)
+    pos_v = np.broadcast_to(np.asarray(pos, np.int32), (B,))
+    old = {}
+    for k, sd in T.cache_shapes(cfg, B, W, per_slot=per_slot).items():
+        if k.endswith("slot_pos"):
+            w = sd.shape[-1]
+            rows = np.where(np.arange(w) < np.minimum(pos_v, w)[:, None],
+                            np.arange(w), -1).astype(np.int32)
+            old[k] = np.broadcast_to(rows if per_slot else rows[0], sd.shape)
+        else:
+            old[k] = np.asarray(rng.standard_normal(sd.shape), sd.dtype)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 1)), jnp.int32)
+    step = jax.jit(SRV.make_decode_step(cfg))
+    new, _ = step(params, {k: jnp.asarray(v) for k, v in old.items()}, toks,
+                  jnp.asarray(pos, jnp.int32))
+    assert set(new) == set(old)
+    for k, was in old.items():
+        got = np.asarray(new[k])
+        w = got.shape[-1]
+        want = np.zeros(got.shape, bool)
+        for b, p in enumerate(pos_v):
+            if k.endswith("slot_pos"):
+                want[..., b if per_slot else slice(None), p % w] = True
+            else:
+                want[..., b, :, :, p % w] = True
+        nbytes = got.dtype.itemsize
+        same = (got.view(np.uint8).reshape(*got.shape, nbytes)
+                == was.view(np.uint8).reshape(*got.shape, nbytes)).all(-1)
+        assert same[~want].all(), f"{k}: written outside its columns"
+        if k.endswith("slot_pos"):
+            assert (got[want] == np.broadcast_to(
+                pos_v[:, None] if per_slot else pos_v[0], got.shape)[want]).all()
+            continue
+        for b, p in enumerate(pos_v):       # each layer's column is written
+            col = same[..., b, :, :, p % w]                 # [(L,) Kv, hd]
+            per_layer = col.reshape(-1, col.shape[-2] * col.shape[-1])
+            assert not per_layer.all(-1).any(), f"{k}: a column unwritten"
+
+
 # ---------------------------------------------------------------------------
 # KV slab wire format
 
 
 def test_kv_slab_roundtrip():
     rng = np.random.default_rng(2)
-    entries = {"s0_k": rng.standard_normal((1, 1, 8, 4)).astype(np.float32),
-               "s0_v": rng.standard_normal((1, 1, 8, 4)).astype(np.float32),
+    entries = {"s0_k": rng.standard_normal((1, 1, 1, 4, 8)).astype(np.float32),
+               "s0_v": rng.standard_normal((1, 1, 1, 4, 8)).astype(np.float32),
                "s0_slot_pos": np.arange(8, dtype=np.int32)}   # elided
     slab = kv.pack_kv(entries, rid=7, slot=3, pos0=5, first_token=42)
     assert kv.peek_kv(slab) == (7, 3)

@@ -158,3 +158,60 @@ def _kernels_keep_their_names(text: str, agg_k: int):
     assert poll.match(calls[poll_name]) and not uvm.match(calls[poll_name])
     assert "/uvm/ifunc_vm/" in calls["ifunc_vm"]
     assert f"/poll/{poll_name}/" in calls[poll_name]
+
+
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+          "u8": 1, "pred": 1}
+
+
+def test_decode_step_writes_cache_in_place(one_chip):
+    """SmolLM-360M's donated decode step at published widths, 64 slots x
+    2048: the stacked caches are the layer loop's carry, so the program
+    needs less scratch than one layer's K, every cache output aliases its
+    donated input, and no copy of the cache is left in it: a cache passed
+    through the loop as its scan's xs/ys is relayouted per layer and copied
+    whole after it, each copy one layer's K/V or more.  Only results with
+    the ring's width as a dimension count (the logits' embedding table is
+    copied too, and is no cache)."""
+    import json
+    import re
+
+    from bench.harness import BENCH
+    from bench.reference.llama import program_config
+    from repro.models import transformer as TR
+    from repro.models.config import ModelConfig
+    from repro.train import serve as SRV
+
+    conf = json.loads((BENCH / "configs" / "smollm_360m.json").read_text())
+    cfg = ModelConfig(**program_config(conf))
+    B, W = conf["decode_slots"], conf["cache_len"]
+
+    def placed(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = placed(TR.param_shapes(cfg))
+    cache = placed(TR.cache_shapes(cfg, B, W, per_slot=True))
+    c = jax.jit(SRV.make_decode_step(cfg), donate_argnums=1).lower(
+        params, cache,
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    layer_k = B * cfg.num_kv_heads * cfg.head_dim * W * 2
+    assert layer_k == 83_886_080
+    assert c.memory_analysis().temp_size_in_bytes < layer_k
+
+    text = c.as_text()
+    head = text.splitlines()[0]
+    aliased = dict((int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", head))
+    n_params = len(jax.tree.leaves(params))
+    assert all(aliased.get(o) == n_params + o
+               for o in range(len(jax.tree.leaves(cache)))), head[:300]
+
+    copies = []
+    for dt, dims in re.findall(
+            r"%copy[.\d]* = (\w+)\[([\d,]*)\]\S* copy\(", text):
+        shape = [int(d) for d in dims.split(",") if d]
+        if W in shape and _BYTES[dt] * int(np.prod(shape)) >= layer_k:
+            copies.append(f"{dt}{shape}")
+    assert not copies, f"cache-sized copies left: {copies}"
