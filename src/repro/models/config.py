@@ -20,7 +20,8 @@ class ModelConfig:
     head_dim: int = 0                # 0 -> d_model // num_heads
 
     # block pattern: the repeating unit scanned over; remainder layers are
-    # unrolled.  kinds: attn | attn_moe | attn_local | ssd | rglru
+    # unrolled.  kinds: attn | attn_moe | attn_local | ssd | ssd_mlp | rglru
+    # (ssd_mlp: a Mamba-2 mixer followed by its own pre-norm MLP)
     block_pattern: tuple[str, ...] = ("attn",)
 
     norm_eps: float = 1e-5
@@ -28,8 +29,19 @@ class ModelConfig:
     mlp_gated: bool = True
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    use_rope: bool = True            # False: no positional encoding (NoPE)
     attn_window: int = 0             # for attn_local blocks
     attn_logit_softcap: float = 0.0
+
+    # muP multipliers (Granite): the embeddings are scaled by
+    # ``embedding_multiplier``, every block's residual branch by
+    # ``residual_multiplier``, attention scores by ``attention_multiplier``
+    # (0 -> 1/sqrt(head_dim)) and the logits divided by ``logits_scaling``.
+    # At their defaults no operation is traced for them.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # MoE
     num_experts: int = 0
@@ -45,6 +57,7 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
+    ssm_conv_bias: bool = False
 
     # hybrid (RG-LRU)
     lru_width: int = 0               # 0 -> d_model
@@ -134,7 +147,7 @@ class ModelConfig:
         def ssd_params():
             di, ds, nh = self.d_inner, self.ssm_state, self.ssm_heads
             in_proj = D * (2 * di + 2 * ds + nh)
-            conv = self.ssm_conv * (di + 2 * ds)
+            conv = (self.ssm_conv + self.ssm_conv_bias) * (di + 2 * ds)
             out = di * D
             extra = nh * 3  # A, D, dt_bias
             return in_proj + conv + out + extra + di  # + gate norm
@@ -148,6 +161,7 @@ class ModelConfig:
             "attn_local": attn_params() + mlp_params(F),
             "attn_moe": attn_params() + moe_params(),
             "ssd": ssd_params(),
+            "ssd_mlp": ssd_params() + mlp_params(F),
             "rglru": rglru_params() + mlp_params(F),
         }
         layers = list(self.block_pattern) * self.n_super + list(self.trailing)

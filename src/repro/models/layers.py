@@ -111,8 +111,9 @@ def _qkv(p, x, cfg, positions):
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"], preferred_element_type=x.dtype)
     if cfg.qkv_bias:
         q, k, v = q + p["wq_b"], k + p["wk_b"], v + p["wv_b"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -137,7 +138,7 @@ def attention_seq_kv(p, x, cfg, *, window: int = 0):
     q = shard_act(q, "batch", "seq", "act_heads", None)
     k = shard_act(k, "batch", "seq", "act_heads", None)
     v = shard_act(v, "batch", "seq", "act_heads", None)
-    scale = 1.0 / np.sqrt(hd)
+    scale = cfg.attention_multiplier or 1.0 / np.sqrt(hd)
     kpos = jnp.arange(S, dtype=jnp.int32)
 
     def block_naive(qc, qpos0):
@@ -309,7 +310,9 @@ def attention_decode(p, x, cfg, cache, pos, layer, *, window: int = 0):
     qg = q.reshape(B, Kv, G, hd)
     qg = shard_act(qg, "cache_batch", "cache_kv_heads", None, None)
     s_ = jnp.einsum("bkgd,bkdt->bkgt", qg, k, preferred_element_type=jnp.float32)
-    s_ = _softcap(s_ / np.sqrt(hd), cfg.attn_logit_softcap)
+    s_ = (s_ * cfg.attention_multiplier if cfg.attention_multiplier
+          else s_ / np.sqrt(hd))
+    s_ = _softcap(s_, cfg.attn_logit_softcap)
     valid = (sp >= 0) & (sp <= pos_v[:, None])
     if window:
         valid &= sp > pos_v[:, None] - window

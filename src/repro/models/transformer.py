@@ -28,6 +28,7 @@ from repro.models.config import ModelConfig
 from repro.parallel.sharding import shard_act
 
 ATTN_KINDS = ("attn", "attn_moe", "attn_local")
+SSD_KINDS = ("ssd", "ssd_mlp")
 
 
 # ---------------------------------------------------------------------------
@@ -45,9 +46,12 @@ def _block_specs(cfg: ModelConfig, kind: str) -> dict[str, L.Spec]:
             s.update(M.moe_specs(cfg))
         else:
             s.update(L.mlp_specs(cfg))
-    elif kind == "ssd":
+    elif kind in SSD_KINDS:
         s.update(L.norm_specs("ln1", D))
         s.update(S.ssd_specs(cfg))
+        if kind == "ssd_mlp":
+            s.update(L.norm_specs("ln2", D))
+            s.update(L.mlp_specs(cfg))
     elif kind == "rglru":
         s.update(L.norm_specs("ln1", D))
         s.update(R.rglru_specs(cfg))
@@ -94,7 +98,7 @@ def _cache_entry_specs(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
     if kind in ATTN_KINDS:
         W = min(cache_len, cfg.attn_window) if (kind == "attn_local" and cfg.attn_window) else cache_len
         return L.attn_cache_specs(cfg, batch, W, per_slot=per_slot)
-    if kind == "ssd":
+    if kind in SSD_KINDS:
         return S.ssd_cache_specs(cfg, batch)
     if kind == "rglru":
         return R.rglru_cache_specs(cfg, batch)
@@ -170,6 +174,21 @@ def _attn_seq_with_cache(p, x, cfg, kind, want_cache: bool):
     return y, {"k": k, "v": v, "slot_pos": slot_pos}
 
 
+def recurrent_keys(cfg: ModelConfig) -> tuple[str, ...]:
+    """Names of the cache entries that hold recurrent state (no positions):
+    a reused slot's rows of these are overwritten whole."""
+    slots = [(f"s{i}_", k) for i, k in enumerate(cfg.block_pattern)]
+    slots += [(f"t{i}_", k) for i, k in enumerate(cfg.trailing)]
+    return tuple(pre + n for pre, kind in slots if kind not in ATTN_KINDS
+                 for n in _cache_entry_specs(cfg, kind, 1, 1))
+
+
+def _residual(x, y, cfg: ModelConfig):
+    """x + y, the branch scaled by ``residual_multiplier`` where it is set."""
+    r = cfg.residual_multiplier
+    return x + (y * r if r != 1.0 else y)
+
+
 def _decode_state(fn, p, h, cfg, cache, layer):
     """A recurrent block's decode against layer ``layer`` of its stacked
     state: read that layer, replace it with the block's new state."""
@@ -194,30 +213,33 @@ def block_fwd(kind: str, cfg: ModelConfig, p: dict, x, *, mode: str, pos=None,
                                               window=window)
         else:
             a, new_cache = _attn_seq_with_cache(p, h, cfg, kind, mode == "prefill")
-        x = x + a
+        x = _residual(x, a, cfg)
         h = L.rmsnorm(x, p["ln2_scale"], cfg.norm_eps)
         if kind == "attn_moe":
             y, aux = M.moe_ffn(p, h, cfg)
         else:
             y = L.mlp(p, h, cfg)
-        x = x + y
-        return x, new_cache, aux
-    if kind == "ssd":
+        return _residual(x, y, cfg), new_cache, aux
+    if kind in SSD_KINDS:
         h = L.rmsnorm(x, p["ln1_scale"], cfg.norm_eps)
         if mode == "decode":
-            y, new_cache = _decode_state(S.ssd_decode, p, h, cfg, cache, layer)
+            y, new_cache = S.ssd_decode(p, h, cfg, cache, layer)
         else:
             y, new_cache = S.ssd_seq_cached(p, h, cfg, want_cache=mode == "prefill")
-        return x + y, new_cache, aux
+        x = _residual(x, y, cfg)
+        if kind == "ssd_mlp":
+            h = L.rmsnorm(x, p["ln2_scale"], cfg.norm_eps)
+            x = _residual(x, L.mlp(p, h, cfg), cfg)
+        return x, new_cache, aux
     if kind == "rglru":
         h = L.rmsnorm(x, p["ln1_scale"], cfg.norm_eps)
         if mode == "decode":
             y, new_cache = _decode_state(R.rglru_decode, p, h, cfg, cache, layer)
         else:
             y, new_cache = R.rglru_seq_cached(p, h, cfg, want_cache=mode == "prefill")
-        x = x + y
+        x = _residual(x, y, cfg)
         h = L.rmsnorm(x, p["ln2_scale"], cfg.norm_eps)
-        return x + L.mlp(p, h, cfg), new_cache, aux
+        return _residual(x, L.mlp(p, h, cfg), cfg), new_cache, aux
     raise ValueError(kind)
 
 
@@ -227,6 +249,8 @@ def block_fwd(kind: str, cfg: ModelConfig, p: dict, x, *, mode: str, pos=None,
 
 def _embed_inputs(params, inputs, cfg: ModelConfig):
     x = jnp.take(params["tok_embed"], inputs["tokens"], axis=0).astype(cfg.act_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.ext_embed_len and "ext_embed" in inputs:  # decode past the prefix: tokens only
         ext = inputs["ext_embed"].astype(cfg.act_dtype)
         x = jnp.concatenate([ext, x], axis=1)
@@ -349,4 +373,6 @@ def _logits(params: dict, x, cfg: ModelConfig):
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype),
                         preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return shard_act(logits, "batch", "seq", "act_vocab")

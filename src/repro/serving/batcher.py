@@ -9,12 +9,19 @@ per_slot=True)``): ``attention_decode`` takes a ``[B]`` position vector,
 each row writes its own ring slot and masks against its own validity row,
 and sequences join/leave mid-wave — the admission path is a row splice,
 never a barrier.
+
+The splice is one jitted program that takes the cache donated and writes
+the new sequence's row of every entry in place: KV rings with their
+``slot_pos``, and recurrent ``state``/``conv`` rows (Mamba-2, RG-LRU),
+which have no positions and are overwritten whole when a slot is reused.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -57,9 +64,18 @@ class ContinuousBatcher:
         self.active: dict[int, Request] = {}            # slot -> request
         self._decode = SRV.jit_decode_step(cfg, donate=True)
         self._one = T.cache_shapes(cfg, 1, cache_len, per_slot=True)
-        self._full = T.cache_shapes(cfg, batch_slots, cache_len, per_slot=True)
+        full = T.cache_shapes(cfg, batch_slots, cache_len, per_slot=True)
+        bdims = {k: next((i for i, (a, b) in enumerate(
+            zip(full[k].shape, v.shape)) if a != b), None)
+            for k, v in self._one.items()}
+        self._splice = jax.jit(functools.partial(_splice, bdims),
+                               donate_argnums=0)
+        self.state_bytes = sum(
+            int(np.prod(self._one[k].shape)) * self._one[k].dtype.itemsize
+            for k in T.recurrent_keys(cfg))
         self.obs = obs if obs is not None else Obs(name)
         m = self.obs.metrics
+        self._state_spliced = m.counter(f"serve.{name}.state_bytes")
         self._installed = m.counter(f"serve.{name}.installed")
         self._decoded = m.counter(f"serve.{name}.decoded")
         self._finished = m.counter(f"serve.{name}.finished")
@@ -72,29 +88,22 @@ class ContinuousBatcher:
         """Splice one prefilled sequence (a single-sequence cache at seq
         width <= W, with or without ``slot_pos`` entries — a KV slab
         arrives without them) into decode slot ``slot`` and activate it.
-        A pure row write: every other slot keeps decoding undisturbed."""
+        A pure row write, in place: every other slot keeps decoding
+        undisturbed, and the slot's recurrent rows are replaced whole."""
         if slot in self.active:
             raise ValueError(f"slot {slot} already active")
         if not (0 < pos0 <= self.W):
             raise ValueError(f"pos0 {pos0} outside cache width {self.W}")
-        with self.obs.tracer.scope("repro.serve.install"):
+        with self.obs.tracer.scope("repro.serve.install",
+                                   state_bytes=self.state_bytes):
             src = dict(cache1)
             for k, tgt in self._one.items():
                 if k not in src and k.endswith("slot_pos"):
                     base = synth_slot_pos(pos0, tgt.shape[-1])
                     src[k] = jnp.asarray(np.broadcast_to(base, tgt.shape))
             src = SRV.pad_cache_to(src, self._one)
-            for k in self.cache:
-                bdim = next((i for i, (a, b) in enumerate(
-                    zip(self._full[k].shape, self._one[k].shape)) if a != b),
-                    None)
-                row = src[k].astype(self.cache[k].dtype)
-                if bdim is None:            # batch-free entry: shared write
-                    self.cache[k] = row
-                else:
-                    idx = tuple([slice(None)] * bdim
-                                + [slice(slot, slot + 1)])
-                    self.cache[k] = self.cache[k].at[idx].set(row)
+            self.cache = self._splice(self.cache, src, jnp.int32(slot))
+        self._state_spliced.inc(self.state_bytes)
         self.tokens[slot, 0] = int(first_token)
         self.pos[slot] = pos0
         self.active[slot] = req
@@ -129,6 +138,16 @@ class ContinuousBatcher:
         self._decoded.inc(emitted)
         self._finished.inc(len(finished))
         return emitted, finished
+
+
+def _splice(bdims: dict, cache: dict, rows: dict, slot):
+    """``cache`` with ``rows`` (one slot's entries) written at ``slot`` on
+    each entry's batch axis; an entry without one (a batch of one) is
+    replaced."""
+    return {k: rows[k].astype(v.dtype) if bdims[k] is None
+            else jax.lax.dynamic_update_slice_in_dim(
+                v, rows[k].astype(v.dtype), slot, bdims[k])
+            for k, v in cache.items()}
 
 
 __all__ = ["Request", "ContinuousBatcher", "synth_slot_pos"]

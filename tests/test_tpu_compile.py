@@ -215,3 +215,65 @@ def test_decode_step_writes_cache_in_place(one_chip):
         if W in shape and _BYTES[dt] * int(np.prod(shape)) >= layer_k:
             copies.append(f"{dt}{shape}")
     assert not copies, f"cache-sized copies left: {copies}"
+
+
+def test_granite_decode_step_updates_state_in_place(one_chip):
+    """granite-4.0-h-micro's donated decode step at published widths, 64
+    slots x 2048: every Mamba layer's state step is the ``ssd_step`` kernel
+    on the stacked float32 state (one pattern slot's stack of 4 layers is
+    537 MB), which stays the loop's carry: every cache output aliases its
+    donated input, no temp buffer is as large as one slot's stack, and no
+    copy of one is left.  The kernel keeps its name, by which the roofline
+    reader finds it."""
+    import json
+    import re
+
+    from bench.harness import BENCH, load_module
+    from bench.reference.granite_hybrid import program_config
+    from repro.models import transformer as TR
+    from repro.models.config import ModelConfig
+    from repro.train import serve as SRV
+
+    conf = json.loads((BENCH / "configs" /
+                       "granite_4_0_h_micro.json").read_text())
+    cfg = ModelConfig(**program_config(conf))
+    B, W = conf["decode_slots"], conf["cache_len"]
+
+    def placed(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = placed(TR.param_shapes(cfg))
+    cache = placed(TR.cache_shapes(cfg, B, W, per_slot=True))
+    c = jax.jit(SRV.make_decode_step(cfg), donate_argnums=1).lower(
+        params, cache,
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    stack = cache["s0_state"]
+    stack_bytes = int(np.prod(stack.shape)) * 4
+    assert stack_bytes == 536_870_912
+    assert c.memory_analysis().temp_size_in_bytes < stack_bytes
+
+    text = c.as_text()
+    head = text.splitlines()[0]
+    aliased = dict((int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", head))
+    n_params = len(jax.tree.leaves(params))
+    assert all(aliased.get(o) == n_params + o
+               for o in range(len(jax.tree.leaves(cache)))), head[:300]
+
+    copies = []
+    for dt, dims in re.findall(
+            r"%copy[.\d]* = (\w+)\[([\d,]*)\]\S* copy\(", text):
+        shape = [int(d) for d in dims.split(",") if d]
+        if _BYTES[dt] * int(np.prod(shape)) >= stack_bytes // 4:
+            copies.append(f"{dt}{shape}")
+    assert not copies, f"state-sized copies left: {copies}"
+
+    kernel = load_module("metrics", "ssd_step_ms.hybrid").KERNEL
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    names = {op.split(" = ")[0].lstrip("%").split(".")[0] for op in calls}
+    assert names == {"ssd_step", "kv_column_write"}
+    ssd = [op for op in calls if kernel.match(op)]
+    assert ssd and all(op.startswith("%ssd_step") for op in ssd)
