@@ -31,26 +31,38 @@ from repro.parallel.sharding import shard_map
 
 def pack_word_frame(payload_f32: np.ndarray, slot_words: int, kind: int = 3,
                     name_hash: int = 0xABC, *, corrupt: bool = False,
-                    no_trailer: bool = False) -> np.ndarray:
-    """Host-side framing of one device frame into a slot's word array."""
+                    no_trailer: bool = False,
+                    out: np.ndarray | None = None) -> np.ndarray:
+    """Host-side framing of one device frame into a slot's word array: a
+    fresh one, or ``out`` (a staged slot row) written in place.  Every word
+    the poll reads is written, the trailer word too (zero when withheld),
+    so a row reused from an earlier generation shows the kernels nothing
+    of what it held."""
     body = np.asarray(payload_f32, np.float32).reshape(-1).view(np.uint32)
     fw = len(body)
     assert fw <= slot_words - HDR_WORDS - 1, "payload too long for slot"
-    s = np.zeros(slot_words, np.uint32)
-    s[0], s[1], s[2], s[3] = MAGIC, fw, kind, name_hash
-    s[4] = (int(s[0]) ^ int(s[1]) ^ int(s[2]) ^ int(s[3])) ^ (1 if corrupt else 0)
+    s = np.zeros(slot_words, np.uint32) if out is None else out
+    chk = MAGIC ^ fw ^ kind ^ name_hash ^ (1 if corrupt else 0)
+    s[:HDR_WORDS] = (MAGIC, fw, kind, name_hash, chk)
     s[HDR_WORDS:HDR_WORDS + fw] = body
-    if not no_trailer:
-        s[HDR_WORDS + fw] = TRAILER
+    s[HDR_WORDS + fw] = 0 if no_trailer else TRAILER
     return s
 
 
 def pack_agg_word_frame(payloads, hashes, agg_k: int, body_words: int,
                         slot_words: int, kind: int = 3, *,
                         corrupt: bool = False, corrupt_sub: int | None = None,
-                        no_trailer: bool = False) -> np.ndarray:
+                        no_trailer: bool = False,
+                        out: np.ndarray | None = None) -> np.ndarray:
     """Host-side framing of one aggregate container (K sub-record batch)
-    into a slot's word array — layout in kernels/agg_poll.py.
+    into a slot's word array — layout in kernels/agg_poll.py — a fresh
+    one, or ``out`` (a staged slot row) written in place.  ``payloads`` is
+    a sequence of bodies, or one ``[n, body_words]`` array copied at once.
+
+    Written whatever the row held: the header, all K descriptor pairs
+    (those past ``n`` zero, so they read SUB_EMPTY) and the trailer word
+    (zero when withheld).  Bodies past ``n`` keep what they held: the
+    sweep masks their outputs.
 
     ``corrupt`` poisons the container header check (whole-container
     REJECT); ``corrupt_sub`` poisons one descriptor's check word (that
@@ -60,21 +72,27 @@ def pack_agg_word_frame(payloads, hashes, agg_k: int, body_words: int,
     n = len(payloads)
     assert n == len(hashes) and n <= agg_k, "sub count exceeds bound agg_k"
     assert slot_words >= HDR_WORDS + 2 * agg_k + agg_k * body_words + 1
-    s = np.zeros(slot_words, np.uint32)
-    s[0], s[1], s[2], s[3] = AGG_MAGIC, n, kind, 0
-    s[4] = (int(s[0]) ^ int(s[1]) ^ int(s[2]) ^ int(s[3])) ^ (1 if corrupt else 0)
-    for i, (p, h) in enumerate(zip(payloads, hashes)):
-        body = np.asarray(p, np.float32).reshape(-1).view(np.uint32)
-        assert len(body) == body_words, "sub body != bound body_words"
-        d = HDR_WORDS + 2 * i
-        s[d] = h & 0xFFFFFFFF
-        s[d + 1] = (int(s[d]) ^ SUB_SALT) & 0xFFFFFFFF
-        if corrupt_sub == i:
-            s[d + 1] ^= 1
-        off = HDR_WORDS + 2 * agg_k + i * body_words
-        s[off:off + body_words] = body
-    if not no_trailer:
-        s[slot_words - 1] = TRAILER
+    s = np.zeros(slot_words, np.uint32) if out is None else out
+    chk = AGG_MAGIC ^ n ^ kind ^ (1 if corrupt else 0)
+    s[:HDR_WORDS] = (AGG_MAGIC, n, kind, 0, chk)
+    desc = s[HDR_WORDS:HDR_WORDS + 2 * agg_k]
+    h = (np.asarray(hashes, np.uint64) & 0xFFFFFFFF).astype(np.uint32)
+    desc[0:2 * n:2] = h
+    desc[1:2 * n:2] = h ^ np.uint32(SUB_SALT)
+    desc[2 * n:] = 0
+    if corrupt_sub is not None and corrupt_sub < n:
+        desc[2 * corrupt_sub + 1] ^= 1
+    off = HDR_WORDS + 2 * agg_k
+    bodies = s[off:off + agg_k * body_words].reshape(agg_k, body_words)
+    if isinstance(payloads, np.ndarray):
+        bodies[:n] = np.asarray(payloads, np.float32).reshape(
+            n, body_words).view(np.uint32)
+    else:
+        for i, p in enumerate(payloads):
+            body = np.asarray(p, np.float32).reshape(-1).view(np.uint32)
+            assert len(body) == body_words, "sub body != bound body_words"
+            bodies[i] = body
+    s[slot_words - 1] = 0 if no_trailer else TRAILER
     return s
 
 

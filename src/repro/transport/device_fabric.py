@@ -4,11 +4,12 @@ same Fabric/Channel/Mailbox contract as the host backends.
 The backend wraps ``core/device_mailbox.py``: a mailbox is a ring of
 word-frames in (emulated) device memory per mesh shard; a put *transcodes*
 the wire byte-frame (header + μVM code + f32 payload + trailer) into the
-device word-frame layout — the NIC-offload moment — and stages it; flush
-deposits every staged generation over the ICI via ``ppermute`` (the
-RDMA-put analogue); the sweep validates all slots in one compiled
-``ring_poll`` + ``ifunc_vm`` pass with the μVM program bound at
-mailbox-open time (the device-side link cache).
+device word-frame layout — the NIC-offload moment — writing it straight
+into its row of the staged generation, one of two host buffers the lane
+keeps and fills in turn; flush deposits the staged generation over the
+ICI via ``ppermute`` (the RDMA-put analogue); the sweep validates all
+slots in one compiled ``ring_poll`` + ``ifunc_vm`` pass with the μVM
+program bound at mailbox-open time (the device-side link cache).
 
 Visibility is generation-batched: frames become consumable only after the
 depositing flush, which is exactly the in-flight window the ProgressEngine
@@ -95,8 +96,17 @@ class DeviceMeshMailbox(Mailbox):
                                          tile, bound_hash=self.bound_hash)
         else:
             self._sweep = make_sweep(mesh, axis, prog, n_tiles, tile)
+        # the generation being filled: one of two resident host buffers
+        # [n_shards, n_slots_per_shard, slot_words], allocated on first use
+        # and then taken in turn, flush by flush (``_open_generation``);
+        # None: the next stage takes the next buffer
         self._staged: np.ndarray | None = None
         self._staged_count = 0
+        self._staged_fresh = False       # allocated for this generation
+        self._gens: list[np.ndarray] = []
+        self._gen = 1                    # index of the buffer last taken
+        self._carried: list[list] = [[], []]  # (shard, idx) rows per buffer
+        self._fences: list = [None, None]     # the deposit that read it
         self._deposited = 0                          # frames awaiting sweep
         self.results: list = []                      # READY outputs, one entry
         #                                 per consumed container/singleton
@@ -119,19 +129,52 @@ class DeviceMeshMailbox(Mailbox):
         """Dispatcher ring index -> (shard, per-shard slot) interleaving."""
         return slot % self.n_shards, (slot // self.n_shards) % self.n_slots_per_shard
 
-    def _stage(self, word_frame: np.ndarray, slot: int) -> None:
-        if self._staged is None:
-            self._staged = np.zeros(
+    def _open_generation(self) -> bool:
+        """Take the other resident staging buffer as the generation to fill;
+        True when it had to be allocated (the first two generations only).
+
+        A reused buffer last went up one flush ago.  ``device_put`` may
+        still read it (an asynchronous copy, or the CPU backend's alias of
+        the numpy array), so wait for the deposit that read it.  In the
+        steady loop a sweep's blocking readback lies between two flushes
+        and that deposit is long done; the wait matters only for flushes
+        with no sweep between them, and never reaches further back than
+        one flush: two buffers are enough.  Then clear the magic and the
+        trailer word of every row it carried, so no frame of an earlier
+        generation is deposited again (the deposit is masked by word 0)."""
+        i = self._gen = 1 - self._gen
+        fresh = i == len(self._gens)
+        if fresh:
+            self._gens.append(np.zeros(
                 (self.n_shards, self.n_slots_per_shard, self.slot_words),
-                np.uint32)
+                np.uint32))
+        else:
+            if self._fences[i] is not None:
+                import jax
+
+                jax.block_until_ready(self._fences[i])
+                self._fences[i] = None
+            buf = self._gens[i]
+            for shard, idx in self._carried[i]:
+                buf[shard, idx, 0] = 0
+                buf[shard, idx, -1] = 0
+        self._carried[i] = []
+        self._staged = self._gens[i]
+        self._staged_fresh = fresh
+        return fresh
+
+    def _stage(self, slot: int) -> np.ndarray:
+        """The row of the generation being filled that ``slot`` stages into
+        (a view: the channel writes the word-frame straight into it)."""
         shard, idx = self.slot_coords(slot)
-        self._staged[shard, idx] = word_frame
+        self._carried[self._gen].append((shard, idx))
         self._staged_count += 1
+        return self._staged[shard, idx]
 
     def _publish(self) -> None:
         """Deposit the staged generation over the ICI (collective_permute):
         the whole generation is copied to the device, however few slots it
-        holds."""
+        holds.  The buffer stays resident for a later generation."""
         if self._staged is None:
             return
         import jax
@@ -139,6 +182,7 @@ class DeviceMeshMailbox(Mailbox):
         self._mb = self._deposit(self._mb,
                                  jax.device_put(self._staged, self._sharding),
                                  shift=self.shift)
+        self._fences[self._gen] = self._mb
         self._deposited += self._staged_count
         self._staged = None
         self._staged_count = 0
@@ -180,6 +224,9 @@ class DeviceMeshMailbox(Mailbox):
             *outs, self._mb = self._sweep(self._mb, self.externals)
             host = [np.asarray(a) for a in outs]
             sc.set_metadata(bytes=sum(h.nbytes for h in host))
+        # the outputs are on the host, so every deposit before this sweep
+        # is done with its staging buffer: drop the rings that fenced them
+        self._fences = [None, None]
         return host
 
     def _sweep_one(self, target_args) -> list:
@@ -188,6 +235,7 @@ class DeviceMeshMailbox(Mailbox):
 
         status, out = self._run_sweep()
         with self.tracer.scope("repro.device.demux") as sc:
+            copy = _sparse(int((status == READY).sum()), status.size)
             statuses: list = []
             self.last_coords = []
             for shard in range(status.shape[0]):
@@ -195,10 +243,11 @@ class DeviceMeshMailbox(Mailbox):
                     st = int(status[shard, slot])
                     coord = self._staged_at(shard, slot)
                     if st == READY:
-                        self.results.append(out[shard, slot])
+                        val = out[shard, slot]
+                        val = val.copy() if copy else val
+                        self.results.append(val)
                         if isinstance(target_args, dict):
-                            target_args.setdefault("results", []).append(
-                                out[shard, slot])
+                            target_args.setdefault("results", []).append(val)
                         statuses.append(Status.OK)
                         self.last_coords.append(coord)
                     elif st == BAD:
@@ -234,6 +283,7 @@ class DeviceMeshMailbox(Mailbox):
 
         status, sub_st, out = self._run_sweep()
         with self.tracer.scope("repro.device.demux") as sc:
+            copy = _sparse(int((sub_st == SUB_READY).sum()), sub_st.size)
             statuses: list = []
             self.last_coords = []
             for shard in range(status.shape[0]):
@@ -248,10 +298,11 @@ class DeviceMeshMailbox(Mailbox):
                             if s_i == SUB_EMPTY:
                                 break
                             if s_i == SUB_READY:
+                                val = out[shard, slot, i]
+                                val = val.copy() if copy else val
                                 subs.append(AggSubResult(
-                                    Status.OK, "", b"", 0,
-                                    value=out[shard, slot, i]))
-                                vals.append(out[shard, slot, i])
+                                    Status.OK, "", b"", 0, value=val))
+                                vals.append(val)
                             elif s_i == SUB_BAD:
                                 subs.append(AggSubResult(
                                     Status.REJECTED, "", b"", 0,
@@ -285,10 +336,44 @@ class DeviceMeshMailbox(Mailbox):
         return statuses
 
 
+def _sparse(n_answers: int, capacity: int) -> bool:
+    """Whether a sweep's answers leave its readback as copies: where they
+    fill less than half of it.  A caller may keep an answer as long as it
+    likes (a future's result), and a view keeps the whole readback alive:
+    for a lane that finds one frame a sweep, a thousand times the answer's
+    own bytes.  Where they fill it, views cost nothing more."""
+    return 2 * n_answers < capacity
+
+
+def _agg_bodies(batch: F.AggBatch, body_words: int):
+    """A container's sub-record bodies, as zero-copy views of the frame,
+    and their name hashes; every sub-record must be a μVM one of the bound
+    size."""
+    uvm = int(F.CodeKind.UVM)
+    for i, (k, plen) in enumerate(zip(batch.kinds, batch.plens)):
+        if k != uvm:
+            raise TransportError("device mesh accepts UVM sub-records only, "
+                                 f"got {batch.kind(i).name}")
+        if plen != 4 * body_words:
+            raise TransportError(f"device agg sub payload {plen // 4} words "
+                                 f"!= bound {body_words}")
+    by_name = [F.fletcher32(nm.encode()) & 0xFFFFFFFF for nm in batch.names]
+    hashes = [by_name[j] for j in batch.name_idx]
+    if batch.n and not any(batch.clens):
+        # no continuations: the bodies lie back to back in the payload
+        # region, so they go into the slot in one copy
+        return np.frombuffer(batch.mv, np.float32, count=batch.n * body_words,
+                             offset=batch.starts[0]).reshape(
+                                 batch.n, body_words), hashes
+    return [np.frombuffer(batch.payload(i), np.float32)
+            for i in range(batch.n)], hashes
+
+
 class DeviceMeshChannel(Channel):
     def __init__(self, mailbox: DeviceMeshMailbox):
         super().__init__()
         self.mailbox = mailbox
+        self.stats["gen_allocs"] = 0     # staging generation buffers made
 
     def put(self, data, slot: int, *, deliver_bytes: int | None = None) -> None:
         """Transcode a wire byte-frame into the device word-frame layout and
@@ -308,8 +393,8 @@ class DeviceMeshChannel(Channel):
         self.stats["bytes"] += len(data)
 
     def _transcode(self, data, slot: int, deliver_bytes: int | None) -> int:
-        """Parse, pack into a word-frame and stage; returns the number of
-        invocations the frame carries."""
+        """Parse, then pack the word-frame straight into its staged row;
+        returns the number of invocations the frame carries."""
         from repro.core.device_mailbox import pack_agg_word_frame, pack_word_frame
 
         mb = self.mailbox
@@ -329,24 +414,10 @@ class DeviceMeshChannel(Channel):
                 batch = F.parse_agg(payload)
             except F.FrameError as e:
                 raise TransportError(f"device agg transcode: {e}") from e
-            pays: list[np.ndarray] = []
-            hashes: list[int] = []
-            for i in range(batch.n):
-                if batch.kind(i) != F.CodeKind.UVM:
-                    raise TransportError(
-                        "device mesh accepts UVM sub-records only, got "
-                        f"{batch.kind(i).name}")
-                tiles = np.frombuffer(batch.payload(i), np.float32)
-                if tiles.size != mb.body_words:
-                    raise TransportError(
-                        f"device agg sub payload {tiles.size} words != "
-                        f"bound {mb.body_words}")
-                pays.append(tiles)
-                hashes.append(F.fletcher32(batch.name(i).encode())
-                              & 0xFFFFFFFF)
-            wf = pack_agg_word_frame(pays, hashes, mb.agg_k, mb.body_words,
-                                     mb.slot_words, kind=int(hdr.code_kind),
-                                     no_trailer=partial)
+            pays, hashes = _agg_bodies(batch, mb.body_words)
+            pack_agg_word_frame(pays, hashes, mb.agg_k, mb.body_words,
+                                mb.slot_words, kind=int(hdr.code_kind),
+                                no_trailer=partial, out=self._row(slot))
         else:
             if hdr.code_kind != F.CodeKind.UVM:
                 raise TransportError(
@@ -365,16 +436,15 @@ class DeviceMeshChannel(Channel):
                 # non-agg device path never name-checks (the program is
                 # linked at open), and the per-sub NACK is an aggregate
                 # concept (there is a handle to rebuild from); parity kept.
-                wf = pack_agg_word_frame(
+                pack_agg_word_frame(
                     [tiles], [mb.bound_hash], mb.agg_k, mb.body_words,
                     mb.slot_words, kind=int(hdr.code_kind),
-                    no_trailer=partial)
+                    no_trailer=partial, out=self._row(slot))
             else:
                 name_hash = F.fletcher32(hdr.name.encode()) & 0xFFFFFFFF
-                wf = pack_word_frame(tiles, mb.slot_words,
-                                     kind=int(hdr.code_kind),
-                                     name_hash=name_hash, no_trailer=partial)
-        mb._stage(wf, slot)
+                pack_word_frame(tiles, mb.slot_words, kind=int(hdr.code_kind),
+                                name_hash=name_hash, no_trailer=partial,
+                                out=self._row(slot))
         if partial:
             from repro.kernels.ring_poll import HDR_WORDS, TRAILER
 
@@ -385,12 +455,21 @@ class DeviceMeshChannel(Channel):
             self.stats["partial"] += 1
         return batch.n if hdr.is_agg else 1
 
+    def _row(self, slot: int) -> np.ndarray:
+        """The staged row ``slot`` is written into, taking the next resident
+        generation buffer when none is being filled."""
+        mb = self.mailbox
+        if mb._staged is None:
+            self.stats["gen_allocs"] += mb._open_generation()
+        return mb._stage(slot)
+
     def flush(self) -> None:
         mb = self.mailbox
         staged = mb._staged
         with mb.tracer.scope(
                 "repro.device.publish", n=mb._staged_count,
-                bytes=0 if staged is None else staged.nbytes):
+                bytes=0 if staged is None else staged.nbytes,
+                fresh=int(staged is not None and mb._staged_fresh)):
             for slot, word_idx, trailer in getattr(self, "_pending_trailers",
                                                    []):
                 shard, idx = mb.slot_coords(slot)
