@@ -44,7 +44,7 @@ W = rng.standard_normal((T, T)).astype(np.float32) * 0.05
 dispatcher = Dispatcher(source, ProgressEngine(inflight_window="trailer"))
 dispatcher.add_peer(
     "tpu-mesh", DeviceMeshFabric(mesh, "model", shift=1), None,
-    n_slots=2, slot_size=640 << 10,
+    n_slots=2, slot_size=640 << 10, target_args={"results": []},
     prog=deserialize_uvm(handle.lib.code), n_tiles=NT,
     externals=jnp.broadcast_to(jnp.asarray(W)[None, None], (SHARDS, 1, T, T)))
 

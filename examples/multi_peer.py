@@ -72,6 +72,7 @@ dispatcher.add_peer("csd", LoopbackFabric(),
 uvm_prog = deserialize_uvm(handle.lib.code)
 dispatcher.add_peer("tpu", DeviceMeshFabric(mesh, "model", shift=0), None,
                     n_slots=4, slot_size=SLOT, prog=uvm_prog,
+                    target_args={"results": []},
                     externals=jnp.broadcast_to(jnp.asarray(W)[None, None],
                                                (n_dev, 1, T, T)))
 print(f"dispatcher: {len(dispatcher.peers)} peers over "

@@ -72,10 +72,6 @@ class Context:
     max_stream_bytes: int = 64 << 20         # bound on a buffered stream's
     #                     assembly allocation (a descriptor promising more
     #                     is rejected before any memory is committed)
-    last_agg_results: list | None = None     # per-sub outcomes of the most
-    #                     recent FLAG_AGG frame this ctx consumed (set by
-    #                     poll_ifunc, harvested by Mailbox.sweep into
-    #                     Mailbox.last_agg for the dispatcher's completion)
     obs: object = None                       # repro.obs.Obs bundle (installed
     #                     by the dispatcher's add_peer so target-side exec
     #                     spans land in the same trace as source-side puts)
@@ -438,6 +434,16 @@ def _run_agg(ctx: Context, batch, target_args) -> list[AggSubResult]:
     return out
 
 
+def collect_result(target_args, value) -> None:
+    """Append an answer to ``target_args["results"]`` where the caller put
+    a list there to collect every message's answer (the μVM runner here
+    and the device sweep both do); where it did not, nothing is kept."""
+    if isinstance(target_args, dict):
+        results = target_args.get("results")
+        if results is not None:
+            results.append(value)
+
+
 def _link(ctx: Context, hdr: F.FrameHeader, code: bytes):
     """First-arrival linking — the clear_cache/GOT-reconstruction moment."""
     if hdr.code_kind == F.CodeKind.PYBC:
@@ -475,9 +481,7 @@ def _link(ctx: Context, hdr: F.FrameHeader, code: bytes):
             out = K.uvm_execute(_prog, tiles, ext)
             if isinstance(target_args, dict):
                 target_args["result"] = out
-                # multi-message collection: same contract as the device
-                # fabric's sweep (results accumulate per message)
-                target_args.setdefault("results", []).append(out)
+            collect_result(target_args, out)
             return out
         return run_uvm
     raise PolicyViolation(f"unsupported code kind {hdr.code_kind}")
@@ -707,11 +711,20 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
     can omit them — stream frames are then rejected rather than consumed
     half-blind."""
     buf = buffer if buffer_size is None else memoryview(buffer)[:buffer_size]
+    return _poll(ctx, buf, target_args,
+                 _NO_STREAMS if streams is None else streams, stream_key,
+                 clear)[0]
+
+
+def _poll(ctx: Context, buf, target_args, streams: dict, stream_key,
+          clear: bool = True) -> tuple[Status, list | None]:
+    """The poll core behind :func:`poll_ifunc` and ``Mailbox.sweep``:
+    the slot's Status, and the per-sub-record outcomes of an aggregate
+    container it consumed (None for any other frame)."""
     try:
         hdr = F.peek_header(buf, ctx.policy.max_frame_len)
         if hdr is None:
-            return Status.NO_MESSAGE
-        ctx.last_agg_results = None      # stale outcomes never misattributed
+            return Status.NO_MESSAGE, None
         ctx.policy.check_header(hdr)
         if hdr.is_reply:
             # result-return frames resolve futures via the transport layer's
@@ -721,18 +734,17 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
         while not F.trailer_arrived(buf, hdr):
             spins += 1
             if spins > ctx.max_trailer_spins:
-                return Status.IN_PROGRESS
+                return Status.IN_PROGRESS, None
             ctx.wait_mem(spins)
         if hdr.is_stream:
-            return _poll_stream(ctx, buf, hdr, target_args,
-                                _NO_STREAMS if streams is None else streams,
-                                stream_key, clear)
+            return _poll_stream(ctx, buf, hdr, target_args, streams,
+                                stream_key, clear), None
         code, payload = F.frame_sections(buf, hdr)
         if hdr.is_agg:
             # coalesced dispatch: ONE container frame carries K cached
             # invocations — decode the whole batch (one signal check) and
-            # run every record in a single pass; per-record outcomes land
-            # in ctx.last_agg_results for the transport completion.
+            # run every record in a single pass; the per-record outcomes
+            # go back to the sweep for the transport completion.
             batch = F.parse_agg(payload)         # FrameError -> REJECTED
             o = ctx.obs
             if o is not None and o.enabled:
@@ -747,11 +759,10 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
                     o.tracer.end(sp)
             else:
                 results = _run_agg(ctx, batch, target_args)
-            ctx.last_agg_results = results
             ctx.stats["bytes_in"] += hdr.frame_len
             if clear:
                 F.clear_frame(buf, hdr)
-            return Status.OK
+            return Status.OK, results
         cont = F.frame_cont(buf, hdr)
         if cont is not None and ctx.flow is None:
             # a continuation frame needs a forwarding hook installed — one
@@ -768,7 +779,7 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
                 ctx.stats["last_nack"] = (hdr.name, hdr.digest)
                 if clear:
                     F.clear_frame(buf, hdr)
-                return Status.NACK_UNCACHED
+                return Status.NACK_UNCACHED, None
             code_b = bytes(code)
             if F.compute_digest(code_b) != hdr.digest:
                 raise F.FrameError("code digest mismatch (corrupt code "
@@ -782,7 +793,7 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
         ctx.stats["last_reject"] = f"{type(e).__name__}: {e}"
         if clear:
             F.scrub_slot(buf)     # best-effort clear of the bad slot
-        return Status.REJECTED
+        return Status.REJECTED, None
     if cont is not None:
         # flow frame: the hook owns execution — it runs (or buffers, for a
         # gather rendezvous) the linked fn, catches the stage's exception
@@ -797,7 +808,7 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
             ctx.stats["last_reject"] = f"{type(e).__name__}: {e}"
             if clear:
                 F.scrub_slot(buf)
-            return Status.REJECTED
+            return Status.REJECTED, None
     else:
         o = ctx.obs
         if o is not None and o.enabled:
@@ -818,7 +829,7 @@ def poll_ifunc(ctx: Context, buffer, buffer_size: int | None, target_args,
     ctx.stats["bytes_in"] += hdr.frame_len
     if clear:
         F.clear_frame(buf, hdr)
-    return Status.OK
+    return Status.OK, None
 
 
 def poll_ring(ctx: Context, ring: R.RingBuffer, target_args) -> Status:
@@ -829,5 +840,7 @@ def poll_ring(ctx: Context, ring: R.RingBuffer, target_args) -> Status:
     ``transport.ring_mailbox(ring).sweep(...)`` directly."""
     from repro.transport.fabric import ring_mailbox
 
-    sts = ring_mailbox(ring).sweep(ctx, target_args, budget=1)
-    return sts[0] if sts else Status.NO_MESSAGE
+    done, raised = ring_mailbox(ring).sweep(ctx, target_args, budget=1)
+    if raised is not None:
+        raise raised
+    return done[0].status if done else Status.NO_MESSAGE

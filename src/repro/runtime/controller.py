@@ -43,8 +43,11 @@ class WorkerAgent:
         """Drain up to max_msgs control messages (called between steps)."""
         if self.mailbox is None:
             return 0
-        sts = self.mailbox.sweep(self.ctx, self.hooks, budget=max_msgs)
-        return sum(1 for st in sts if st == A.Status.OK)
+        done, raised = self.mailbox.sweep(self.ctx, self.hooks,
+                                          budget=max_msgs)
+        if raised is not None:
+            raise raised
+        return sum(1 for c in done if c.status == A.Status.OK)
 
 
 class PodController:

@@ -251,7 +251,7 @@ class ElasticController:
                     self._pump_beat(m, now)
             if down:
                 continue                 # dead progress side: frames sit
-            m.mailbox.sweep(m.ctx, m.targs, budget=self.n_slots)
+            self._sweep(m)
             beats = m.targs.get("hb", {}).get("beats", 0)
             if beats > m.folded:         # ONLY an executed beat proves life
                 self.stats["beats_folded"] += beats - m.folded
@@ -280,6 +280,12 @@ class ElasticController:
         m.tail += 1
         m.last_beat = now
         self.stats["beats_sent"] += 1
+
+    def _sweep(self, m: _Member) -> None:
+        """Run the member's control frames; an ifunc's raise surfaces."""
+        raised = m.mailbox.sweep(m.ctx, m.targs, budget=self.n_slots)[1]
+        if raised is not None:
+            raise raised
 
     # -- failure path --------------------------------------------------------
 
@@ -397,6 +403,6 @@ class ElasticController:
         eng.post(m.channel, slab[:n], m.tail, peer=f"hb:{m.name}")
         eng.flush(m.channel)
         m.tail += 1
-        m.mailbox.sweep(m.ctx, m.targs, budget=self.n_slots)
+        self._sweep(m)
         m.targs.pop("relink", None)
         self.stats["manifest_entries"] += len(manifest)

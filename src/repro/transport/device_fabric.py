@@ -28,11 +28,8 @@ import numpy as np
 
 from repro.core import frame as F
 from repro.obs.trace import Tracer
-from repro.transport.fabric import Channel, Fabric, Mailbox, TransportError
-
-
-#: the scopes of a lane no dispatcher has handed its bundle to
-_NO_TRACER = Tracer()
+from repro.transport.fabric import (Channel, Fabric, Mailbox, Swept,
+                                    TransportError)
 
 
 class DeviceMeshMailbox(Mailbox):
@@ -42,10 +39,13 @@ class DeviceMeshMailbox(Mailbox):
     #: (the lane has no target context): its scopes, its channel's, and
     #: ``target.sweep_us`` go there
     obs = None
+    #: a sweep consumes whatever deposited frames it finds, so a frame
+    #: below ``consumed`` may be one that never arrived
+    in_order = False
 
     @property
     def tracer(self) -> Tracer:
-        return _NO_TRACER if self.obs is None else self.obs.tracer
+        return Mailbox.tracer if self.obs is None else self.obs.tracer
 
     def __init__(self, fabric: "DeviceMeshFabric", mesh, axis: str, prog,
                  externals, n_slots: int, n_tiles: int, tile: int = 128,
@@ -108,14 +108,6 @@ class DeviceMeshMailbox(Mailbox):
         self._carried: list[list] = [[], []]  # (shard, idx) rows per buffer
         self._fences: list = [None, None]     # the deposit that read it
         self._deposited = 0                          # frames awaiting sweep
-        self.results: list = []                      # READY outputs, one entry
-        #                                 per consumed container/singleton
-        self.last_coords: list[tuple[int, int]] = []  # (shard, slot) per
-        #                                 status of the most recent sweep,
-        #                                 where the frame was *staged* (a
-        #                                 deposit lands ``shift`` shards on)
-        #                                 — the reply demux correlates device
-        #                                 results to task corr-ids with this
 
     @property
     def supports_agg(self) -> bool:
@@ -197,25 +189,25 @@ class DeviceMeshMailbox(Mailbox):
         raise TransportError("device mailbox slots live in device memory; "
                              "use sweep()")
 
-    def sweep(self, ctx, target_args, budget: int | None = None) -> list:
-        """One compiled validate+execute pass over every deposited slot.
+    def sweep(self, ctx, target_args, budget: int | None = None):
+        """One compiled validate+execute pass over every deposited slot;
+        ``(completions, None)`` as on a host lane (no ifunc raises here).
         ``budget`` is ignored: the sweep is a single device program, so a
         device lane may yield more than one message per dispatcher poll
         round (its yield still counts against the caller's total budget).
-        READY results land in ``self.results`` and
-        ``target_args['results']``."""
+        A completion's coordinate is where its frame was *staged* (a
+        deposit lands ``shift`` shards on)."""
         if self._deposited == 0:
-            self.last_coords = []
-            return []
+            return [], None
         o = self.obs
         t0 = time.perf_counter() if o is not None and o.enabled else None
         consumed0 = self.consumed
-        statuses = (self._sweep_agg if self.agg_k
-                    else self._sweep_one)(target_args)
+        done = (self._sweep_agg if self.agg_k
+                else self._sweep_one)(target_args)
         if t0 is not None and self.consumed != consumed0:
             # as on a host lane: only sweeps that consumed something observe
             o.sweep_hist.observe((time.perf_counter() - t0) * 1e6)
-        return statuses
+        return done, None
 
     def _run_sweep(self) -> list:
         """The jitted sweep over the ring, its outputs read back to the
@@ -229,111 +221,86 @@ class DeviceMeshMailbox(Mailbox):
         self._fences = [None, None]
         return host
 
-    def _sweep_one(self, target_args) -> list:
+    def _demux(self, status, ready) -> list:
+        """One :class:`Swept` per slot the sweep found a frame in, with
+        ``ready(c, shard, slot)`` filling in a READY one; then advance the
+        consume counters past the OK/REJECTED ones."""
         from repro.core.api import Status
         from repro.kernels.ring_poll import BAD, INFLIGHT, READY
 
-        status, out = self._run_sweep()
+        as_status = {READY: Status.OK, BAD: Status.REJECTED,
+                     INFLIGHT: Status.IN_PROGRESS}
         with self.tracer.scope("repro.device.demux") as sc:
-            copy = _sparse(int((status == READY).sum()), status.size)
-            statuses: list = []
-            self.last_coords = []
+            done: list[Swept] = []
             for shard in range(status.shape[0]):
                 for slot in range(status.shape[1]):
-                    st = int(status[shard, slot])
-                    coord = self._staged_at(shard, slot)
-                    if st == READY:
-                        val = out[shard, slot]
-                        val = val.copy() if copy else val
-                        self.results.append(val)
-                        if isinstance(target_args, dict):
-                            target_args.setdefault("results", []).append(val)
-                        statuses.append(Status.OK)
-                        self.last_coords.append(coord)
-                    elif st == BAD:
-                        statuses.append(Status.REJECTED)
-                        self.last_coords.append(coord)
-                    elif st == INFLIGHT:
-                        statuses.append(Status.IN_PROGRESS)
-                        self.last_coords.append(coord)
-            sc.set_metadata(n=self._consume(statuses))
-        return statuses
+                    st = as_status.get(int(status[shard, slot]))
+                    if st is None:
+                        continue                     # EMPTY
+                    c = Swept(self._staged_at(shard, slot), st)
+                    if st is Status.OK:
+                        ready(c, shard, slot)
+                    elif st is Status.REJECTED:
+                        c.error = TransportError("frame rejected on device "
+                                                 "sweep")
+                    done.append(c)
+            consumed = sum(1 for c in done if c.status is not
+                           Status.IN_PROGRESS)
+            self.head += consumed
+            self.consumed += consumed
+            self._deposited = max(self._deposited - consumed, 0)
+            sc.set_metadata(n=consumed)
+        return done
 
-    def _consume(self, statuses) -> int:
-        """Advance the consume counters past a sweep's OK/REJECTED slots."""
-        from repro.core.api import Status
+    def _sweep_one(self, target_args) -> list:
+        from repro.core.api import collect_result
+        from repro.kernels.ring_poll import READY
 
-        consumed = sum(1 for s in statuses
-                       if s in (Status.OK, Status.REJECTED))
-        self.head += consumed
-        self.consumed += consumed
-        self._deposited = max(self._deposited - consumed, 0)
-        return consumed
+        status, out = self._run_sweep()
+        copy = _sparse(int((status == READY).sum()), status.size)
+
+        def ready(c, shard, slot):
+            c.value = out[shard, slot].copy() if copy else out[shard, slot]
+            collect_result(target_args, c.value)
+        return self._demux(status, ready)
 
     def _sweep_agg(self, target_args) -> list:
         """Aggregate-container sweep: one batched kernel pass validates all
         containers + descriptors and ONE μVM launch executes every
-        sub-record body; per-sub outcomes land in ``last_agg`` keyed by
-        coordinates so the dispatcher completes them with host-lane
+        sub-record body; each READY container's completion carries its
+        per-sub outcomes, so the dispatcher completes them with host-lane
         semantics (per-sub NACK rebuild, poisoned sub = ERR with siblings
         unharmed, corrupt container = whole REJECT)."""
-        from repro.core.api import AggSubResult, Status
+        from repro.core.api import AggSubResult, Status, collect_result
         from repro.kernels.agg_poll import SUB_BAD, SUB_EMPTY, SUB_READY
-        from repro.kernels.ring_poll import BAD, INFLIGHT, READY
 
         status, sub_st, out = self._run_sweep()
-        with self.tracer.scope("repro.device.demux") as sc:
-            copy = _sparse(int((sub_st == SUB_READY).sum()), sub_st.size)
-            statuses: list = []
-            self.last_coords = []
-            for shard in range(status.shape[0]):
-                for slot in range(status.shape[1]):
-                    st = int(status[shard, slot])
-                    coord = self._staged_at(shard, slot)
-                    if st == READY:
-                        subs: list[AggSubResult] = []
-                        vals: list = []
-                        for i in range(self.agg_k):
-                            s_i = int(sub_st[shard, slot, i])
-                            if s_i == SUB_EMPTY:
-                                break
-                            if s_i == SUB_READY:
-                                val = out[shard, slot, i]
-                                val = val.copy() if copy else val
-                                subs.append(AggSubResult(
-                                    Status.OK, "", b"", 0, value=val))
-                                vals.append(val)
-                            elif s_i == SUB_BAD:
-                                subs.append(AggSubResult(
-                                    Status.REJECTED, "", b"", 0,
-                                    error=TransportError(
-                                        "poisoned sub-record (descriptor "
-                                        "check mismatch)")))
-                            else:                    # SUB_NACK
-                                subs.append(AggSubResult(
-                                    Status.NACK_UNCACHED, "", b"", 0))
-                        self.last_agg[coord] = subs
-                        while len(self.last_agg) > 2 * self.n_slots:
-                            self.last_agg.pop(next(iter(self.last_agg)))
-                        # ONE results entry per consumed container keeps the
-                        # dispatcher's per-status result cursor aligned: a
-                        # 1-sub container (transcoded singleton) yields its
-                        # bare output, a K-sub one the per-sub list
-                        entry = vals[0] if len(subs) == 1 and vals else vals
-                        self.results.append(entry)
-                        if isinstance(target_args, dict):
-                            target_args.setdefault("results",
-                                                   []).extend(vals)
-                        statuses.append(Status.OK)
-                        self.last_coords.append(coord)
-                    elif st == BAD:
-                        statuses.append(Status.REJECTED)
-                        self.last_coords.append(coord)
-                    elif st == INFLIGHT:
-                        statuses.append(Status.IN_PROGRESS)
-                        self.last_coords.append(coord)
-            sc.set_metadata(n=self._consume(statuses))
-        return statuses
+        copy = _sparse(int((sub_st == SUB_READY).sum()), sub_st.size)
+
+        def ready(c, shard, slot):
+            c.subs = []
+            for i in range(self.agg_k):
+                s_i = int(sub_st[shard, slot, i])
+                if s_i == SUB_EMPTY:
+                    break
+                if s_i == SUB_READY:
+                    val = out[shard, slot, i]
+                    val = val.copy() if copy else val
+                    c.subs.append(AggSubResult(Status.OK, "", b"", 0,
+                                               value=val))
+                    collect_result(target_args, val)
+                elif s_i == SUB_BAD:
+                    c.subs.append(AggSubResult(
+                        Status.REJECTED, "", b"", 0, error=TransportError(
+                            "poisoned sub-record (descriptor check "
+                            "mismatch)")))
+                else:                            # SUB_NACK
+                    c.subs.append(AggSubResult(Status.NACK_UNCACHED, "",
+                                               b"", 0))
+            if len(c.subs) == 1:
+                # maybe a transcoded singleton: its answer is its one sub's
+                c.value, c.error = c.subs[0].value, c.subs[0].error
+        return self._demux(status, ready)
 
 
 def _sparse(n_answers: int, capacity: int) -> bool:

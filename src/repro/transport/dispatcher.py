@@ -42,9 +42,12 @@ And the *result-return path* (the task runtime's wire, see ``repro.tasks``):
   as a reply frame with the same corr_id; ``poll_replies`` drains reply
   rings and hands ``(corr_id, value)`` to the registered ``reply_router``;
 * device-mesh lanes have no reverse ring: the sweep's READY results *are*
-  the replies — the dispatcher correlates them to corr-ids by the
-  (shard, slot) coordinates each send staged into and routes them through
-  the same ``reply_router``;
+  the replies, routed straight to the same ``reply_router``;
+* every fabric hands its completions back the same way: a sweep returns
+  one ``fabric.Swept`` record per frame it reached, keyed by the slot
+  coordinate the frame was posted to (``Mailbox.slot_coords``), and
+  ``poll`` completes each against the lane's one in-flight table
+  (``RingState.inflight``) in one status ladder;
 * encoding is delegated to a pluggable ``reply_codec`` (the task layer's
   wire module) so the transport stays value-format-agnostic.
 
@@ -71,18 +74,19 @@ rate lever:
   ``agg_max_age`` checked each poll.  A queue holding a single record
   flushes as a plain SLIM singleton — the latency path never regresses;
 * the target decodes the whole container in one ``poll_ifunc`` pass and
-  reports per-sub-record statuses (``Mailbox.last_agg``): a sub-record
-  whose digest was evicted NACKs *individually* and is rebuilt as a FULL
-  singleton retransmit — its executed siblings are never replayed — on
-  the same quiescence-gated resend queue per-peer FIFO already rides;
+  reports per-sub-record statuses (the container's ``Swept.subs``): a
+  sub-record whose digest was evicted NACKs *individually* and is rebuilt
+  as a FULL singleton retransmit — its executed siblings are never
+  replayed — on the same quiescence-gated resend queue per-peer FIFO
+  already rides;
 * replies coalesce symmetrically: the corr-carrying records of one
   aggregate post their results as ONE ``FLAG_AGG|FLAG_REPLY`` frame into
   the reply ring, and ``poll_replies`` demuxes it back per corr_id;
 * unbudgeted polls (``drain``) sweep a whole ring's worth of ready slots
   per lane visit instead of one message per poll-loop round — budgeted
   polls keep the historical one-per-lane-per-round fairness contract;
-* device-mesh lanes never coalesce: the deposit/sweep pipeline already
-  batches generation-wide (aggregates are host-tier by construction).
+* device-mesh lanes coalesce only when opened agg-bound (``agg_k=``):
+  the put transcodes a container into a K-sub word-frame.
 
 And *streamed large payloads* (frame v2.5 ``FLAG_STREAM``), the
 64KiB-cliff killer on the other end of the size spectrum:
@@ -130,8 +134,8 @@ DEFAULT_N_SLOTS = 8
 _PEER_STAT_KEYS = (
     "sent", "bytes", "delivered", "rejected", "backpressure",
     "inflight_polls", "slim_sent", "nacks", "resent", "replies", "errors",
-    "coalesced", "agg_sent", "agg_subs", "agg_replies", "agg_harvest_lost",
-    "nack_lost", "reply_rejects", "streams", "stream_chunks", "timed_out",
+    "coalesced", "agg_sent", "agg_subs", "agg_replies", "nack_lost",
+    "reply_rejects", "streams", "stream_chunks", "timed_out",
     "fenced_orphans", "dropped_puts")
 
 _API = None      # repro.core.api, imported lazily (it imports codegen —
@@ -166,6 +170,7 @@ class _TxRec:
     #                         frame (the pump's source-side state)
     span: object = None     # open obs wire span (tracing runs only): put ->
     #                         delivery confirmation / NACK / reject
+    slot: int = 0           # produce index the frame was posted at
 
 
 @dataclass(slots=True)
@@ -261,19 +266,36 @@ class RingState:
     mailbox: object
     channel: object
     tail: int = 0            # source-side produce index
-    inflight: dict = field(default_factory=dict)   # abs slot -> _TxRec
-    corr_by_coords: dict = field(default_factory=dict)  # device lanes:
-    #                                    (shard, slot) -> (corr_id, sent_at)
-    #                                    awaiting a sweep result
-    agg_by_coords: dict = field(default_factory=dict)  # device lanes:
-    #                                    (shard, slot) -> _TxRec of a staged
-    #                                    aggregate container (device frames
-    #                                    have no slot->inflight tracking; the
-    #                                    sweep reports by coordinates)
+    inflight: dict = field(default_factory=dict)   # mailbox.slot_coords of
+    #                                    the produce index -> _TxRec, the
+    #                                    one table a sweep's Swept records
+    #                                    complete against
 
     @property
     def credits(self) -> int:
         return self.mailbox.n_slots - (self.tail - self.mailbox.consumed)
+
+    def track(self, rec: _TxRec) -> None:
+        """Enter the frame being posted at ``tail`` in the in-flight table,
+        under the coordinate its completion will carry."""
+        rec.slot = self.tail
+        self.inflight[self.mailbox.slot_coords(self.tail)] = rec
+        if len(self.inflight) > 2 * self.mailbox.n_slots:
+            self.prune()
+
+    def done_elsewhere(self, rec: _TxRec) -> bool:
+        """Whether a sweeper other than the dispatcher (a WorkerAgent's own
+        poll) consumed ``rec``'s frame, so it will never complete here: on
+        a mailbox that consumes in produce order, any frame below
+        ``consumed``."""
+        mb = self.mailbox
+        return mb.in_order and rec.slot < mb.consumed
+
+    def prune(self) -> None:
+        """Drop the records of frames consumed elsewhere."""
+        for k in [k for k, rec in self.inflight.items()
+                  if self.done_elsewhere(rec)]:
+            del self.inflight[k]
 
 
 @dataclass
@@ -329,21 +351,14 @@ class Peer:
     def oldest_inflight_age(self, now: float | None = None) -> float:
         """Age (seconds) of the oldest tracked frame still awaiting its
         target's sweep — the liveness floor signal.  0.0 when nothing is
-        in flight.  Covers handle sends on host lanes and corr-carrying
-        stages on device lanes (``corr_by_coords``), so a wedged mesh is
-        as visible as a wedged host ring."""
+        in flight.  Covers every tracked send on every fabric, so a wedged
+        mesh is as visible as a wedged host ring."""
         now = time.monotonic() if now is None else now
         oldest = None
         for r in self.rings:
-            for slot, rec in r.inflight.items():
-                if slot < r.mailbox.consumed:
-                    continue            # consumed by an external sweeper
-                if oldest is None or rec.sent_at < oldest:
-                    oldest = rec.sent_at
-            for _, sent_at in r.corr_by_coords.values():
-                if oldest is None or sent_at < oldest:
-                    oldest = sent_at
-            for rec in r.agg_by_coords.values():
+            for rec in r.inflight.values():
+                if r.done_elsewhere(rec):
+                    continue
                 if oldest is None or rec.sent_at < oldest:
                     oldest = rec.sent_at
         return 0.0 if oldest is None else max(0.0, now - oldest)
@@ -397,9 +412,6 @@ class Dispatcher:
         self._stream_threshold = None    # None -> _agg_max_sub_bytes
         self._stream_nonce = 0           # monotone: unique per stream open
         self._active_streams: list[_StreamTx] = []
-        self._sweep_raise = None   # deferred mid-batch ifunc exception (a
-        #       corr-less poisoned slot behind already-swept frames): poll
-        #       re-raises it only after processing those frames' statuses
         self.faults = None       # FaultInjector: consulted (when set) at the
         #       poll loop (down peers stop being swept), the post point
         #       (k-th-put drops), and the ElasticController's beat pump
@@ -544,8 +556,6 @@ class Dispatcher:
         for r in peer.rings:
             self.engine.release_slab(r.channel)
             r.inflight.clear()
-            r.corr_by_coords.clear()
-            r.agg_by_coords.clear()
         if peer.reply_channel is not None:
             self.engine.release_slab(peer.reply_channel)
         peer.resend.clear()
@@ -621,8 +631,7 @@ class Dispatcher:
             o.recorder.add("put", peer.name,
                            f"{rec.name} corr={rec.corr_id} {len(view)}B"
                            f"{' slim' if rec.slim else ''}")
-            if (o.tracer.enabled and rec.span is None
-                    and peer.fabric.kind != "device"):
+            if o.tracer.enabled and rec.span is None:
                 # the wire span: post -> delivery confirmation (poll OK),
                 # NACK, or reject — ended where the inflight record pops
                 rec.span = o.tracer.begin(
@@ -643,25 +652,8 @@ class Dispatcher:
         else:
             self.engine.post(lane.channel, view, lane.tail, peer=peer.name,
                              on_complete=on_complete, future=future)
-        if rec is not None and peer.fabric.kind != "device":
-            lane.inflight[lane.tail] = rec
-            if len(lane.inflight) > 2 * lane.mailbox.n_slots:
-                # target sweeps outside our poll loop (e.g. WorkerAgent):
-                # drop records for slots already consumed elsewhere
-                low = lane.mailbox.consumed
-                for s in [s for s in lane.inflight if s < low]:
-                    del lane.inflight[s]
-        if (rec is not None and rec.corr_id
-                and peer.fabric.kind == "device"):
-            # device replies come back as sweep results at the coordinates
-            # this send stages into (the Mailbox.slot_coords contract)
-            lane.corr_by_coords[lane.mailbox.slot_coords(lane.tail)] = (
-                rec.corr_id, rec.sent_at)
-        if (rec is not None and rec.subs is not None
-                and peer.fabric.kind == "device"):
-            # device aggregates complete by coordinates: the sweep leaves
-            # per-sub outcomes in Mailbox.last_agg keyed the same way
-            lane.agg_by_coords[lane.mailbox.slot_coords(lane.tail)] = rec
+        if rec is not None:
+            lane.track(rec)
         lane.tail += 1
         if peer.stripe and lane is peer.rings[
                 peer.stripe_tx % len(peer.rings)]:
@@ -876,7 +868,7 @@ class Dispatcher:
                     actor=getattr(self.src_ctx, "name", "source"),
                     corr=tx.corr_id or None, bytes=desc.total_len,
                     chunks=desc.n_chunks)
-        lane.inflight[lane.tail] = rec
+        lane.track(rec)
         lane.tail += 1
         peer.stats["sent"] += 1
         if slim:
@@ -1287,7 +1279,7 @@ class Dispatcher:
         rec = _TxRec(F.AGG_NAME, F.NO_DIGEST, None, slim=True,
                      subs=list(subs))
         o = self.obs
-        if o.tracer.enabled and peer.fabric.kind != "device":
+        if o.tracer.enabled:
             # the container flush is its own span: the coalesced records'
             # submit spans (tasks layer) nest around it by corr
             rec.span = o.tracer.begin(
@@ -1552,93 +1544,119 @@ class Dispatcher:
     def _lanes(self) -> list[tuple[Peer, RingState]]:
         return [(p, r) for p in self.peers.values() for r in p.rings]
 
-    def _rebuild_full(self, lane: RingState, abs_slot: int, rec: _TxRec):
+    def _rebuild_full(self, lane: RingState, rec: _TxRec):
         """NACK fallback: the SLIM frame still sits in the source slab cell
         for its slot (the credit only just returned, nothing has overwritten
         it); hand it to ``ifunc_msg_to_full`` to restore the code section."""
         A = _api()
 
-        view = self.engine.slab_slot(lane.channel, abs_slot)
+        view = self.engine.slab_slot(lane.channel, rec.slot)
         return A.ifunc_msg_to_full(A.IfuncMsg(rec.handle, view, slim=True))
 
-    def _sweep_task(self, peer: Peer, lane: RingState,
-                    max_slots: int = 1) -> list:
-        """Sweep up to ``max_slots`` ready slots of a reply-enabled host
-        lane: per slot, capture the request's corr_id before execution
-        destroys the frame, capture the ifunc's output
-        (``target_args["result"]``) — or the exception it raised — after,
-        and post the encoded reply.  An ifunc exception consumes the slot
-        (clear + head advance) instead of wedging the ring; the error
-        travels back as a FLAG_ERR reply.  A fire-and-forget frame
-        (corr_id == 0) has no reply to carry the error, so after consuming
-        the slot the exception re-raises to the poll caller — same
-        visibility as a plain dispatcher; mid-batch, the raise is
-        *deferred* until the statuses of the slots already swept in this
-        batch have been processed (``poll`` re-raises it after this
-        lane's completion), so a delivered aggregate ahead of a poisoned
-        slot still confirms digests and resolves its futures.  Aggregate
-        containers pass through untouched here (header corr is 0); their
-        per-sub-record replies coalesce in :meth:`_complete_agg`."""
+    def _complete(self, peer: Peer, lane: RingState,
+                  done: list) -> tuple[int, int]:
+        """Complete one sweep's records against the lane's in-flight table.
+        OK confirms the digest and answers a corr-carrying frame (its value,
+        or the error its ifunc raised); REJECTED answers with the error;
+        NACK_UNCACHED un-confirms the digest and queues a FULL retransmit.
+        Returns (messages delivered or rejected, slots consumed)."""
         Status = _api().Status
-
-        mb = lane.mailbox
-        out: list = []
-        for _ in range(max_slots):
-            buf = mb.slot_view(mb.head)
-            hdr = mb.peek()                  # fabric-contract header peek
-            corr = 0 if hdr is None else hdr.corr_id
-            name = "" if hdr is None else hdr.name
-            kind = F.CodeKind.PYBC if hdr is None else hdr.code_kind
-            targs = peer.target_args
-            if isinstance(targs, dict):
-                targs.pop("result", None)
-            err = None
-            try:
-                sts = mb.sweep(peer.target_ctx, targs, budget=1)
-            except Exception as e:           # raised *inside* the ifunc
-                err = e
-                F.scrub_slot(buf)
-                mb.streams.pop(mb.slot_coords(mb.head), None)   # a raising
-                #              exec-on-arrival stream dies with its slot
-                mb.head += 1                 # consume the poisoned slot
-                mb.consumed += 1
-                peer.stats["errors"] += 1
-                if not corr:
-                    if not out:
-                        raise                # no future to carry the error
-                    self._sweep_raise = e    # don't discard what the batch
-                    break                    # already swept: raise after
-                sts = [Status.OK]            # delivered — it just raised
-            if corr and sts and sts[0] in (Status.OK, Status.REJECTED):
-                if err is not None:
-                    value, is_err = err, True
-                elif sts[0] == Status.REJECTED:
-                    value, is_err = TransportError(
-                        str(peer.target_ctx.stats.get(
-                            "last_reject", "frame rejected"))), True
+        o = self.obs
+        n = consumed = 0
+        for c in done:
+            st = c.status
+            if st is Status.IN_PROGRESS:
+                peer.stats["inflight_polls"] += 1
+                continue
+            consumed += 1
+            rec = lane.inflight.pop(c.coord, None)
+            corr = c.corr if rec is None else rec.corr_id
+            if st is Status.OK:
+                if rec is not None and rec.subs is not None:
+                    # aggregate container: per-sub-record completion (cache
+                    # confirms, individual NACK rebuilds, one coalesced reply)
+                    n += self._complete_agg(peer, rec, c.subs)
+                    continue
+                peer.stats["delivered"] += 1
+                n += 1
+                if rec is not None:
+                    peer.cached.add(rec.digest)
+                    if rec.stream is not None:
+                        rec.stream.dead = True       # complete: pump off
+                    if o.enabled:
+                        o.rtt_hist.observe(
+                            (time.monotonic() - rec.sent_at) * 1e6)
+                        if rec.span is not None:
+                            o.tracer.end(rec.span, status="ok")
+                            rec.span = None
+                if c.error is not None:
+                    peer.stats["errors"] += 1        # delivered: it raised
+                if corr:
+                    self._post_reply(
+                        peer, c.name, c.kind, corr,
+                        c.value if c.error is None else c.error,
+                        c.error is not None)
+            elif st is Status.REJECTED:
+                peer.stats["rejected"] += 1
+                n += 1
+                if rec is not None:
+                    if o.enabled:
+                        o.recorder.add("reject", peer.name,
+                                       f"{rec.name} corr={rec.corr_id}")
+                        if rec.span is not None:
+                            o.tracer.end(rec.span, status="rejected")
+                            rec.span = None
+                    if rec.stream is not None:
+                        # corrupt stream: ONLY this stream dies — stop its
+                        # pump; the scrubbed slot flows on
+                        rec.stream.dead = True
+                    for sub in rec.subs or ():
+                        # whole container rejected (corrupt aggregate
+                        # signal): every corr-carrying record resolves
+                        # with the transport error — none executed
+                        if sub.corr_id:
+                            self._route_reply(
+                                sub.corr_id, peer.name,
+                                TransportError("aggregate container "
+                                               "rejected"),
+                                True, decoded=True)
+                if corr:
+                    self._post_reply(peer, c.name, c.kind, corr, c.error,
+                                     True)
+            else:                                    # NACK_UNCACHED
+                peer.stats["nacks"] += 1
+                self.stats["nacks"] += 1
+                if rec is not None and o.enabled:
+                    o.recorder.add("nack", peer.name,
+                                   f"{rec.name} corr={rec.corr_id} "
+                                   f"slim miss")
+                    if rec.span is not None:
+                        o.tracer.end(rec.span, status="nack")
+                        rec.span = None
+                if rec is not None and rec.stream is not None:
+                    # SLIM stream missed the cache at its descriptor: park
+                    # the pump and queue a FULL re-open from chunk 0
+                    # (nothing executed)
+                    rec.stream.dead = True
+                    peer.cached.discard(rec.digest)
+                    peer.resend.append(_StreamResend(rec.stream))
+                elif rec is not None and rec.handle is not None:
+                    peer.cached.discard(rec.digest)
+                    peer.resend.append(self._rebuild_full(lane, rec))
                 else:
-                    value = (targs.get("result")
-                             if isinstance(targs, dict) else None)
-                    is_err = False
-                self._post_reply(peer, name, kind, corr, value, is_err)
-            out.extend(sts)
-            if not sts or sts[-1] not in (Status.OK, Status.REJECTED,
-                                          Status.NACK_UNCACHED):
-                break                        # empty / in-progress: stop here
-        return out
+                    # a SLIM frame we have no record/handle for (raw send):
+                    # nothing to rebuild — surface the loss
+                    peer.stats["nack_lost"] += 1
+        return n, consumed
 
-    def _complete_agg(self, peer: Peer, lane: RingState, rec: _TxRec,
-                      coords) -> int:
+    def _complete_agg(self, peer: Peer, rec: _TxRec, results) -> int:
         """Source-side completion of one delivered aggregate: walk the
-        per-sub-record outcomes the target's sweep left in
-        ``Mailbox.last_agg`` under ``coords`` — confirm cached digests,
-        queue FULL-singleton retransmits for digest misses (ONLY the
-        missed records; executed siblings are never replayed), and
-        coalesce corr-carrying results into one reply frame (device
-        lanes, which have no reply ring, route each result straight to
-        the reply router instead).  Returns the number of consumed (OK or
-        rejected) sub-records, i.e. this container's contribution to the
-        poll budget."""
+        per-sub-record outcomes its :class:`~repro.transport.fabric.Swept`
+        carried — confirm cached digests, queue FULL-singleton retransmits
+        for digest misses (ONLY the missed records; executed siblings are
+        never replayed), and coalesce corr-carrying results into one reply.
+        Returns the number of consumed (OK or rejected) sub-records, i.e.
+        this container's contribution to the poll budget."""
         A = _api()
         Status = A.Status
         o = self.obs
@@ -1647,14 +1665,6 @@ class Dispatcher:
             if rec.span is not None:
                 o.tracer.end(rec.span, subs=len(rec.subs or ()))
                 rec.span = None
-        results = lane.mailbox.last_agg.pop(coords, None)
-        if results is not None and len(results) != len(rec.subs):
-            # a harvest that does not match the container we sent (an
-            # external sweeper raced us, or the bounded stash evicted):
-            # trusting per-index outcomes would misattribute NACKs —
-            # treat as delivered-without-detail instead
-            peer.stats["agg_harvest_lost"] += 1
-            results = None
         cached_add = peer.cached.add
         subs = rec.subs
         ok_marker = A._AGG_PLAIN_OK
@@ -1674,7 +1684,7 @@ class Dispatcher:
             return len(subs)
         consumed = n_ok = n_rej = n_nack = n_err = 0
         reply_subs = []
-        for i, sub in enumerate(rec.subs):
+        for i, sub in enumerate(subs):
             res = (results[i] if results is not None and i < len(results)
                    else None)
             st = Status.OK if res is None else res.status
@@ -1724,50 +1734,48 @@ class Dispatcher:
             s["nacks"] += n_nack
             self.stats["nacks"] += n_nack
         if reply_subs:
-            if peer.fabric.kind == "device":
-                # no reply ring on a mesh lane: the sweep's harvested
-                # values ARE the results — route them directly, decoded
-                for sub, value, is_err in reply_subs:
-                    self._route_reply(sub.corr_id, peer.name, value,
-                                      is_err, decoded=True)
-                s["replies"] += len(reply_subs)
-                self.stats["replies"] += len(reply_subs)
-            else:
-                self._post_agg_reply(peer, reply_subs)
+            self._post_agg_reply(peer, reply_subs)
         return consumed
 
     def _post_agg_reply(self, peer: Peer, reply_subs: list[tuple]) -> None:
         """Coalesce the results of one aggregate's corr-carrying records
         into ONE ``FLAG_AGG|FLAG_REPLY`` frame on the peer's reply ring —
         the response direction amortizes exactly like the request one.
-        Falls back to singleton replies when there is only one result (or
-        the encoded batch outgrows a reply slot)."""
-        if peer.reply_channel is None or self.reply_codec is None:
-            self.stats["reply_dropped"] += len(reply_subs)
+        Falls back to singleton replies when there is only one result or
+        the encoded batch outgrows a reply slot.  With no reply ring (a
+        device lane) the values are the results, routed straight to the
+        reply router."""
+        if peer.reply_channel is None:
+            for sub, value, is_err in reply_subs:
+                self._route_reply(sub.corr_id, peer.name, value, is_err,
+                                  decoded=True)
+            peer.stats["replies"] += len(reply_subs)
+            self.stats["replies"] += len(reply_subs)
             return
         codec = self.reply_codec
-        wire = []
-        for sub, value, is_err in reply_subs:
-            try:
-                payload = (codec.encode_error(value) if is_err
-                           else codec.encode(value))
-            except Exception as e:           # unencodable result: the error
-                payload, is_err = codec.encode_error(e), True   # IS the reply
-            wire.append(F.AggSub(sub.name, sub.kind, F.NO_DIGEST,
-                                 sub.corr_id, payload, err=is_err))
-        if (len(wire) > 1
-                and F.agg_frame_len(wire) <= peer.reply_mailbox.slot_size):
-            if peer.reply_credits <= 0:
-                self._drain_replies(peer)
-            slab = self.engine.slab_slot(peer.reply_channel, peer.reply_tail)
-            n = F.seal_agg_frame(slab, wire, reply=True)
-            self.engine.post(peer.reply_channel, slab[:n], peer.reply_tail,
-                             peer=peer.name)
-            peer.reply_tail += 1
-            peer.stats["replies"] += len(wire)
-            peer.stats["agg_replies"] += 1
-            self.stats["replies"] += len(wire)
-            return
+        if len(reply_subs) > 1 and codec is not None:
+            wire = []
+            for sub, value, is_err in reply_subs:
+                try:
+                    payload = (codec.encode_error(value) if is_err
+                               else codec.encode(value))
+                except Exception as e:       # unencodable result: the error
+                    payload, is_err = codec.encode_error(e), True  # IS it
+                wire.append(F.AggSub(sub.name, sub.kind, F.NO_DIGEST,
+                                     sub.corr_id, payload, err=is_err))
+            if F.agg_frame_len(wire) <= peer.reply_mailbox.slot_size:
+                if peer.reply_credits <= 0:
+                    self._drain_replies(peer)
+                slab = self.engine.slab_slot(peer.reply_channel,
+                                             peer.reply_tail)
+                n = F.seal_agg_frame(slab, wire, reply=True)
+                self.engine.post(peer.reply_channel, slab[:n],
+                                 peer.reply_tail, peer=peer.name)
+                peer.reply_tail += 1
+                peer.stats["replies"] += len(wire)
+                peer.stats["agg_replies"] += 1
+                self.stats["replies"] += len(wire)
+                return
         for sub, value, is_err in reply_subs:
             self._post_reply(peer, sub.name, sub.kind, sub.corr_id, value,
                              is_err)
@@ -1776,8 +1784,15 @@ class Dispatcher:
                     is_err: bool) -> None:
         """Pack a result into a FLAG_REPLY frame and post it target->source.
         The source can always drain its own inbox, so a full reply ring is
-        drained inline rather than dropping the result."""
-        if peer.reply_channel is None or self.reply_codec is None:
+        drained inline rather than dropping the result.  A peer with no
+        reply ring (a device lane: its sweep results are the replies) has
+        the result routed straight to the reply router."""
+        if peer.reply_channel is None:
+            self._route_reply(corr, peer.name, value, is_err, decoded=True)
+            peer.stats["replies"] += 1
+            self.stats["replies"] += 1
+            return
+        if self.reply_codec is None:
             self.stats["reply_dropped"] += 1
             return
         if peer.reply_credits <= 0:
@@ -1899,8 +1914,6 @@ class Dispatcher:
         aggregate, per sub-record.  Replies (result-return frames, device
         sweep results with corr-ids) are routed to the reply_router as a
         side effect; they do not count against ``budget``."""
-        Status = _api().Status
-
         for cb in tuple(self.pollers):
             # side-band pollers (ElasticController heartbeat pump/sweep)
             # run BEFORE the lane snapshot: one may retire a dead peer,
@@ -1938,196 +1951,47 @@ class Dispatcher:
                     continue         # striped peer: consume in the same
                     #                  strict rotation the posts followed,
                     #                  one frame per visit — per-peer FIFO
-                take_eff = 1 if peer.stripe else take
-                track = peer.fabric.kind != "device"
-                slot = lane.mailbox.head
-                if track and peer.reply_channel is not None:
-                    sts = self._sweep_task(
-                        peer, lane,
-                        take_eff if take_eff is not None
-                        else lane.mailbox.n_slots)
-                    coords = res_new = None
-                elif track:
-                    sts = lane.mailbox.sweep(peer.target_ctx,
-                                             peer.target_args,
-                                             budget=take_eff)
-                    coords = res_new = None
-                else:
-                    res_before = len(getattr(lane.mailbox, "results", ()))
-                    sts = lane.mailbox.sweep(peer.target_ctx,
-                                             peer.target_args, budget=1)
-                    coords = getattr(lane.mailbox, "last_coords", None)
-                    res_new = list(getattr(lane.mailbox, "results",
-                                           ())[res_before:])
-                # the device lane's completions are a traced layer; a host
-                # lane's per-message poll stays free of the scope's cost
-                sc = (self.obs.tracer.scope("repro.dispatch.complete")
-                      if sts and not track else None)
-                if sc is not None:
-                    sc.__enter__()
-                done0 = done
-                try:
-                    ri = 0                       # cursor over res_new
-                    for i, st in enumerate(sts):
-                        rec = None
-                        coord = (coords[i] if coords is not None
-                                 and i < len(coords) else None)
-                        if st in (Status.OK, Status.REJECTED,
-                                  Status.NACK_UNCACHED):
-                            if track:
-                                rec = lane.inflight.pop(slot, None)
-                            elif coord is not None:
-                                rec = lane.agg_by_coords.pop(coord, None)
-                            slot += 1
-                        if st == Status.OK:
-                            progressed = True
-                            if not track:
-                                # one results entry lands per device container
-                                # (aggregate or singleton): consume the cursor
-                                # BEFORE branching so later statuses in this
-                                # sweep stay aligned
-                                val = (res_new[ri] if ri < len(res_new)
-                                       else None)
-                                ri += 1
-                            if rec is not None and rec.subs is not None:
-                                # aggregate container: per-sub-record
-                                # completion (cache confirms, individual NACK
-                                # rebuilds, one coalesced reply)
-                                done += self._complete_agg(
-                                    peer, lane, rec,
-                                    coord if not track
-                                    else lane.mailbox.slot_coords(slot - 1))
-                                continue
-                            peer.stats["delivered"] += 1
-                            done += 1
-                            if rec is not None:
-                                peer.cached.add(rec.digest)
-                                if rec.stream is not None:
-                                    # complete: pump off
-                                    rec.stream.dead = True
-                                o = self.obs
-                                if o.enabled:
-                                    o.rtt_hist.observe(
-                                        (time.monotonic() - rec.sent_at) * 1e6)
-                                    if rec.span is not None:
-                                        o.tracer.end(rec.span, status="ok")
-                                        rec.span = None
-                            if not track:
-                                ent = (lane.corr_by_coords.pop(coord, None)
-                                       if coord is not None else None)
-                                if ent:  # device reply: the result IS it
-                                    self._route_reply(ent[0], peer.name, val,
-                                                      False, decoded=True)
-                        elif st == Status.REJECTED:
-                            peer.stats["rejected"] += 1
-                            done += 1
-                            progressed = True
-                            if rec is not None:
-                                o = self.obs
-                                if o.enabled:
-                                    o.recorder.add(
-                                        "reject", peer.name,
-                                        f"{rec.name} corr={rec.corr_id}")
-                                    if rec.span is not None:
-                                        o.tracer.end(rec.span,
-                                                     status="rejected")
-                                        rec.span = None
-                            if rec is not None and rec.stream is not None:
-                                # corrupt stream: ONLY this stream dies —
-                                # stop its pump; the scrubbed slot flows on
-                                rec.stream.dead = True
-                            if rec is not None and rec.subs is not None:
-                                # whole container rejected (corrupt aggregate
-                                # signal): every corr-carrying record resolves
-                                # with the transport error — none executed
-                                for sub in rec.subs:
-                                    if sub.corr_id:
-                                        self._route_reply(
-                                            sub.corr_id, peer.name,
-                                            TransportError(
-                                                "aggregate container "
-                                                "rejected"),
-                                            True, decoded=True)
-                            if not track and coord is not None:
-                                ent = lane.corr_by_coords.pop(coord, None)
-                                corr = ent[0] if ent else 0
-                                if corr:
-                                    self._route_reply(
-                                        corr, peer.name,
-                                        "frame rejected on device sweep",
-                                        True, decoded=True)
-                        elif st == Status.NACK_UNCACHED:
-                            peer.stats["nacks"] += 1
-                            self.stats["nacks"] += 1
-                            progressed = True
-                            if rec is not None:
-                                o = self.obs
-                                if o.enabled:
-                                    o.recorder.add(
-                                        "nack", peer.name,
-                                        f"{rec.name} corr={rec.corr_id} "
-                                        f"slim miss")
-                                    if rec.span is not None:
-                                        o.tracer.end(rec.span, status="nack")
-                                        rec.span = None
-                            if rec is not None and rec.stream is not None:
-                                # SLIM stream missed the cache at its
-                                # descriptor: park the pump and queue a FULL
-                                # re-open from chunk 0 (nothing executed)
-                                rec.stream.dead = True
-                                peer.cached.discard(rec.digest)
-                                peer.resend.append(_StreamResend(rec.stream))
-                            elif rec is not None and rec.handle is not None:
-                                peer.cached.discard(rec.digest)
-                                peer.resend.append(
-                                    self._rebuild_full(lane, slot - 1, rec))
-                            else:
-                                # a SLIM frame we have no record/handle
-                                # for (raw send): nothing to rebuild —
-                                # surface the loss
-                                peer.stats["nack_lost"] += 1
-                        elif st == Status.IN_PROGRESS:
-                            peer.stats["inflight_polls"] += 1
-                finally:
-                    if sc is not None:
-                        sc.set_metadata(n=done - done0)
-                        sc.__exit__(None, None, None)
-                if peer.stripe:
-                    # rotation advances one step per consumed slot, so the
-                    # next visit reads the ring the next post landed in
-                    peer.stripe_rx += sum(
-                        1 for st in sts
-                        if st in (Status.OK, Status.REJECTED,
-                                  Status.NACK_UNCACHED))
-                err = (self._sweep_raise
-                       or getattr(lane.mailbox, "pending_raise", None))
-                if err is not None:
-                    # a corr-less poisoned slot mid-batch (from either the
-                    # reply-lane _sweep_task or a plain Mailbox.sweep):
-                    # its lane's completed statuses (digest confirms,
-                    # aggregate completions, replies) are processed above
-                    # — NOW the exception gets its historical visibility
-                    self._sweep_raise = None
-                    lane.mailbox.pending_raise = None
-                    raise err
+                mb = lane.mailbox
+                swept, raised = mb.sweep(peer.target_ctx, peer.target_args,
+                                         budget=1 if peer.stripe else take)
+                if swept:
+                    with mb.tracer.scope("repro.dispatch.complete") as sc:
+                        n, consumed = self._complete(peer, lane, swept)
+                        sc.set_metadata(n=n)
+                    done += n
+                    if consumed:
+                        progressed = True
+                        if peer.stripe:
+                            # rotation advances one step per consumed slot,
+                            # so the next visit reads the ring the next
+                            # post landed in
+                            peer.stripe_rx += consumed
+                if raised is not None:
+                    # a corr-less ifunc raised at the slot after this
+                    # sweep's completions, which are processed above; now
+                    # the exception surfaces.  A task lane (one with a
+                    # reply ring) never wedges: the slot goes first
+                    if peer.reply_channel is not None:
+                        mb.drop_head()
+                        peer.stats["errors"] += 1
+                        if peer.stripe:
+                            peer.stripe_rx += 1
+                    raise raised
             self._rr += 1
         self.poll_replies()
         self.stats["polled"] += done
         return done
 
     def _pending_inflight(self) -> int:
-        """Tracked frames still awaiting their target's sweep: host-lane
-        inflight records (past-consumed records are pruned as a side
-        effect) plus device-lane corr-ids awaiting a sweep result, plus
-        coalesced records still queued for an aggregate flush."""
+        """Tracked frames still awaiting their target's sweep (records of
+        frames another sweeper consumed are pruned as a side effect), plus
+        queued retransmits and coalesced records still queued for an
+        aggregate flush."""
         n = 0
         for peer in self.peers.values():
             for lane in peer.rings:
-                low = lane.mailbox.consumed
-                for s in [s for s in lane.inflight if s < low]:
-                    del lane.inflight[s]
-                n += (len(lane.inflight) + len(lane.corr_by_coords)
-                      + len(lane.agg_by_coords))
+                lane.prune()
+                n += len(lane.inflight)
             n += len(peer.resend)
             n += sum(len(q.subs) for q in peer.coalesce.values())
         return n
@@ -2152,12 +2016,12 @@ class Dispatcher:
         for peer in targets:
             timed_out = 0
             for lane in peer.rings:
-                low = lane.mailbox.consumed
-                for slot in sorted(lane.inflight):
-                    rec = lane.inflight[slot]
-                    if slot >= low and now - rec.sent_at < min_age:
+                for key, rec in sorted(lane.inflight.items(),
+                                       key=lambda kv: kv[1].slot):
+                    gone = lane.done_elsewhere(rec)
+                    if not gone and now - rec.sent_at < min_age:
                         continue         # young: the peer may still be alive
-                    del lane.inflight[slot]
+                    del lane.inflight[key]
                     o = self.obs
                     if o.enabled and rec.span is not None:
                         o.tracer.end(rec.span, status="failed")
@@ -2167,21 +2031,21 @@ class Dispatcher:
                         #          pump must never touch the slot again
                         if rec.stream in self._active_streams:
                             self._active_streams.remove(rec.stream)
-                    if slot < low:
+                    if gone:
                         continue
                     if o.enabled:
                         o.recorder.add(
                             "fail_inflight", peer.name,
                             f"{rec.name} corr={rec.corr_id} "
                             f"age={now - rec.sent_at:.3f}s")
+                    to = f"{peer.fabric.kind} lane {peer.name!r}: {reason}"
                     if rec.subs is not None:
                         for sub in rec.subs:   # aggregate: fail per record
                             if sub.corr_id:
                                 self._route_reply(
                                     sub.corr_id, peer.name,
                                     TransportError(
-                                        f"{sub.name} (coalesced) to "
-                                        f"{peer.name!r}: {reason}"),
+                                        f"{sub.name} (coalesced) to {to}"),
                                     True, decoded=True)
                                 timed_out += 1
                         continue
@@ -2190,34 +2054,10 @@ class Dispatcher:
                     self._route_reply(
                         rec.corr_id, peer.name,
                         TransportError(
-                            f"{rec.name} to {peer.name!r}: {reason} "
+                            f"{rec.name} to {to} "
                             f"(in flight {now - rec.sent_at:.3f}s)"),
                         True, decoded=True)
                     timed_out += 1
-                for coords, (corr, sent_at) in list(
-                        lane.corr_by_coords.items()):
-                    if now - sent_at < min_age:
-                        continue
-                    del lane.corr_by_coords[coords]
-                    self._route_reply(
-                        corr, peer.name,
-                        TransportError(
-                            f"device lane {peer.name!r}: {reason}"),
-                        True, decoded=True)
-                    timed_out += 1
-                for coords, rec in list(lane.agg_by_coords.items()):
-                    if now - rec.sent_at < min_age:
-                        continue         # device aggregate: fail per record
-                    del lane.agg_by_coords[coords]
-                    for sub in rec.subs or ():
-                        if sub.corr_id:
-                            self._route_reply(
-                                sub.corr_id, peer.name,
-                                TransportError(
-                                    f"{sub.name} (device agg) to "
-                                    f"{peer.name!r}: {reason}"),
-                                True, decoded=True)
-                            timed_out += 1
             if timed_out:
                 while peer.resend:       # retransmits to a dead peer: drop
                     msg = peer.resend.popleft()

@@ -28,6 +28,7 @@ from dataclasses import dataclass
 
 from repro.core import frame as F
 from repro.core import rdma as R
+from repro.obs.trace import Tracer
 
 
 class TransportError(Exception):
@@ -52,6 +53,31 @@ def _api():
 # contracts
 
 
+@dataclass(slots=True)
+class Swept:
+    """What a sweep found at one slot that held a frame: the one record a
+    target hands its dispatcher, on every fabric.
+
+    ``coord`` is the slot's :meth:`Mailbox.slot_coords` coordinate, the
+    key of the dispatcher's in-flight table.  ``corr``, ``name`` and
+    ``kind`` are the frame header's, read before execution (a device
+    frame carries none: its dispatcher knows them).  ``value`` is the
+    ifunc's answer: ``target_args["result"]`` of a corr-carrying host
+    frame, the output of a device frame.  ``subs`` holds an aggregate's
+    per-sub-record ``api.AggSubResult`` outcomes.  ``error`` is what a
+    corr-carrying frame's ifunc raised (status OK: delivered, and the
+    error is its answer) or why a frame was rejected."""
+
+    coord: object
+    status: object                      # repro.core.api.Status
+    corr: int = 0
+    name: str = ""
+    kind: object = None                 # frame.CodeKind
+    value: object = None
+    subs: list | None = None
+    error: BaseException | None = None
+
+
 class Mailbox:
     """A target-owned ring of ``n_slots`` frame slots of ``slot_size`` bytes.
 
@@ -59,41 +85,29 @@ class Mailbox:
     lives with the source-side Channel.  ``consumed`` is the monotone count
     of drained slots — the source reads it to compute returned credits (the
     emulation analogue of a credit-return counter the target writes back).
+    :meth:`sweep` returns one :class:`Swept` per frame it reached,
+    keyed by :meth:`slot_coords`: the whole hand-off from target to
+    dispatcher.
     """
 
     fabric: "Fabric"
     n_slots: int
     slot_size: int
+    #: what the dispatcher scopes this lane's completions with: a tracer
+    #: never enabled, so a host lane keeps no span in memory (a profiler
+    #: session still records the scope); a lane that traces its own
+    #: layers (the device lane) returns its dispatcher's
+    tracer = Tracer()
+    #: slots are consumed in produce order (a ring): a frame below
+    #: ``consumed`` is done, whoever swept it
+    in_order = True
 
     def __init__(self):
         self.head = 0
         self.consumed = 0
-        #: coordinates of each status the most recent :meth:`sweep`
-        #: returned, in order.  Host mailboxes consume in ring order, so
-        #: the produce index is the coordinate; backends that sweep out of
-        #: order (the device mesh) override :meth:`slot_coords` and fill
-        #: this with their native coordinates — the reply demux correlates
-        #: sweep results to task corr-ids through it.
-        self.last_coords: list = []
-        #: per-sub-record outcomes of consumed FLAG_AGG frames, keyed by
-        #: the slot coordinate the container occupied (host coordinates
-        #: are the monotone absolute produce index, so keys never repeat
-        #: on ring wrap): coordinate -> list[api.AggSubResult].  Filled by
-        #: :meth:`sweep` (from ``ctx.last_agg_results``), popped by the
-        #: dispatcher's aggregate completion; bounded so a sweep-only
-        #: caller that ignores aggregates cannot leak entries.
-        self.last_agg: dict = {}
-        #: an ifunc exception raised by a slot *behind* frames this sweep
-        #: already consumed: the batch stops, the consumed frames' statuses
-        #: are returned (their completions must not be lost), and the
-        #: caller (Dispatcher.poll) re-raises this after processing them.
-        #: The poisoned slot itself is NOT consumed — exactly the
-        #: historical budget=1 behavior, where the raise surfaced on the
-        #: poll that reached the slot.
-        self.pending_raise: BaseException | None = None
         #: in-progress FLAG_STREAM receive state, keyed by the slot
         #: coordinate the stream frame occupies: coordinate ->
-        #: ``api._StreamRx``.  Owned by ``poll_ifunc`` (created on the
+        #: ``api._StreamRx``.  Owned by the poll core (created on the
         #: descriptor's arrival, popped at completion/rejection); the
         #: mailbox carries it because stream lifetime spans many sweeps
         #: of one slot.  ``stream_consumed`` exposes the consume counter
@@ -101,8 +115,9 @@ class Mailbox:
         self.streams: dict = {}
 
     def slot_coords(self, i: int):
-        """Stable coordinate a produce-index maps to (what ``last_coords``
-        entries are keyed by).  Identity for in-order host rings."""
+        """Stable coordinate a produce-index maps to (what a
+        :class:`Swept` is keyed by).  Identity for in-order host
+        rings."""
         return i
 
     def stream_consumed(self, coords) -> int:
@@ -129,55 +144,84 @@ class Mailbox:
         except (F.FrameError, TransportError, NotImplementedError):
             return None
 
-    def sweep(self, ctx, target_args, budget: int | None = None) -> list:
-        """Drain up to ``budget`` slots through ``poll_ifunc``; returns the
-        list of per-slot Status values observed.  OK/REJECTED/NACK_UNCACHED
-        all consume the slot and advance head (a NACKed SLIM frame is
-        cleared — the retransmit arrives as a fresh FULL frame).
+    def drop_head(self) -> None:
+        """Consume the slot at ``head`` whatever it holds (an ifunc raised
+        there): scrub it, and end the stream that held it, if any."""
+        F.scrub_slot(self.slot_view(self.head))
+        self.streams.pop(self.slot_coords(self.head), None)
+        self.head += 1
+        self.consumed += 1
+
+    def sweep(self, ctx, target_args, budget: int | None = None):
+        """Drain up to ``budget`` slots through the poll core.  Returns
+        ``(completions, raised)``: one :class:`Swept` per slot that
+        held a frame, in consume order, and the exception an ifunc raised
+        at the slot after the last of them, or None.
+
+        OK/REJECTED/NACK_UNCACHED consume the slot and advance head (a
+        NACKed SLIM frame is cleared — the retransmit arrives as a fresh
+        FULL frame); IN_PROGRESS and an empty slot end the batch.  An ifunc
+        that raises on a corr-carrying frame has an answer to carry it: the
+        slot is consumed and its completion (status OK) holds the error.
+        One that raises on a corr-less frame ends the batch with the slot
+        left as it is; the caller raises ``raised`` once it has processed
+        the completions before it.
 
         Note: the *recovery* half of the NACK protocol (rebuilding and
         requeueing the FULL frame) lives in ``Dispatcher.poll``; a caller
         sweeping a mailbox directly must either send FULL frames only (the
         default until a dispatcher confirms the peer) or handle
-        NACK_UNCACHED in the returned statuses itself."""
+        NACK_UNCACHED in the returned completions itself."""
         A = _api()
+        Status = A.Status
 
-        out = []
+        done: list[Swept] = []
+        raised = None
+        is_dict = isinstance(target_args, dict)
         budget = self.n_slots if budget is None else budget
         obs = getattr(ctx, "obs", None)
         t0 = (time.perf_counter() if obs is not None and obs.enabled
               else None)
         consumed0 = self.consumed
         for _ in range(budget):
+            coord = self.slot_coords(self.head)
+            hdr = self.peek()                # what execution may destroy
+            c = Swept(coord, Status.OK)
+            if hdr is not None:
+                c.corr, c.name, c.kind = hdr.corr_id, hdr.name, hdr.code_kind
+            if c.corr and is_dict:
+                target_args.pop("result", None)
             try:
-                st = A.poll_ifunc(ctx, self.slot_view(self.head), None,
-                                  target_args, streams=self.streams,
-                                  stream_key=self.slot_coords(self.head))
-            except Exception as e:       # raised *inside* an ifunc
-                if not out:
-                    raise                # first slot: historical behavior
-                self.pending_raise = e   # mid-batch: don't discard the
-                break                    # consumed frames' statuses
-            out.append(st)
-            agg = getattr(ctx, "last_agg_results", None)
-            if agg is not None:
-                # a FLAG_AGG container was consumed at this slot: stash its
-                # per-sub-record outcomes under the slot's coordinate for
-                # the dispatcher's aggregate completion pass
-                self.last_agg[self.slot_coords(self.head)] = agg
-                ctx.last_agg_results = None
-                while len(self.last_agg) > 2 * self.n_slots:
-                    self.last_agg.pop(next(iter(self.last_agg)))
-            if st in (A.Status.OK, A.Status.REJECTED, A.Status.NACK_UNCACHED):
-                self.head += 1
-                self.consumed += 1
-            else:
+                c.status, c.subs = A._poll(
+                    ctx, self.slot_view(self.head), target_args,
+                    self.streams, coord)
+            except Exception as e:           # raised *inside* an ifunc
+                if not c.corr:
+                    raised = e               # no future to carry it
+                    break
+                self.drop_head()             # delivered: the error answers
+                c.error = e
+                done.append(c)
+                continue
+            st = c.status
+            if st is Status.NO_MESSAGE:
                 break
+            if c.corr:
+                if st is Status.OK:
+                    c.value = target_args.get("result") if is_dict else None
+                elif st is Status.REJECTED:
+                    c.error = TransportError(
+                        ctx.stats.get("last_reject", "frame rejected"))
+            done.append(c)
+            if st is Status.IN_PROGRESS:
+                break
+            self.head += 1
+            self.consumed += 1
         if t0 is not None and self.consumed != consumed0:
             # only sweeps that consumed something observe: idle polls would
             # otherwise flood the distribution with empty-peek latencies
             obs.sweep_hist.observe((time.perf_counter() - t0) * 1e6)
-        return out
+        return done, raised
 
 
 class Channel:
@@ -508,5 +552,5 @@ __all__ = [
     "Channel", "Fabric", "LegacyRingMailbox", "Mailbox", "TransportError",
     "LoopbackChannel", "LoopbackFabric", "LoopbackMailbox",
     "RdmaChannel", "RdmaFabric", "RdmaMailbox",
-    "endpoint_channel", "frame_fits", "ring_mailbox",
+    "Swept", "endpoint_channel", "frame_fits", "ring_mailbox",
 ]

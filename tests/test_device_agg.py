@@ -44,6 +44,7 @@ def _mk_device(lib_dir, *, agg_k=K, n_slots=2, prog_name="bind"):
     d.set_coalescing(True, max_subs=agg_k, max_sub_bytes=128 << 10)
     d.add_peer("tpu", DeviceMeshFabric(mesh, "model", shift=0), None,
                n_slots=n_slots, slot_size=8 << 20,
+               target_args={"results": []},
                prog=deserialize_uvm(h.lib.code),
                externals=jnp.broadcast_to(jnp.asarray(W)[None, None],
                                           (n_dev, 1, T, T)),

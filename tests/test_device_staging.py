@@ -122,17 +122,14 @@ class Lane:
     def sweep(self) -> dict:
         """Sweep the ring; {staged coord: [values]} of every frame that
         came back READY, each coord at most once."""
-        mb = self.mb
-        n0 = len(mb.results)
-        statuses = mb.sweep(None, {})
-        new = iter(mb.results[n0:])
+        done, raised = self.mb.sweep(None, {})
+        assert raised is None
         got: dict = {}
-        for st, coord in zip(statuses, mb.last_coords):
-            assert st == Status.OK, (st, coord)
-            assert coord not in got, f"{coord} answered twice in one sweep"
-            entry = next(new)
-            got[coord] = ([s.value for s in mb.last_agg[coord]]
-                          if self.kind == "agg" else [entry])
+        for c in done:
+            assert c.status == Status.OK, (c.status, c.coord)
+            assert c.coord not in got, f"{c.coord} answered twice in one sweep"
+            got[c.coord] = ([s.value for s in c.subs]
+                            if self.kind == "agg" else [c.value])
         return got
 
     def check(self, got: dict) -> None:
@@ -204,10 +201,10 @@ def short_container(lane: Lane) -> None:
     np.testing.assert_array_equal(lane.read_words(row, n),
                                   lane.read_words(want, n))
     lane.ch.flush()
-    args: dict = {}
-    statuses = mb.sweep(None, args)
-    assert statuses == [Status.OK]
-    assert len(mb.last_agg[mb.slot_coords(0)]) == n
+    args: dict = {"results": []}
+    done, _ = mb.sweep(None, args)
+    assert [c.status for c in done] == [Status.OK]
+    assert done[0].coord == mb.slot_coords(0) and len(done[0].subs) == n
     assert len(args["results"]) == n
     for v, x in zip(args["results"], xs):
         np.testing.assert_allclose(np.asarray(v), np.maximum(0.5 * x, 0),

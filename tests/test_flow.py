@@ -330,7 +330,7 @@ def test_drain_deadline_fails_wedged_futures(lib_dir):
     h = register_ifunc(rt.ctx, "task_sum")
     peer = rt.dispatcher.peers["p"]
     lane = peer.rings[0]
-    lane.mailbox.sweep = lambda *a, **k: []       # the peer wedges
+    lane.mailbox.sweep = lambda *a, **k: ([], None)       # the peer wedges
     futs = [rt.submit("p", h, b"\x01"), rt.submit("p", h, b"\x02")]
     t0 = time.monotonic()
     rt.drain(deadline=0.15)
@@ -349,7 +349,7 @@ def test_oldest_inflight_age_surfaces_in_stats(lib_dir):
     h = register_ifunc(rt.ctx, "task_sum")
     assert rt.dispatcher.per_peer_stats()["p"]["oldest_inflight_s"] == 0.0
     peer = rt.dispatcher.peers["p"]
-    peer.rings[0].mailbox.sweep = lambda *a, **k: []
+    peer.rings[0].mailbox.sweep = lambda *a, **k: ([], None)
     fut = rt.submit("p", h, b"\x01")
     time.sleep(0.02)
     rt.progress()
@@ -435,9 +435,9 @@ def test_device_lane_pending_corr_fails_on_deadline(device_rt):
     future resolves with a TransportError instead of hanging."""
     rt, h, T = device_rt
     lane = rt.dispatcher.peers["tpu"].rings[0]
-    lane.mailbox.sweep = lambda *a, **k: []      # the mesh wedges
+    lane.mailbox.sweep = lambda *a, **k: ([], None)      # the mesh wedges
     fut = rt.submit("tpu", h, np.ones((1, T, T), np.float32))
     rt.drain(deadline=0.1)
     with pytest.raises(TransportError, match="device lane"):
         fut.result()
-    assert not lane.corr_by_coords
+    assert not lane.inflight

@@ -207,7 +207,7 @@ def test_fail_inflight_dumps_recorder(lib_dir, capsys):
     obs = Obs("t")                                         # counters-only
     d, _ = _mk(lib_dir, obs)
     for r in d.peers["p"].rings:                           # peer stops consuming
-        r.mailbox.sweep = lambda *a, **k: []
+        r.mailbox.sweep = lambda *a, **k: ([], None)
     h = register_ifunc(d.src_ctx, "rle_insert")
     errs = []
     d.reply_router = lambda corr, name, value, is_err, decoded: \
@@ -227,7 +227,7 @@ def test_fail_inflight_dump_can_be_disabled(lib_dir, capsys):
     obs = Obs("t", dump_on_fail=False)
     d, _ = _mk(lib_dir, obs)
     for r in d.peers["p"].rings:
-        r.mailbox.sweep = lambda *a, **k: []
+        r.mailbox.sweep = lambda *a, **k: ([], None)
     h = register_ifunc(d.src_ctx, "rle_insert")
     d.reply_router = lambda *a: None
     assert d.send_ifunc("p", h, b"doomed", corr_id=7)

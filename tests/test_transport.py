@@ -217,6 +217,7 @@ def test_device_fabric_through_dispatcher(lib_dir):
     d = Dispatcher(src, ProgressEngine(inflight_window="trailer"))
     d.add_peer("tpu", DeviceMeshFabric(mesh, "model", shift=0), None,
                n_slots=2, slot_size=128 << 10,
+               target_args={"results": []},
                prog=deserialize_uvm(h.lib.code),
                externals=jnp.broadcast_to(jnp.asarray(W)[None, None],
                                           (n_dev, 1, T, T)))
@@ -248,6 +249,7 @@ def test_device_fabric_multiple_generations_no_loss(lib_dir):
     d = Dispatcher(src, ProgressEngine(inflight_window="trailer"))
     d.add_peer("tpu", DeviceMeshFabric(mesh, "model", shift=0), None,
                n_slots=4, slot_size=128 << 10,
+               target_args={"results": []},
                prog=deserialize_uvm(h.lib.code),
                externals=jnp.broadcast_to(jnp.asarray(W)[None, None],
                                           (n_dev, 1, T, T)))
@@ -264,6 +266,85 @@ def test_device_fabric_multiple_generations_no_loss(lib_dir):
     want = sorted(float(np.maximum(x, 0).sum()) for x in xs)
     np.testing.assert_allclose(got, want, rtol=1e-4)
     assert d.peers["tpu"].credits == 4 * d.peers["tpu"].rings[0].mailbox.n_shards
+
+
+def _task_lane(lib_dir, kind: str, *, agg_k: int = 4):
+    """A TaskRuntime with coalescing on and one peer "p": a loopback host
+    lane running task_sum, or a device lane (agg-bound with ``agg_k``)
+    running uvm_affine; returns (runtime, handle, payload maker)."""
+    from repro.tasks import TaskRuntime
+
+    rt = TaskRuntime(Context("src", lib_dir=lib_dir),
+                     engine=ProgressEngine(inflight_window="trailer"),
+                     default_timeout=60.0)
+    if kind == "loopback":
+        h = register_ifunc(rt.ctx, "task_sum")
+        rt.dispatcher.set_coalescing(True, max_subs=4)
+        rt.add_peer("p", LoopbackFabric(),
+                    Context("p", lib_dir=lib_dir, link_mode="remote"),
+                    n_slots=4, slot_size=16 << 10)
+        return rt, h, lambda i: bytes([i + 1]) * 3
+    jax = pytest.importorskip("jax")
+
+    from repro.core.codegen import deserialize_uvm
+    from repro.parallel.sharding import make_mesh
+    from repro.transport.device_fabric import DeviceMeshFabric
+
+    T = 128
+    mesh = make_mesh((1,), ("model",), devices=jax.devices()[:1])
+    h = register_ifunc(rt.ctx, "uvm_affine")
+    kw = {}
+    if agg_k:
+        kw = dict(agg_k=agg_k, prog_name=h.lib.name)
+        rt.dispatcher.set_coalescing(True, max_subs=agg_k,
+                                     max_sub_bytes=T * T * 4)
+    rt.add_peer("p", DeviceMeshFabric(mesh, "model"), None, n_slots=4,
+                slot_size=8 << 20, prog=deserialize_uvm(h.lib.code),
+                externals=np.eye(T, dtype=np.float32)[None, None], **kw)
+    return rt, h, lambda i: np.full((1, T, T), i + 1, np.float32)
+
+
+@pytest.mark.parametrize("kind", ["loopback", "device"])
+def test_one_inflight_table_drains_and_fails(kind, lib_dir):
+    """Host and device lanes track every post in the lane's one in-flight
+    table: a drain empties it, and fail_inflight resolves both a pending
+    singleton's future and an aggregate's futures from it."""
+    rt, h, make = _task_lane(lib_dir, kind)
+    lane = rt.dispatcher.peers["p"].rings[0]
+    futs = [rt.submit("p", h, make(0))]          # first delivery: FULL
+    rt.drain()
+    futs += rt.submit_many("p", h, [make(i) for i in range(3)])
+    rt.drain()
+    assert all(f.done() and f.exception() is None for f in futs)
+    assert not lane.inflight
+    lane.mailbox.sweep = lambda *a, **k: ([], None)      # the lane wedges
+    single = rt.submit("p", h, make(5))
+    rt.flush()                                   # a lone record: singleton
+    agg = rt.submit_many("p", h, [make(i) for i in range(3)])
+    assert len(lane.inflight) == 2
+    assert sorted(len(r.subs or ()) for r in lane.inflight.values()) == [0, 3]
+    assert rt.dispatcher.fail_inflight("wedged lane") == 4
+    for f in [single, *agg]:
+        with pytest.raises(TransportError, match="wedged lane"):
+            f.result(0)
+    assert not lane.inflight
+
+
+def test_device_answer_not_retained_by_the_program(lib_dir):
+    """An answer taken from its future is the caller's alone: once the
+    caller drops it and the future, nothing in the dispatcher, the device
+    lane or its target_args keeps it alive."""
+    import gc
+    import weakref
+
+    rt, h, make = _task_lane(lib_dir, "device", agg_k=0)
+    fut = rt.submit("p", h, make(1))
+    answer = fut.result()
+    np.testing.assert_allclose(np.asarray(answer), 2.0)    # relu(x @ I)
+    ref = weakref.ref(answer)
+    del answer, fut
+    gc.collect()
+    assert ref() is None
 
 
 def test_controller_inject_flushes_despite_refusal(lib_dir):
