@@ -9,7 +9,10 @@ into its row of the staged generation, one of two host buffers the lane
 keeps and fills in turn; flush deposits the staged generation over the
 ICI via ``ppermute`` (the RDMA-put analogue); the sweep validates all
 slots in one compiled ``ring_poll`` + ``ifunc_vm`` pass with the μVM
-program bound at mailbox-open time (the device-side link cache).
+program bound at mailbox-open time (the device-side link cache).  Its
+readback brings the statuses to the host, then only the outputs of the
+READY slots: gathered on each shard's device where they are few, the
+whole ring's outputs where they fill half of it.
 
 Visibility is generation-batched: frames become consumable only after the
 depositing flush, which is exactly the in-flight window the ProgressEngine
@@ -22,7 +25,9 @@ Kept in its own module so ``repro.transport`` imports without jax.
 
 from __future__ import annotations
 
+import functools
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -96,6 +101,22 @@ class DeviceMeshMailbox(Mailbox):
                                          tile, bound_hash=self.bound_hash)
         else:
             self._sweep = make_sweep(mesh, axis, prog, n_tiles, tile)
+        # a sparse sweep's answers are gathered on the device through one
+        # of these, by the size ``_sparse`` picks; all are compiled here,
+        # for the lane's shapes, so none compiles in the steady state.  XLA
+        # compiles with the GIL released, so they compile side by side
+        self._rows = n_slots * max(agg_k, 1)          # output rows per shard
+        out = jax.ShapeDtypeStruct(
+            (self.n_shards, n_slots, *((agg_k,) if agg_k else ()), n_tiles,
+             tile, tile), np.float32, sharding=self._sharding)
+        gather = _row_gather()
+        sizes = _gather_sizes(self._rows)
+        lowered = [gather.lower(out, jax.ShapeDtypeStruct(
+            (self.n_shards, size), np.int32, sharding=self._sharding),
+            mesh=mesh, axis=axis) for size in sizes]
+        with ThreadPoolExecutor(min(len(sizes), 8) or 1) as pool:
+            self._gathers = dict(zip(sizes, pool.map(
+                lambda lo: lo.compile(), lowered)))
         # the generation being filled: one of two resident host buffers
         # [n_shards, n_slots_per_shard, slot_words], allocated on first use
         # and then taken in turn, flush by flush (``_open_generation``);
@@ -209,17 +230,46 @@ class DeviceMeshMailbox(Mailbox):
             o.sweep_hist.observe((time.perf_counter() - t0) * 1e6)
         return done, None
 
-    def _run_sweep(self) -> list:
-        """The jitted sweep over the ring, its outputs read back to the
-        host (blocking); the cleared ring stays on the device."""
+    def _run_sweep(self) -> tuple:
+        """The jitted sweep over the ring, then its readback (blocking):
+        the statuses (``status``, and ``sub_st`` on an aggregate lane),
+        then the outputs of the READY rows, whole or gathered on each
+        shard's device as ``_sparse`` decides.  A row is a slot, or on an
+        aggregate lane a slot's sub-record (``slot * agg_k + sub``).
+        Returns the statuses and ``answer(shard, row)``: a READY row's
+        output, a view of what was read back.  The cleared ring stays on
+        the device."""
+        from repro.kernels.agg_poll import SUB_READY
+        from repro.kernels.ring_poll import READY
+
         with self.tracer.scope("repro.device.readback") as sc:
-            *outs, self._mb = self._sweep(self._mb, self.externals)
-            host = [np.asarray(a) for a in outs]
-            sc.set_metadata(bytes=sum(h.nbytes for h in host))
-        # the outputs are on the host, so every deposit before this sweep
-        # is done with its staging buffer: drop the rings that fenced them
+            *sts, out, self._mb = self._sweep(self._mb, self.externals)
+            sts = [np.asarray(a) for a in sts]
+            ready = (sts[-1] == (SUB_READY if self.agg_k else READY)
+                     ).reshape(self.n_shards, self._rows)
+            size = _sparse(int(ready.sum(axis=1).max()), self._rows)
+            tail = out.shape[-3:]
+            if size is None:
+                block = np.asarray(out).reshape(*ready.shape, *tail)
+                at = np.broadcast_to(np.arange(self._rows), ready.shape)
+            else:
+                # each shard's READY rows first, padded with row 0; a READY
+                # row's answer lies at its rank among its shard's
+                idx = np.zeros((self.n_shards, size), np.int32)
+                for shard, r in enumerate(ready):
+                    rows = np.flatnonzero(r)
+                    idx[shard, :rows.size] = rows
+                at = np.cumsum(ready, axis=1) - 1
+                block = (np.asarray(self._gathers[size](out, idx)) if size
+                         else np.empty((self.n_shards, 0, *tail), np.float32))
+            sc.set_metadata(bytes=sum(a.nbytes for a in sts) + block.nbytes,
+                            rows=block.shape[0] * block.shape[1],
+                            compact=int(size is not None))
+        # the statuses are on the host, so the sweep and every deposit
+        # before it are done with their staging buffers: drop the rings
+        # that fenced them
         self._fences = [None, None]
-        return host
+        return (*sts, lambda shard, row: block[shard, at[shard, row]])
 
     def _demux(self, status, ready) -> list:
         """One :class:`Swept` per slot the sweep found a frame in, with
@@ -254,13 +304,11 @@ class DeviceMeshMailbox(Mailbox):
 
     def _sweep_one(self, target_args) -> list:
         from repro.core.api import collect_result
-        from repro.kernels.ring_poll import READY
 
-        status, out = self._run_sweep()
-        copy = _sparse(int((status == READY).sum()), status.size)
+        status, answer = self._run_sweep()
 
         def ready(c, shard, slot):
-            c.value = out[shard, slot].copy() if copy else out[shard, slot]
+            c.value = answer(shard, slot)
             collect_result(target_args, c.value)
         return self._demux(status, ready)
 
@@ -274,8 +322,7 @@ class DeviceMeshMailbox(Mailbox):
         from repro.core.api import AggSubResult, Status, collect_result
         from repro.kernels.agg_poll import SUB_BAD, SUB_EMPTY, SUB_READY
 
-        status, sub_st, out = self._run_sweep()
-        copy = _sparse(int((sub_st == SUB_READY).sum()), sub_st.size)
+        status, sub_st, answer = self._run_sweep()
 
         def ready(c, shard, slot):
             c.subs = []
@@ -284,8 +331,7 @@ class DeviceMeshMailbox(Mailbox):
                 if s_i == SUB_EMPTY:
                     break
                 if s_i == SUB_READY:
-                    val = out[shard, slot, i]
-                    val = val.copy() if copy else val
+                    val = answer(shard, slot * self.agg_k + i)
                     c.subs.append(AggSubResult(Status.OK, "", b"", 0,
                                                value=val))
                     collect_result(target_args, val)
@@ -303,13 +349,54 @@ class DeviceMeshMailbox(Mailbox):
         return self._demux(status, ready)
 
 
-def _sparse(n_answers: int, capacity: int) -> bool:
-    """Whether a sweep's answers leave its readback as copies: where they
-    fill less than half of it.  A caller may keep an answer as long as it
-    likes (a future's result), and a view keeps the whole readback alive:
-    for a lane that finds one frame a sweep, a thousand times the answer's
-    own bytes.  Where they fill it, views cost nothing more."""
-    return 2 * n_answers < capacity
+def _gather_sizes(rows: int) -> list[int]:
+    """The sizes a sparse sweep's gather pads to, on a shard of ``rows``
+    output rows: 1, 2, 3, 4, 6, 8, 12, 16, 24, ... below half of ``rows``,
+    and the most answers below half.  None is more than 1.5 times the
+    largest count that pads to the size below it, so a padded block is
+    more than two thirds answers."""
+    top = (rows - 1) // 2
+    return sorted({s for k in range(top.bit_length())
+                   for s in (1 << k, (3 << k) >> 1) if s < top}
+                  | ({top} if top else set()))
+
+
+def _sparse(n_answers: int, rows: int) -> int | None:
+    """How a sweep whose fullest shard holds ``n_answers`` READY rows of
+    its ``rows`` is read back: None, whole, where they fill half of it or
+    more; else the size of the gather, per shard, that holds them (0:
+    nothing to read).  A caller may keep an answer as long as it likes (a
+    future's result), and an answer is a view of its readback: a gathered
+    block holds little else, and a whole one is half answers or more
+    (on one shard; on a mesh, half on the fullest shard)."""
+    if 2 * n_answers >= rows:
+        return None
+    if n_answers == 0:
+        return 0
+    return min(s for s in _gather_sizes(rows) if s >= n_answers)
+
+
+@functools.cache
+def _row_gather():
+    """``gather(out, idx, mesh=, axis=)``: on every shard, the output rows
+    ``idx[shard]`` of its own ``out[shard]`` (the sweep's outputs, rows
+    flattened), so no output crosses chips.  One jitted function per
+    process: lanes of one shape share its compiles."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.sharding import shard_map
+
+    @functools.partial(jax.jit, static_argnames=("mesh", "axis"))
+    def gather(out, idx, *, mesh, axis):
+        def f(o, i):
+            rows = o[0].reshape(-1, *o.shape[-3:])
+            return jnp.take(rows, i[0], axis=0, mode="clip")[None]
+        return shard_map(f, mesh, in_specs=(P(axis), P(axis)),
+                         out_specs=P(axis))(out, idx)
+
+    return gather
 
 
 def _agg_bodies(batch: F.AggBatch, body_words: int):

@@ -21,6 +21,17 @@ contracts under test:
 
 Each runs on a singleton and an aggregate lane, on one chip (this process)
 and on a 2-shard mesh with ``shift=1`` (one subprocess for all of those).
+
+The readback of a sweep, on a singleton and an aggregate lane, on one chip
+and on a 4-shard mesh with ``shift=1``:
+
+* where the fullest shard's answers fill less than half of its output
+  rows, only they are read back, gathered on the device and padded to
+  1, 2, 3, 4, 6, ... rows a shard; each answer is bit-identical to the
+  whole readback's, and the scope's ``rows`` and ``bytes`` say so;
+* uneven counts across shards map every answer to its staged coordinate;
+* BAD slots and NACK sub-records are reported as before and add no row;
+* no sweep, of any fill, compiles anything once the lane has swept once.
 """
 
 import json
@@ -41,7 +52,9 @@ from repro.core.api import Status, ifunc_msg_create  # noqa: E402
 from repro.core.codegen import deserialize_uvm  # noqa: E402
 from repro.core.device_mailbox import (pack_agg_word_frame,  # noqa: E402
                                        pack_word_frame)
+from repro.kernels.agg_poll import SUB_SALT  # noqa: E402
 from repro.kernels.ring_poll import HDR_WORDS, INFLIGHT, TRAILER  # noqa: E402
+from repro.obs import Obs  # noqa: E402
 from repro.parallel.sharding import make_mesh  # noqa: E402
 from repro.transport.device_fabric import DeviceMeshFabric  # noqa: E402
 
@@ -57,7 +70,7 @@ class Lane:
     """One device lane (mailbox + channel) running uvm_affine,
     relu(x @ W) with W = 0.5 I, on ``shards`` devices."""
 
-    def __init__(self, kind: str, shards: int):
+    def __init__(self, kind: str, shards: int, slots: int = N_SLOTS):
         self.kind = kind
         mesh = make_mesh((shards,), ("model",), devices=jax.devices()[:shards])
         self.h = register_ifunc(Context("src"), "uvm_affine")
@@ -67,7 +80,7 @@ class Lane:
         fab = DeviceMeshFabric(mesh, "model", shift=1 if shards > 1 else 0)
         w = np.eye(T, dtype=np.float32) * 0.5
         self.mb = fab.open_mailbox(
-            None, N_SLOTS, 8 << 20, prog=deserialize_uvm(lib.code),
+            None, slots, 8 << 20, prog=deserialize_uvm(lib.code),
             externals=np.broadcast_to(w, (shards, 1, T, T)), agg_k=agg_k,
             prog_name=lib.name if agg_k else None)
         self.ch = fab.connect(None, self.mb)
@@ -124,6 +137,10 @@ class Lane:
         came back READY, each coord at most once."""
         done, raised = self.mb.sweep(None, {})
         assert raised is None
+        return self.answered(done)
+
+    def answered(self, done) -> dict:
+        """{staged coord: [values]} of a sweep's completions, all READY."""
         got: dict = {}
         for c in done:
             assert c.status == Status.OK, (c.status, c.coord)
@@ -288,7 +305,192 @@ SCENARIOS = {f.__name__: f for f in (exactly_once, stale_trailer,
                                       counter, sparse_answers)}
 
 
+# -- readback ------------------------------------------------------------
+
+ROWS = 16                       # output rows a shard on the readback lanes
+TILE_BYTES = T * T * 4
+
+
+class ReadbackLane(Lane):
+    """A lane of ``ROWS`` output rows a shard (16 singleton slots, or 4
+    containers of ``K``), traced, so a sweep's readback scope is kept."""
+
+    def __init__(self, kind: str, shards: int):
+        super().__init__(kind, shards, ROWS // (K if kind == "agg" else 1))
+        self.mb.obs = Obs("t", trace=True)
+        self.n = shards
+
+    def fill(self, frames: dict) -> None:
+        """Put ``{(staged shard, slot): n sub-records}`` (1 on the
+        singleton lane) and flush."""
+        for (shard, idx), n in frames.items():
+            self.put(idx * self.n + shard, None if self.kind == "singleton"
+                     else n)
+        self.ch.flush()
+
+    def rows_each(self, k: int) -> dict:
+        """Frames that fill the first ``k`` rows of every shard."""
+        per = 1 if self.kind == "singleton" else K
+        return {(s, j): min(per, k - j * per) for s in range(self.n)
+                for j in range(-(-k // per))}
+
+    def sweep_read(self):
+        """The outputs of the deposited frames read back whole (a sweep
+        of the ring that leaves it as it is), then the lane's own sweep:
+        its completions and its readback scope's args."""
+        mb = self.mb
+        whole = np.asarray(mb._sweep(mb._mb, mb.externals)[-2])
+        done, raised = mb.sweep(None, {})
+        assert raised is None
+        spans = [sp for sp in mb.obs.tracer.spans(cat="scope")
+                 if sp.name == "repro.device.readback"]
+        return done, whole, spans[-1].args
+
+    def found(self, coord) -> tuple[int, int]:
+        """Where a frame staged at ``coord`` is swept (``shift`` on)."""
+        return (coord[0] + self.mb.shift) % self.n, coord[1]
+
+    def answers(self, done) -> dict:
+        """{(found shard, row): answer} of every READY row."""
+        out = {}
+        for c in done:
+            shard, slot = self.found(c.coord)
+            if self.kind == "singleton":
+                if c.value is not None:
+                    out[shard, slot] = c.value
+                continue
+            for i, sub in enumerate(c.subs or []):
+                if sub.value is not None:
+                    out[shard, slot * K + i] = sub.value
+        return out
+
+    def expect(self, args: dict, size: int | None) -> None:
+        """The readback scope of a sweep read back whole (``size`` None)
+        or through a gather of ``size`` rows a shard."""
+        slots = self.n * self.mb.n_slots_per_shard
+        status_bytes = 4 * slots * (1 + (K if self.kind == "agg" else 0))
+        rows = self.n * (ROWS if size is None else size)
+        assert args == {"bytes": status_bytes + rows * TILE_BYTES,
+                        "rows": rows, "compact": int(size is not None)}, \
+            (args, size)
+
+    def same_as_whole(self, done, whole) -> None:
+        got = self.answers(done)
+        rows = whole.reshape(self.n, ROWS, T, T)
+        for (shard, row), v in got.items():
+            np.testing.assert_array_equal(np.asarray(v).reshape(T, T),
+                                          rows[shard, row])
+
+
+def readback_edges(lane: ReadbackLane) -> None:
+    """Sweeps of 1, 3, 5, 7, 8 and 16 answers on every shard (of 16
+    rows): gathers of 1, 3, 6 and 7 rows (7, the most below half), then
+    whole readbacks at half and full."""
+    for k, size in ((1, 1), (3, 3), (5, 6), (7, 7), (8, None),
+                    (16, None)):
+        lane.fill(lane.rows_each(k))
+        done, whole, args = lane.sweep_read()
+        lane.expect(args, size)
+        assert len(lane.answers(done)) == k * lane.n
+        lane.same_as_whole(done, whole)
+        lane.check(lane.answered(done))
+
+
+def uneven_shards(lane: ReadbackLane) -> None:
+    """5, 1, 0 and 3 answers on the shards (the first alone on one
+    chip), at scattered rows: one gather of 6 rows a shard, and every
+    answer at its staged coordinate."""
+    if lane.kind == "singleton":
+        frames = {(0, 1): 1, (0, 4): 1, (0, 6): 1, (0, 9): 1, (0, 15): 1,
+                  (1, 7): 1, (3, 0): 1, (3, 8): 1, (3, 14): 1}
+    else:
+        frames = {(0, 1): 3, (0, 3): 2, (1, 2): 1, (3, 0): 2, (3, 3): 1}
+    lane.fill({c: n for c, n in frames.items() if c[0] < lane.n})
+    done, whole, args = lane.sweep_read()
+    lane.expect(args, 6)
+    lane.same_as_whole(done, whole)
+    lane.check(lane.answered(done))
+
+
+def bad_and_nack(lane: ReadbackLane) -> None:
+    """Three answers beside a BAD slot (a corrupt header check word)
+    and, on the aggregate lane, a NACK sub-record (a name hash that is
+    not the bound one): a gather of 3 rows a shard, the BAD slot
+    rejected and the NACK reported as before, each with no answer."""
+    mb = lane.mb
+    if lane.kind == "singleton":
+        for idx in range(3):
+            lane.put(idx * lane.n)
+        bad, good = 3, {0: None, 1: None, 2: None}
+    else:
+        lane.put(0, K)                          # sub 1 NACKs
+        row = lane.row(0)
+        row[HDR_WORDS + 2] = 0x1234
+        row[HDR_WORDS + 3] = 0x1234 ^ SUB_SALT
+        bad, good = 1, {0: [True, False, True, True]}
+    lane.put(bad * lane.n)
+    lane.row(bad * lane.n)[4] ^= 1             # header check word
+    lane.ch.flush()
+    done, whole, args = lane.sweep_read()
+    lane.expect(args, 3)
+    lane.same_as_whole(done, whole)
+    by_slot = {c.coord[1]: c for c in done}
+    assert set(by_slot) == {*good, bad}, sorted(by_slot)
+    assert by_slot[bad].status == Status.REJECTED
+    assert "rejected" in str(by_slot[bad].error) and by_slot[bad].value is None
+    for idx, oks in good.items():
+        c = by_slot[idx]
+        xs = lane.staged[mb.slot_coords(idx * lane.n)]
+        assert c.status == Status.OK
+        if oks is None:
+            np.testing.assert_allclose(c.value, np.maximum(0.5 * xs[0], 0),
+                                       rtol=1e-5, atol=1e-6)
+            continue
+        assert [s.status == Status.OK for s in c.subs] == oks
+        for s, x, ok in zip(c.subs, xs, oks):
+            if ok:
+                np.testing.assert_allclose(s.value, np.maximum(0.5 * x, 0),
+                                           rtol=1e-5, atol=1e-6)
+            else:
+                assert s.status == Status.NACK_UNCACHED and s.value is None
+    lane.staged.clear()
+
+
+def steady_state(lane: ReadbackLane) -> None:
+    """Once the lane has swept once, sweeps of every fill from 1 row a
+    shard to all of them trace and compile nothing."""
+    from jax._src import monitoring
+
+    lane.fill(lane.rows_each(1))
+    lane.sweep()                                # the sweep and deposit
+    lane.staged.clear()
+    compiles = []
+
+    def on(event: str, _secs: float, **_kw) -> None:
+        if event.startswith("/jax/core/compile/"):
+            compiles.append(event)
+
+    sizes = lane.mb._sweep._cache_size()
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        for k in range(1, ROWS + 1):
+            lane.fill(lane.rows_each(k))
+            lane.check(lane.sweep())
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+    assert compiles == [], compiles
+    assert lane.mb._sweep._cache_size() == sizes
+
+
+READBACK = {f.__name__: f for f in (readback_edges, uneven_shards,
+                                     bad_and_nack, steady_state)}
+READBACK_MESHES = ("one_chip", "four_shards")
+
+
 def run_case(scenario: str, kind: str, shards: int) -> None:
+    if scenario in READBACK:
+        READBACK[scenario](ReadbackLane(kind, shards))
+        return
     # a short container needs an aggregate lane: in its "singleton" case
     # the short frame is a singleton, a degenerate 1-sub container
     single = scenario == "short_container" and kind == "singleton"
@@ -297,37 +499,48 @@ def run_case(scenario: str, kind: str, shards: int) -> None:
     SCENARIOS[scenario](lane)
 
 
-def _run_two_shards() -> dict:
-    """Every case on a 2-shard mesh, in this process (2 host devices)."""
+def _run_shards(shards: int) -> dict:
+    """Every case of a ``shards``-shard mesh, in this process (that many
+    host devices): the staging cases on 2, the readback cases on 4."""
     out = {}
-    for scenario in SCENARIOS:
+    for scenario in SCENARIOS if shards == 2 else READBACK:
         for kind in KINDS:
             try:
-                run_case(scenario, kind, 2)
+                run_case(scenario, kind, shards)
                 out[f"{scenario}/{kind}"] = "ok"
             except Exception:       # reported per case by the parent test
                 out[f"{scenario}/{kind}"] = traceback.format_exc()
     return out
 
 
-_TWO_SHARDS_SCRIPT = r"""
+_SHARDS_SCRIPT = r"""
 import json, os, sys
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+n = int(sys.argv[1])
+os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
 sys.path.insert(0, os.environ["STAGING_TESTS_DIR"])
 import test_device_staging as S
-print("RESULTS " + json.dumps(S._run_two_shards()))
+print("RESULTS " + json.dumps(S._run_shards(n)))
 """
+
+
+def _shard_results(shards: int) -> dict:
+    env = dict(os.environ, PYTHONPATH=f"{REPO}/src",
+               STAGING_TESTS_DIR=str(REPO / "tests"))
+    r = subprocess.run([sys.executable, "-c", _SHARDS_SCRIPT, str(shards)],
+                       env=env, capture_output=True, text=True, timeout=900)
+    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULTS ")]
+    assert lines, r.stdout[-3000:] + r.stderr[-3000:]
+    return json.loads(lines[-1][len("RESULTS "):])
 
 
 @pytest.fixture(scope="module")
 def two_shard_results():
-    env = dict(os.environ, PYTHONPATH=f"{REPO}/src",
-               STAGING_TESTS_DIR=str(REPO / "tests"))
-    r = subprocess.run([sys.executable, "-c", _TWO_SHARDS_SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=900)
-    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULTS ")]
-    assert lines, r.stdout[-3000:] + r.stderr[-3000:]
-    return json.loads(lines[-1][len("RESULTS "):])
+    return _shard_results(2)
+
+
+@pytest.fixture(scope="module")
+def four_shard_results():
+    return _shard_results(4)
 
 
 @pytest.mark.parametrize("mesh", MESHES)
@@ -338,6 +551,17 @@ def test_staging_reuse(scenario, kind, mesh, request):
         run_case(scenario, kind, 1)
         return
     res = request.getfixturevalue("two_shard_results")[f"{scenario}/{kind}"]
+    assert res == "ok", res
+
+
+@pytest.mark.parametrize("mesh", READBACK_MESHES)
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("scenario", list(READBACK))
+def test_readback(scenario, kind, mesh, request):
+    if mesh == "one_chip":
+        run_case(scenario, kind, 1)
+        return
+    res = request.getfixturevalue("four_shard_results")[f"{scenario}/{kind}"]
     assert res == "ok", res
 
 

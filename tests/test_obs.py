@@ -489,7 +489,7 @@ def test_device_lane_scopes_bytes_and_sweep_hist(tmp_path, lib_dir):
     import numpy as np
 
     obs = Obs("t")
-    rt, h = _device_lane(lib_dir, obs)
+    rt, h = _device_lane(lib_dir, obs, n_slots=8)
     ring = rt.dispatcher.peers["tpu"].rings[0]
     ch, mb = ring.channel, ring.mailbox
     assert mb.obs is obs
@@ -518,10 +518,21 @@ def test_device_lane_scopes_bytes_and_sweep_hist(tmp_path, lib_dir):
     assert sum(st["bytes"] for st in pub) == generation * 3
     assert sum(st["n"] for st in pub) == 6
     assert sum(st["n"] for st in stats["repro.device.transcode"]) == 6
-    # a sweep reads back every slot's int32 status and its f32 output tile
+    # a sweep reads back every slot's int32 status, then f32 output tiles:
+    # every slot's where its answers fill half the ring or more, else its
+    # answers' alone, gathered on the device (``compact``; on this ring of
+    # 8 slots a gather pads 1, 2 or 3 answers to no more than their count)
     back = stats["repro.device.readback"]
-    per_sweep = mb.n_shards * mb.n_slots_per_shard * (4 + 128 * 128 * 4)
-    assert back and all(st["bytes"] == per_sweep for st in back)
+    slots = mb.n_shards * mb.n_slots_per_shard
+    assert mb.n_shards == 1 and slots == 8
+    demux = stats["repro.device.demux"]
+    assert back and len(back) == len(demux)
+    for st, dm in zip(back, demux):
+        whole = 2 * dm["n"] >= slots
+        assert st["compact"] == int(not whole), (st, dm)
+        assert st["rows"] == (slots if whole else dm["n"]), (st, dm)
+        assert st["bytes"] == slots * 4 + st["rows"] * 128 * 128 * 4, st
+    assert any(st["compact"] for st in back), back
     consuming = sum(st["n"] > 0 for st in stats["repro.device.demux"])
     assert sum(st["n"] for st in stats["repro.device.demux"]) == 6
     assert obs.sweep_hist.count == consuming >= 3

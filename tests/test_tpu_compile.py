@@ -139,6 +139,31 @@ def test_sweep_compiles(topo, n_chips, agg_k):
         assert "collective-permute" in dep.as_text()
 
 
+@pytest.mark.parametrize("n_chips,agg_k", [(1, 0), (1, AGG_K), (4, 0),
+                                           (4, AGG_K)])
+def test_row_gather_compiles(topo, n_chips, agg_k):
+    """The gather a sparse sweep's answers are read back through, at its
+    smallest and largest size for the lane, compiles with no collective:
+    each chip gathers from its own outputs."""
+    from repro.transport.device_fabric import _gather_sizes, _row_gather
+
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("model",))
+    rows = NamedSharding(mesh, P("model"))
+    slots, n_tiles = (AGG_SLOTS, 1) if agg_k else (SLOTS, N_TILES)
+    out = jax.ShapeDtypeStruct(
+        (n_chips, slots, *((agg_k,) if agg_k else ()), n_tiles, T, T),
+        jnp.float32, sharding=rows)
+    sizes = _gather_sizes(slots * max(agg_k, 1))
+    for size in (sizes[0], sizes[-1]):
+        idx = jax.ShapeDtypeStruct((n_chips, size), jnp.int32, sharding=rows)
+        c = _row_gather().lower(out, idx, mesh=mesh, axis="model").compile()
+        text = c.as_text()
+        assert "gather" in text or "dynamic-slice" in text
+        for coll in ("all-gather", "all-to-all", "collective-permute",
+                     "all-reduce"):
+            assert coll not in text, coll
+
+
 def _kernels_keep_their_names(text: str, agg_k: int):
     """The sweep's kernels carry their own names into the compiled module
     (so a trace's breakdown names them), and the roofline readers, which
